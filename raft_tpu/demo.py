@@ -81,6 +81,8 @@ def main(argv=None):
     parser.add_argument("--mixed_precision", action="store_true")
     parser.add_argument("--show", action="store_true")
     args = parser.parse_args(argv)
+    from raft_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     reject_raft_only_flags(parser, args)
     if args.iters is None:
         args.iters = 20          # reference demo.py:62

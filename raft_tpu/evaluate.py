@@ -1262,6 +1262,8 @@ def main(argv=None):
     parser.add_argument("--data_root", default=None)
     parser.add_argument("--output_path", default=None)
     args = parser.parse_args(argv)
+    from raft_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     default_iters = {"chairs": 24, "kitti": 24, "sintel": 32,
                      "sintel_occ": 32, "sintel_submission": 32,

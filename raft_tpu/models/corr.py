@@ -306,8 +306,9 @@ def _sharded_fused_lookup(fmap1, pyramid2, coords, mesh, radius, scale,
     query-side work. Returns None when the sharding doesn't divide the
     operands (caller falls back to the unsharded call, which XLA then
     runs replicated)."""
-    from raft_tpu.parallel.mesh import (DATA_AXIS, SHARD_MAP_NOCHECK,
-                                        SPATIAL_AXIS, shard_map)
+    from jax import shard_map
+
+    from raft_tpu.parallel.mesh import DATA_AXIS, SPATIAL_AXIS
 
     n_sp = mesh.shape.get(SPATIAL_AXIS, 1)
     n_dt = mesh.shape.get(DATA_AXIS, 1)
@@ -331,7 +332,7 @@ def _sharded_fused_lookup(fmap1, pyramid2, coords, mesh, radius, scale,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(qspec, pspec, qspec),
-                     out_specs=qspec, **SHARD_MAP_NOCHECK)(
+                     out_specs=qspec, check_vma=False)(
         fmap1, pyramid2, coords)
 
 

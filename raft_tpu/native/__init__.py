@@ -14,6 +14,7 @@ counterpart bit-for-bit-or-atol (see ``tests/test_native_augment.py``).
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 from typing import Optional, Tuple
 
@@ -47,9 +48,13 @@ def _load() -> Optional[ctypes.CDLL]:
                                                ctypes.c_double,
                                                ctypes.c_double, f32p, f32p,
                                                ctypes.c_int, ctypes.c_int]
-    except (RuntimeError, OSError, AttributeError):
-        # build failure OR a stale cached .so missing expected symbols:
-        # fall back to numpy
+    except (RuntimeError, OSError, AttributeError) as e:
+        # No compiler, a failed build, or a library missing expected
+        # symbols: the numpy/cv2 path takes over — said once, loudly,
+        # because it is several times slower.
+        logging.getLogger(__name__).warning(
+            "native augmentation library unavailable (%s); the data "
+            "layer falls back to its numpy/cv2 path", e)
         return None
     _lib = lib
     return _lib

@@ -5,30 +5,36 @@ build machinery needed)."""
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sys
 import tempfile
 
 _SRC = os.path.join(os.path.dirname(__file__), "augment.cpp")
-_LIB_NAME = "libraft_augment.so"
 
 
 def lib_path() -> str:
+    """Where the library for THIS ``augment.cpp`` lives: inside the
+    checkout (``raft_tpu/native/_build/``, git-ignored) unless
+    ``RAFT_TPU_NATIVE_DIR`` places it elsewhere, and named by the
+    source's content hash — a binary built from another tree's source
+    has another name and can never be loaded in its place."""
     cache = os.environ.get("RAFT_TPU_NATIVE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "raft_tpu")
-    return os.path.join(cache, _LIB_NAME)
+        os.path.dirname(__file__), "_build")
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(cache, f"libraft_augment_{digest}.so")
 
 
 def build(force: bool = False, quiet: bool = True) -> str:
     """Compile augment.cpp → shared library; returns its path.
 
-    Rebuilds when the source is newer than the binary. Raises
+    Builds once per source content (see :func:`lib_path`). Raises
     ``RuntimeError`` on compiler failure (callers fall back to numpy).
     """
     out = lib_path()
-    if not force and os.path.exists(out) and (
-            os.path.getmtime(out) >= os.path.getmtime(_SRC)):
+    if not force and os.path.exists(out):
         return out
     os.makedirs(os.path.dirname(out), exist_ok=True)
     # write to a temp file then rename: another process may race the build

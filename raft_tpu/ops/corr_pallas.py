@@ -405,6 +405,7 @@ def _pallas_fwd(f1, f2s, cx, cy, radius, scale, interpret, levels, tq,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((win * w2p_max, tq), jnp.float32)],
         interpret=interpret,
+        name=klayout.KERNEL_NAMES["corr_fwd"],
     )(cx, cy, f1, *f2s)
 
 
@@ -447,6 +448,7 @@ def _pallas_bwd(f1, f2s, cx, cy, g, radius, scale, interpret, levels, tq,
         scratch_shapes=[pltpu.VMEM((win * w2p_max, tq), jnp.float32),
                         pltpu.VMEM((tq, c), jnp.float32)],
         interpret=interpret,
+        name=klayout.KERNEL_NAMES["corr_bwd"],
     )(cx, cy, f1, *f2s, g)
 
 
@@ -660,43 +662,6 @@ def windowed_correlation_pallas_fused(
     if not tout:
         out = jnp.swapaxes(out, 1, 2)                    # (B, Np, L*win*win)
     return out[:, :n].reshape(b, h, w, len(levels) * win * win)
-
-
-def run_with_band_retry(run, record: dict, name: str) -> bool:
-    """Measurement-harness self-healing for this kernel's one
-    never-compiled-on-chip construct (the dynamic-trip-count row loop).
-
-    Runs ``run()`` under the current band mode, recording
-    ``{name}_band`` on success. On failure it walks the remainder of
-    the fallback ladder **dynamic → static → off** (masked-static first:
-    it keeps the banded traffic win using only round-2-proven
-    constructs; the full sweep is the last resort), restoring any
-    pre-existing operator setting afterwards. Every failure is recorded
-    under a distinct ``{name}_band_{mode}_error`` key and swallowed (a
-    sibling arm's numbers must survive); returns False only if every
-    mode fails. An operator-forced ``RAFT_CORR_BAND`` is honored as the
-    ladder's starting rung.
-    """
-    prev = os.environ.get("RAFT_CORR_BAND")
-    ladder = ["dynamic", "static", "off"]
-    first = {"0": "off", "static": "static"}.get(prev or "1", "dynamic")
-    env_of = {"dynamic": "1", "static": "static", "off": "0"}
-    try:
-        for mode in ladder[ladder.index(first):]:
-            os.environ["RAFT_CORR_BAND"] = env_of[mode]
-            try:
-                run()
-                record[f"{name}_band"] = mode
-                return True
-            except Exception as e:
-                record[f"{name}_band_{mode}_error"] = \
-                    f"{type(e).__name__}: {e}"
-        return False
-    finally:
-        if prev is None:
-            os.environ.pop("RAFT_CORR_BAND", None)
-        else:
-            os.environ["RAFT_CORR_BAND"] = prev
 
 
 def windowed_correlation_pallas(fmap1: jnp.ndarray, fmap2: jnp.ndarray,

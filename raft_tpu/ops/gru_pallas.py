@@ -208,6 +208,18 @@ def split_x_weights(mats, cxs):
 # Kernel
 # ---------------------------------------------------------------------------
 
+def gate_sigmoid(v):
+    """Sigmoid of a gate tile, computed in float32 and cast back.
+
+    Mosaic (jax 0.9.0) rejects ``jax.nn.sigmoid`` on a bf16 tile — the
+    logistic lowering broadcasts an f32 constant into a bf16 vector and
+    fails verification — and the v5e VPU/EUP has no bf16 path anyway, so
+    the upcast is what the hardware does. Shared by the kernel, the fused
+    step kernel and their jnp twins so all stay the same math; a no-op
+    at f32."""
+    return jax.nn.sigmoid(v.astype(jnp.float32)).astype(v.dtype)
+
+
 def _shift_rows(v, s: int):
     """``out[n] = v[n + s]`` along the sublane axis, zero-filled at the
     edges (out-of-assembly sources are either image padding or rows whose
@@ -324,8 +336,8 @@ def _gru_kernel(*refs, w: int, h_img: int, th: int, nparts: int):
 
     # Horizontal step over the full assembly (the halo rows' h1 feed the
     # vertical step's taps; (TH+8)/TH recompute — see module docstring).
-    zr1 = jax.nn.sigmoid(sepconv(ha, xas, wzr1h_ref, wzr1x_refs,
-                                 bzr1_ref, 1, hmask))
+    zr1 = gate_sigmoid(sepconv(ha, xas, wzr1h_ref, wzr1x_refs,
+                               bzr1_ref, 1, hmask))
     z1, r1 = zr1[:, :c], zr1[:, c:]
     q1 = jnp.tanh(sepconv(r1 * ha, xas, wq1h_ref, wq1x_refs,
                           bq1_ref, 1, hmask))
@@ -333,8 +345,8 @@ def _gru_kernel(*refs, w: int, h_img: int, th: int, nparts: int):
 
     # Vertical step; only the cur rows of the outputs are consumed, and
     # every tap they draw on lies inside the assembly span.
-    zr2 = jax.nn.sigmoid(sepconv(h1, xas, wzr2h_ref, wzr2x_refs,
-                                 bzr2_ref, w, vmask))
+    zr2 = gate_sigmoid(sepconv(h1, xas, wzr2h_ref, wzr2x_refs,
+                               bzr2_ref, w, vmask))
     z2, r2 = zr2[:, :c], zr2[:, c:]
     q2 = jnp.tanh(sepconv(r2 * h1, xas, wq2h_ref, wq2x_refs,
                           bq2_ref, w, vmask))
@@ -396,6 +408,8 @@ def _pallas_gru(static, h2d, xs2d, mats):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=vmem.scan_compiler_params(),
+        name=klayout.KERNEL_NAMES["gru"],
     )(*operands, *flat_mats)
 
 
@@ -459,12 +473,12 @@ def reference_gru(static, h2d, x2d, mats):
                     preferred_element_type=jnp.float32)
         return acc.astype(cdt) + bias
 
-    zr1 = jax.nn.sigmoid(sepconv(h2d, xs, wzr1h, wzr1x, bzr1, 1, hmask))
+    zr1 = gate_sigmoid(sepconv(h2d, xs, wzr1h, wzr1x, bzr1, 1, hmask))
     z1, r1 = zr1[..., :c], zr1[..., c:]
     q1 = jnp.tanh(sepconv(r1 * h2d, xs, wq1h, wq1x, bq1, 1, hmask))
     h1 = (1 - z1) * h2d + z1 * q1
 
-    zr2 = jax.nn.sigmoid(sepconv(h1, xs, wzr2h, wzr2x, bzr2, w, vmask))
+    zr2 = gate_sigmoid(sepconv(h1, xs, wzr2h, wzr2x, bzr2, w, vmask))
     z2, r2 = zr2[..., :c], zr2[..., c:]
     q2 = jnp.tanh(sepconv(r2 * h1, xs, wq2h, wq2x, bq2, w, vmask))
     return (1 - z2) * h1 + z2 * q2
@@ -505,34 +519,23 @@ _gru.defvjp(_gru_fwd, _gru_bwd)
 
 def gru_vmem_parts(h_img: int, w: int, c: int, cx: int, th: int,
                    dtype_bytes: int) -> dict:
-    """Named scoped-VMEM estimate for one launch (see raft_tpu.ops.vmem).
-    Conservative: counts the double-buffered input blocks, the resident
-    weights, the concat/shift value copies and the live float32
-    accumulator set (gate acc + h1 + q)."""
-    g = th * w
+    """Named scoped-VMEM estimate for one launch: Mosaic's calibrated
+    per-row figure over the ``(TH + 8) * W`` assembly span
+    (``vmem.scan_rows_parts``), stretched linearly for channel counts
+    beyond the calibrated C=128 / Cx=256."""
     rows = (th + 2 * _HALO) * w
-    chx = c + cx
-    return {
-        "h_blocks": 3 * g * c * dtype_bytes,
-        "x_blocks": 3 * g * cx * dtype_bytes,
-        "out_block": g * c * dtype_bytes,
-        "weights": (2 * _TAPS * chx * 3 * c + 2 * 3 * c) * dtype_bytes,
-        "assembly_and_shift": 2 * rows * chx * dtype_bytes,
-        "f32_accumulators": rows * 4 * c * 4,
-    }
+    return vmem.scan_rows_parts("gru", rows, dtype_bytes,
+                                max(1.0, (c + cx) / 384))
 
 
 def choose_rows(h_img: int, w: int, c: int, cx: int,
                 dtype_bytes: int) -> int | None:
     """Largest row-tile TH in {16, 8, 4} whose VMEM estimate fits the
-    admission budget and whose flattened tile is sublane-aligned.
+    scan-body budget and whose flattened tile is sublane-aligned.
     None → no admissible tile (caller falls back to the flax path)."""
-    for th in (16, 8, 4):
-        if (th * w) % 8:
-            continue
-        if vmem.fits(gru_vmem_parts(h_img, w, c, cx, th, dtype_bytes)):
-            return th
-    return None
+    return vmem.choose_rows(
+        (16, 8, 4), w,
+        lambda th: gru_vmem_parts(h_img, w, c, cx, th, dtype_bytes))
 
 
 def gru_eligible(h_img: int, w: int, c: int, cx: int, dtype,
@@ -578,6 +581,10 @@ def should_fuse(h, x, hidden_dim: int, mode: str | None = None) -> bool:
     xs = x if isinstance(x, (tuple, list)) else (x,)
     cx = sum(xx.shape[-1] for xx in xs)
     on_tpu = jax.default_backend() == "tpu"
+    if on_tpu:
+        from raft_tpu.parallel.spatial import keeps_xla_under_partitioning
+        if keeps_xla_under_partitioning("RAFT_GRU_PALLAS", mode):
+            return False
     interpret = not on_tpu
     _, hh, ww, c = h.shape
     ok = gru_eligible(hh, ww, c, cx, h.dtype, interpret)
@@ -644,7 +651,8 @@ def sepconv_gru(h, x, mats, *, dtype=None, interpret: bool | None = None,
     th = max(th, _HALO)
     if not interpret:
         vmem.preflight(gru_vmem_parts(hh, ww, c, cx, th, cdt.itemsize),
-                       f"fused GRU kernel (th={th}, w={ww})")
+                       f"fused GRU kernel (th={th}, w={ww})",
+                       vmem.SCAN_LIMIT_BYTES)
 
     hpad = _round_up(hh, th)
     n = hpad * ww

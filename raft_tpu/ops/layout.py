@@ -71,6 +71,33 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl
 
+#: Stable ``pallas_call`` names, one per kernel. A name shows up in the
+#: compiled program's text (in the ``op_name`` of its
+#: ``tpu_custom_call``) and in profiler traces, so tools can tell which
+#: kernels a program really lowered (``kernel_census``) without parsing
+#: kernel bodies.
+KERNEL_NAMES = {key: f"raft_{key}" for key in (
+    "corr_fwd", "corr_bwd", "gru", "motion", "step", "msda_fwd", "msda_bwd")}
+
+
+def kernel_census(compiled_text: str) -> dict:
+    """Count the Mosaic kernels in a compiled program's text
+    (``compiled.as_text()``): ``{kernel: n}`` over ``KERNEL_NAMES`` keys,
+    plus ``"unnamed"`` for any other ``tpu_custom_call``. Kernels that
+    did not lower are absent — an interpret-mode or XLA-path program
+    returns ``{}``."""
+    counts: dict = {}
+    for line in compiled_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        # op_name is ".../<name>/pallas_call" in a forward program and
+        # ".../transpose(jvp(<name>))/pallas_call" in a backward one.
+        op_name = line.partition('op_name="')[2].partition('"')[0]
+        key = next((k for k, name in KERNEL_NAMES.items()
+                    if name in op_name), "unnamed")
+        counts[key] = counts.get(key, 0) + 1
+    return counts
+
 
 def boundary_store(out_ref, value, *, transpose: bool = False) -> None:
     """The canonical final store of a kernel output block.

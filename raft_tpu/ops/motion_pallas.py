@@ -97,10 +97,11 @@ _HALO = 5
 # assemblies under the phase-peak liveness estimate.
 _ROW_LADDER = (16, 8, 4)
 
-# Canonical BasicMotionEncoder channel widths (convc1/convc2/convf1/
-# convf2/conv outputs) — fixed by the architecture; the admission table
-# defaults to them and the wrapper re-derives from the packed weights.
-_WIDTHS = (256, 192, 128, 64, 126)
+# The VMEM calibration (vmem._ROW_BYTES) was taken at the canonical
+# BasicMotionEncoder widths (256/192/128/64/126, fixed by the
+# architecture) and 324 corr channels, which Mosaic pads to 3 x 128
+# lanes; a wider window is uncalibrated and keeps the conv path.
+_MAX_CORR_CHANNELS = 384
 
 
 # ---------------------------------------------------------------------------
@@ -289,6 +290,8 @@ def _pallas_motion(static, flow2d, corr2d, mats):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=vmem.scan_compiler_params(),
+        name=klayout.KERNEL_NAMES["motion"],
     )(*operands)
 
 
@@ -378,41 +381,14 @@ _motion.defvjp(_motion_fwd, _motion_bwd)
 # ---------------------------------------------------------------------------
 
 def motion_vmem_parts(h_img: int, w: int, cc: int, th: int,
-                      dtype_bytes: int, widths=_WIDTHS) -> dict:
-    """Named scoped-VMEM estimate for one launch (see raft_tpu.ops.vmem).
-
-    Round 10 refined this from sum-of-all-intermediates to *phase-peak*
-    liveness: the five convs run sequentially, so the working set is
-    the largest single phase's live values — the phase's input
-    operand(s), one shifted copy, the f32 accumulator, and the
-    across-phase residents (``fa`` for the passthrough, ``cor`` across
-    the flow branch) — not every feature map at once. The peak phase is
-    ``convc2`` (c1→c2 with c1-wide input + shift). Input windows are
-    charged per neighbor block (``ceil(_HALO/th)`` per side), which is
-    what lets the TH=4 rung admit shapes TH=8 cannot."""
-    c1, c2, f1, f2, co = widths
-    d = dtype_bytes
-    g = th * w
-    nb = -(-_HALO // th)
+                      dtype_bytes: int) -> dict:
+    """Named scoped-VMEM estimate for one launch: Mosaic's calibrated
+    per-row figure over the ``(TH + 10) * W`` assembly span
+    (``vmem.scan_rows_parts``). The figure holds for the canonical
+    widths and up to ``_MAX_CORR_CHANNELS`` corr channels, which
+    ``motion_eligible`` enforces."""
     rows = (th + 2 * _HALO) * w
-    weight_elems = (cc * c1 + 9 * c1 * c2 + 49 * 2 * f1 + 9 * f1 * f2
-                    + 9 * (c2 + f2) * co + c1 + c2 + f1 + f2 + co)
-    # Per-row live bytes of each sequential phase: held-across operands
-    # + the phase's input + shifted copy + f32 accumulator.
-    phases = (
-        cc * d + 2 * d + c1 * 4,                            # convc1 (1x1)
-        2 * d + 2 * c1 * d + c2 * 4,                        # convc2 (peak)
-        2 * d + c2 * d + 2 * 2 * d + f1 * 4,                # convf1 (7x7)
-        2 * d + c2 * d + 2 * f1 * d + f2 * 4,               # convf2
-        2 * d + c2 * d + f2 * d + max(c2, f2) * d + co * 4,  # conv (cat)
-    )
-    return {
-        "corr_blocks": (2 * nb + 1) * g * cc * d,
-        "flow_blocks": (2 * nb + 1) * g * 2 * d,
-        "out_block": g * (co + 2) * d,
-        "weights": weight_elems * d,
-        "intermediates_phase_peak": rows * max(phases),
-    }
+    return vmem.scan_rows_parts("motion", rows, dtype_bytes)
 
 
 def choose_rows(h_img: int, w: int, cc: int,
@@ -421,10 +397,9 @@ def choose_rows(h_img: int, w: int, cc: int,
     fits the admission budget and whose flattened tile is
     sublane-aligned (vmem.choose_rows). None → no admissible tile
     (auto falls back to the conv path). At Sintel eval shapes (H=55,
-    W=128, Ccorr=324) bf16 admits th=16 and f32 admits th=4 — round
-    10's phase-peak accounting plus the multi-neighbor TH=4 rung;
-    before it, f32 fit no tile at all — asserted in
-    tests/test_motion_pallas.py."""
+    W=128, Ccorr=324) bf16 admits th=16 and f32 th=8 under the explicit
+    100 MiB limit — asserted in tests/test_motion_pallas.py and compiled
+    for the chip in tests/test_chip_compile.py."""
     return vmem.choose_rows(
         _ROW_LADDER, w,
         lambda th: motion_vmem_parts(h_img, w, cc, th, dtype_bytes))
@@ -440,6 +415,8 @@ def motion_eligible(h_img: int, w: int, cc: int, dtype,
         return False
     if interpret:
         return True
+    if cc > _MAX_CORR_CHANNELS:
+        return False
     return choose_rows(h_img, w, cc, jnp.dtype(dtype).itemsize) is not None
 
 
@@ -469,6 +446,10 @@ def should_fuse(flow, corr, mode: str | None = None) -> bool:
                 f"spatial dims and 2 flow channels)")
         return False
     on_tpu = jax.default_backend() == "tpu"
+    if on_tpu:
+        from raft_tpu.parallel.spatial import keeps_xla_under_partitioning
+        if keeps_xla_under_partitioning("RAFT_MOTION_PALLAS", mode):
+            return False
     interpret = not on_tpu
     _, hh, ww, _ = flow.shape
     cc = corr.shape[-1]
@@ -518,8 +499,6 @@ def motion_encoder(flow, corr, mats, *, dtype=None,
     co = mats[-1].shape[1]
     cdt = jnp.dtype(dtype) if dtype is not None else corr.dtype
     out_dt = jnp.promote_types(cdt, flow.dtype)
-    widths = (mats[0].shape[1], mats[2].shape[1], mats[4].shape[1],
-              mats[6].shape[1], co)
 
     if th is None:
         if interpret:
@@ -532,8 +511,9 @@ def motion_encoder(flow, corr, mats, *, dtype=None,
             th = choose_rows(hh, ww, cc, cdt.itemsize) or _ROW_LADDER[-1]
     if not interpret:
         vmem.preflight(
-            motion_vmem_parts(hh, ww, cc, th, cdt.itemsize, widths),
-            f"fused motion encoder (th={th}, w={ww})")
+            motion_vmem_parts(hh, ww, cc, th, cdt.itemsize),
+            f"fused motion encoder (th={th}, w={ww})",
+            vmem.SCAN_LIMIT_BYTES)
 
     hpad = _round_up(hh, th)
     n = hpad * ww

@@ -96,10 +96,17 @@ def ms_deform_attn(value: jnp.ndarray,
                 "backend='pallas' but the shapes don't fit the kernel's "
                 f"VMEM-resident layout (value {value.shape}, levels "
                 f"{list(spatial_shapes)}); see msda_pallas.pallas_eligible")
-        if backend == "pallas" or (
-                backend == "auto" and eligible
-                and sampling_locations.shape[1] >= _PALLAS_MIN_QUERIES
-                and jax.default_backend() == "tpu"):
+        auto_kernel = (backend == "auto" and eligible
+                       and sampling_locations.shape[1] >= _PALLAS_MIN_QUERIES
+                       and jax.default_backend() == "tpu")
+        if auto_kernel:
+            # The kernel has no shard_map wrapper: on a mesh (data-
+            # parallel training) GSPMD would refuse it.
+            from raft_tpu.parallel.spatial import \
+                keeps_xla_under_partitioning
+            auto_kernel = not keeps_xla_under_partitioning(
+                "ms_deform_attn backend", "auto")
+        if backend == "pallas" or auto_kernel:
             return msda_pallas.ms_deform_attn_pallas(
                 value, spatial_shapes, sampling_locations,
                 attention_weights)

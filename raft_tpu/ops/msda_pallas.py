@@ -65,6 +65,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from raft_tpu.ops.layout import KERNEL_NAMES
+
 _LANE = 128
 
 
@@ -200,6 +202,7 @@ def _level_fwd(px, py, aw, v, *, m_heads, points, d_head, h, wp,
         out_shape=jax.ShapeDtypeStruct((b, m_heads * d_head, npad),
                                        jnp.float32),
         interpret=interpret,
+        name=KERNEL_NAMES["msda_fwd"],
     )(px, py, aw, v)
 
 
@@ -234,6 +237,7 @@ def _level_bwd(px, py, aw, v, g, *, m_heads, points, d_head, h, wp,
             jax.ShapeDtypeStruct((b, mdh, wp), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAMES["msda_bwd"],
     )(px, py, aw, v, g)
 
 

@@ -39,13 +39,14 @@ shifted-matmul arithmetic — parity with the two-launch chain is
 near-bit-exact at f32, and ≤2e-4 vs the conv path
 (``tests/test_step_pallas.py``).
 
-VMEM admission is ``vmem.step_vmem_parts`` (phase-peak liveness — the
-phases run sequentially, so the working set is the largest phase plus
-the cross-phase residents) under the shared ``vmem.choose_rows``
-ladder ``(16, 8, 4)``; at Sintel bf16 only TH=4 admits ``'mg'``
-(~12.8 MiB), f32 admits nothing (the weights alone are ~9.5 MB) — an
-honest, loudly-logged fallback to the two-launch chain, never a
-silent one.
+VMEM admission is ``step_vmem_parts`` — Mosaic's calibrated per-row
+figure over the motion span, which is this kernel's peak
+(``vmem.scan_rows_parts``) — under the shared ``vmem.choose_rows``
+ladder ``(16, 8, 4)`` and the explicit 100 MiB limit; at Sintel shapes
+bf16 admits TH=8 at both depths and f32 admits ``'mg'`` at TH=4 only. A
+shape the ladder rejects
+(e.g. W=240 at 1080p, either depth) falls back, loudly logged, to the
+two-launch chain, never silently.
 
 The custom VJP recomputes through the identical-math jnp twin
 (``reference_motion`` → ``reference_gru`` → flow-head taps); a fused
@@ -71,9 +72,11 @@ from raft_tpu.ops import layout as klayout
 from raft_tpu.ops import vmem
 from raft_tpu.ops.gru_pallas import (_TAPS, _bshift, _flatten_mats,
                                      _full_spec, _round_up, _shift_rows,
-                                     halo_assemble, split_x_weights)
+                                     gate_sigmoid, halo_assemble,
+                                     split_x_weights)
 from raft_tpu.ops.gru_pallas import reference_gru
-from raft_tpu.ops.motion_pallas import _WIDTHS, reference_motion
+from raft_tpu.ops.motion_pallas import (_MAX_CORR_CHANNELS,
+                                        reference_motion)
 from raft_tpu.utils.envflags import STEP_FLAG, resolve_step_pallas
 
 # Per-stage receptive-field depths (rows each side). The GRU needs its
@@ -253,14 +256,14 @@ def _step_kernel(*refs, w: int, h_img: int, th: int, fh: bool):
                     preferred_element_type=jnp.float32)
         return acc.astype(cdt) + b_ref[...]
 
-    zr1 = jax.nn.sigmoid(sepconv(ha, xas, wzr1h, (wzr1xa, wzr1xb),
-                                 bzr1, 1, hmask))
+    zr1 = gate_sigmoid(sepconv(ha, xas, wzr1h, (wzr1xa, wzr1xb),
+                               bzr1, 1, hmask))
     z1, r1 = zr1[:, :c], zr1[:, c:]
     q1 = jnp.tanh(sepconv(r1 * ha, xas, wq1h, (wq1xa, wq1xb),
                           bq1, 1, hmask))
     h1 = (1 - z1) * ha + z1 * q1
-    zr2 = jax.nn.sigmoid(sepconv(h1, xas, wzr2h, (wzr2xa, wzr2xb),
-                                 bzr2, w, vmask))
+    zr2 = gate_sigmoid(sepconv(h1, xas, wzr2h, (wzr2xa, wzr2xb),
+                               bzr2, w, vmask))
     z2, r2 = zr2[:, :c], zr2[:, c:]
     q2 = jnp.tanh(sepconv(r2 * h1, xas, wq2h, (wq2xa, wq2xb),
                           bq2, w, vmask))
@@ -328,6 +331,8 @@ def _pallas_step(static, net2d, inp2d, flow2d, corr2d, mmats, gmats,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=vmem.scan_compiler_params(),
+        name=klayout.KERNEL_NAMES["step"],
     )(*operands, *flat_mats)
     return tuple(out) if fh else out
 
@@ -415,22 +420,29 @@ _step.defvjp(_step_fwd, _step_bwd)
 # Admission + dispatch
 # ---------------------------------------------------------------------------
 
+def step_vmem_parts(w: int, th: int, dtype_bytes: int, *,
+                    flow_head: bool = False) -> dict:
+    """Named scoped-VMEM estimate for one fused launch: Mosaic's
+    calibrated per-row figure over the deep (motion) span of
+    ``TH + 2*hm`` rows — 9 halo rows a side for ``mg``, 11 for ``mgf``.
+    Holds for the canonical widths (C = Cinp = 128, at most 384 corr
+    channels), which ``plan_fusion`` enforces."""
+    _, hm = halos(flow_head)
+    return vmem.scan_rows_parts("step", (th + 2 * hm) * w, dtype_bytes)
+
+
 def choose_rows(h_img: int, w: int, cc: int, dtype_bytes: int, *,
-                flow_head: bool = False, c: int = 128, cinp: int = 128,
-                widths=_WIDTHS) -> int | None:
+                flow_head: bool = False) -> int | None:
     """Largest admissible row tile for one fused launch under the
-    shared (16, 8, 4) ladder and the phase-peak ``step_vmem_parts``
-    estimate; None → this fusion depth doesn't fit (the caller steps
-    down mgf → mg → two-launch chain). At Sintel eval shapes bf16
-    admits TH=4 for ``mg`` only; f32 admits nothing — asserted in
+    shared (16, 8, 4) ladder and ``step_vmem_parts``; None → this
+    fusion depth doesn't fit (the caller steps down mgf → mg →
+    two-launch chain). At Sintel eval shapes bf16 admits TH=8 at both
+    depths; at 1080p (W=240) neither admits any — asserted in
     tests/test_step_pallas.py."""
     return vmem.choose_rows(
         _ROW_LADDER, w,
-        lambda th: vmem.step_vmem_parts(
-            h_img, w, cc, th, dtype_bytes, flow_head=flow_head, c=c,
-            cinp=cinp, motion_widths=widths,
-            halo_motion=_HALO_MOTION, halo_gru=_HALO_GRU,
-            halo_flow_head=_HALO_FLOW_HEAD))
+        lambda th: step_vmem_parts(w, th, dtype_bytes,
+                                   flow_head=flow_head))
 
 
 def resolve_mode() -> str:
@@ -473,16 +485,19 @@ def plan_fusion(net, inp, corr, flow, want_flow_head: bool,
         # Interpret mode is a parity tool, not a fast path: only a
         # forced '1' runs it; auto keeps the XLA/chained path off-TPU.
         return ("mgf" if want_flow_head else "mg") if mode == "1" else None
+    from raft_tpu.parallel.spatial import keeps_xla_under_partitioning
+    if keeps_xla_under_partitioning(STEP_FLAG, mode):
+        return None
     _, hh, ww, c = net.shape
     cinp = inp.shape[-1]
     cc = corr.shape[-1]
     d = jnp.dtype(net.dtype).itemsize
-    lanes_ok = c % 128 == 0 and cinp % 128 == 0
-    if lanes_ok and want_flow_head and choose_rows(
-            hh, ww, cc, d, flow_head=True, c=c, cinp=cinp):
+    # The widths the VMEM figure was calibrated at (vmem._ROW_BYTES).
+    widths_ok = c == 128 and cinp == 128 and cc <= _MAX_CORR_CHANNELS
+    if widths_ok and want_flow_head and choose_rows(
+            hh, ww, cc, d, flow_head=True):
         return "mgf"
-    if lanes_ok and choose_rows(hh, ww, cc, d, flow_head=False, c=c,
-                                cinp=cinp):
+    if widths_ok and choose_rows(hh, ww, cc, d, flow_head=False):
         return "mg"
     if mode == "1":
         raise ValueError(
@@ -494,9 +509,7 @@ def plan_fusion(net, inp, corr, flow, want_flow_head: bool,
         STEP_FLAG,
         f"(H={hh}, W={ww}, C={c}, Ccorr={cc}, "
         f"dtype={jnp.dtype(net.dtype).name})",
-        vmem.step_vmem_parts(hh, ww, cc, _ROW_LADDER[-1], d,
-                             flow_head=False, c=max(c, 1),
-                             cinp=max(cinp, 1)))
+        step_vmem_parts(ww, _ROW_LADDER[-1], d))
     return None
 
 
@@ -532,22 +545,18 @@ def fused_step(net, inp, corr, flow, mmats, gmats, fmats=None, *,
     co = mmats[-1].shape[1]
     cdt = jnp.dtype(dtype) if dtype is not None else jnp.dtype(net.dtype)
     out_dt = net.dtype
-    widths = (mmats[0].shape[1], mmats[2].shape[1], mmats[4].shape[1],
-              mmats[6].shape[1], co)
 
     if th is None:
         if interpret:
             th = 4
         else:
-            th = choose_rows(hh, ww, cc, cdt.itemsize, flow_head=fh,
-                             c=c, cinp=cinp,
-                             widths=widths) or _ROW_LADDER[-1]
+            th = choose_rows(hh, ww, cc, cdt.itemsize,
+                             flow_head=fh) or _ROW_LADDER[-1]
     if not interpret:
         vmem.preflight(
-            vmem.step_vmem_parts(hh, ww, cc, th, cdt.itemsize,
-                                 flow_head=fh, c=c, cinp=cinp,
-                                 motion_widths=widths),
-            f"fused step kernel (th={th}, w={ww}, flow_head={fh})")
+            step_vmem_parts(ww, th, cdt.itemsize, flow_head=fh),
+            f"fused step kernel (th={th}, w={ww}, flow_head={fh})",
+            vmem.SCAN_LIMIT_BYTES)
 
     hpad = _round_up(hh, th)
 

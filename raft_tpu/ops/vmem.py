@@ -1,28 +1,32 @@
-"""Scoped-VMEM budget shared by the Pallas kernels (corr, GRU, motion,
+"""Scoped-VMEM budgets shared by the Pallas kernels (corr, GRU, motion,
 and the fused one-launch step kernel).
 
-A TPU core has ~16 MB of VMEM; Mosaic additionally needs headroom for
-compiler-managed temporaries (matmul operand staging, double-buffered
-block windows).  Exceeding it does not fail gracefully: the 512-query-tile
-corr config died in Mosaic with a raw scoped-allocator OOM — ``17.41 MB
-vs 16 MB limit`` after a long compile (BASELINE.md "Query tile 512") —
+Mosaic holds a ``pallas_call`` to a *scoped* VMEM limit: 16 MiB unless
+the call passes a larger one in its compiler params (a v5e core has 128
+MiB). Exceeding it does not fail gracefully: the 512-query-tile corr
+config died in Mosaic with a raw scoped-allocator OOM — ``17.41 MB vs
+16 MB limit`` after a long compile (BASELINE.md "Query tile 512") —
 with no indication of *which* buffers blew the budget.
 
-This module gives kernels two shared pieces:
+This module gives kernels the shared pieces:
 
-* ``BUDGET_BYTES`` — the conservative admission budget (13 MiB) that
-  ``corr_pallas.fused_eligible`` has used since round 2; the 3 MiB gap to
-  the hard limit is the measured headroom Mosaic's own temporaries need.
+* ``BUDGET_BYTES`` — the conservative admission budget (13 MiB) under the
+  default limit that ``corr_pallas.fused_eligible`` has used since round
+  2; the 3 MiB gap is the measured headroom Mosaic's own temporaries
+  need.
+* ``SCAN_LIMIT_BYTES`` / ``scan_compiler_params()`` / ``scan_rows_parts``
+  — the scan-body kernels' explicit limit, the compiler params that
+  carry it, and their estimate, calibrated from the compiler's own
+  reports.
 * ``preflight(parts, where)`` — a loud pre-launch check: given the
   kernel's named buffer estimate, raise ``ValueError`` with the itemized
-  breakdown and the requested-vs-16 MB numbers *before* ``pallas_call``
-  hands the config to Mosaic, instead of after a multi-minute compile.
+  breakdown *before* ``pallas_call`` hands the config to Mosaic, instead
+  of after a multi-minute compile.
 * ``log_fallback(flag, shape, parts)`` — the ``auto`` counterpart: when
   a kernel's dispatch *wants* the fused path on TPU but the admission
-  table rejects the shape (e.g. f32 at Sintel eval shapes), emit one
-  structured warning naming the flag, the shape, and the estimate-vs-
-  budget numbers — a silent fall-back to the slow path is a perf bug
-  that hides for months.
+  table rejects the shape, emit one structured warning naming the flag,
+  the shape, and the estimate-vs-budget numbers — a silent fall-back to
+  the slow path is a perf bug that hides for months.
 
 Estimates are static (shape arithmetic only) and intentionally
 conservative — over-admitting reproduces the raw Mosaic OOM this module
@@ -38,11 +42,48 @@ from typing import Mapping
 
 _LOG = logging.getLogger(__name__)
 
-#: Hard per-core scoped-VMEM limit Mosaic allocates against.
+#: Mosaic's default scoped-VMEM limit: what a ``pallas_call`` that passes
+#: no compiler params is held to. The corr and MSDA kernels live under it.
 LIMIT_BYTES = 16 * 2 ** 20
 
-#: Conservative admission budget: leaves ~3 MiB for Mosaic temporaries.
+#: Conservative admission budget under the default limit: leaves ~3 MiB
+#: for Mosaic temporaries.
 BUDGET_BYTES = 13 * 2 ** 20
+
+#: The scan-body kernels (GRU, motion, fused step) keep a whole
+#: multi-conv chain resident and need several times the default: they ask
+#: Mosaic for this much explicitly (``scan_compiler_params``) and are
+#: admitted against it. A v5e core has 128 MiB of VMEM; this leaves 28
+#: MiB for whatever XLA keeps there around the call.
+SCAN_LIMIT_BYTES = 100 * 2 ** 20
+
+#: Bytes of scoped VMEM Mosaic takes per flattened assembly row
+#: (``(TH + 2*halo) * W`` rows), by kernel and compute-dtype width, at the
+#: canonical RAFT-large widths (hidden 128, x 256, 324 corr channels).
+#: Read from ``used_scoped_memory_configs`` in the compiled text (jax
+#: 0.9.0 / libtpu 0.0.34, v5e) of compiles under a 1 GiB limit, batch 1-8,
+#: TH in {4, 8, 16}, at 55x128 (Sintel), 46x62 (chairs), 48x156 (KITTI)
+#: and 135x240 (1080p). Mosaic's footprint is elastic — under a tighter
+#: limit the same kernel compiles into less, by an amount that varies
+#: with batch and surroundings — so the unlimited figure is the upper
+#: bound and the only safe thing to admit against. It is linear in the
+#: row span with no constant term, and bf16 costs most of what f32 does
+#: (the v5e VPU computes the elementwise tail in f32 either way). Largest
+#: observed, KiB/row: GRU 13.4 (bf16) / 19.3 (f32); motion 24.3 / 27.5;
+#: fused step, either depth, over its motion span 25.0 / 33.8 — rounded
+#: up, further where a width (W=62 in f32) was not probed.
+_ROW_BYTES = {
+    "gru": {2: 14 * 1024, 4: 24 * 1024},
+    "motion": {2: 26 * 1024, 4: 31 * 1024},
+    "step": {2: 26 * 1024, 4: 35 * 1024},
+}
+
+
+def scan_compiler_params():
+    """The TPU compiler params every scan-body ``pallas_call`` passes:
+    the explicit scoped-VMEM limit their admission is sized from."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=SCAN_LIMIT_BYTES)
 
 
 def total_bytes(parts: Mapping[str, int]) -> int:
@@ -50,22 +91,24 @@ def total_bytes(parts: Mapping[str, int]) -> int:
     return sum(parts.values())
 
 
-def fits(parts: Mapping[str, int]) -> bool:
-    """Whether the estimate fits the conservative admission budget."""
-    return total_bytes(parts) <= BUDGET_BYTES
+def fits(parts: Mapping[str, int], budget: int = BUDGET_BYTES) -> bool:
+    """Whether the estimate fits the admission budget."""
+    return total_bytes(parts) <= budget
 
 
-def preflight(parts: Mapping[str, int], where: str) -> None:
+def preflight(parts: Mapping[str, int], where: str,
+              budget: int = BUDGET_BYTES) -> None:
     """Raise a clear ``ValueError`` if ``parts`` exceeds the admission
     budget — called by kernel wrappers immediately before ``pallas_call``
     so an oversized config fails in microseconds with an itemized
     breakdown instead of a raw Mosaic scoped-VMEM OOM after compile.
 
     ``where`` names the kernel/config for the message (e.g.
-    ``"corr fused forward (tq=512)"``).
+    ``"corr fused forward (tq=512)"``). ``budget`` is the default-limit
+    budget unless the kernel passes its own (``SCAN_LIMIT_BYTES``).
     """
     total = total_bytes(parts)
-    if total <= BUDGET_BYTES:
+    if total <= budget:
         return
     mb = 2 ** 20
     items = ", ".join(f"{k}={v / mb:.2f} MB"
@@ -73,21 +116,20 @@ def preflight(parts: Mapping[str, int], where: str) -> None:
                                          key=lambda kv: -kv[1]))
     raise ValueError(
         f"{where}: estimated VMEM {total / mb:.2f} MB exceeds the "
-        f"{BUDGET_BYTES / mb:.0f} MB admission budget "
-        f"(hard per-core limit {LIMIT_BYTES / mb:.0f} MB, remainder is "
-        f"Mosaic temporary headroom). Breakdown: {items}. "
+        f"{budget / mb:.0f} MB admission budget. Breakdown: {items}. "
         f"Shrink the tile or shard the input instead of letting Mosaic "
         f"hit a raw scoped-VMEM OOM (BASELINE.md 'Query tile 512')."
     )
 
 
-def choose_rows(ladder, w: int, parts_fn) -> int | None:
+def choose_rows(ladder, w: int, parts_fn,
+                budget: int = SCAN_LIMIT_BYTES) -> int | None:
     """Generic row-tile admission ladder shared by the scan-body kernels.
 
     Walks ``ladder`` (descending TH candidates) and returns the first
     tile height that is sublane-aligned for the flattened ``(th*w, C)``
     view (``(th * w) % 8 == 0``) and whose ``parts_fn(th)`` estimate
-    ``fits`` the admission budget; ``None`` if no rung admits (caller
+    ``fits`` the scan-body budget; ``None`` if no rung admits (caller
     falls back to the XLA path via ``log_fallback``).  Larger tiles
     amortize weight-stationary reuse across more rows, so the ladder is
     ordered biggest-first and the *first* admitted rung wins.
@@ -95,101 +137,40 @@ def choose_rows(ladder, w: int, parts_fn) -> int | None:
     for th in ladder:
         if (th * w) % 8:
             continue
-        if fits(parts_fn(th)):
+        if fits(parts_fn(th), budget):
             return th
     return None
 
 
-def step_vmem_parts(h_img: int, w: int, cc: int, th: int,
-                    dtype_bytes: int, *,
-                    flow_head: bool = False,
-                    c: int = 128, cinp: int = 128,
-                    motion_widths=(256, 192, 128, 64, 126),
-                    fh_hidden: int = 256,
-                    halo_motion: int = 5, halo_gru: int = 4,
-                    halo_flow_head: int = 2) -> dict:
-    """Named VMEM estimate for the fused one-launch scan-body kernel
-    (``step_pallas``: motion encoder → SepConvGRU, optionally + flow
-    head) at row tile ``th``.
+def scan_rows_parts(kind: str, rows: int, dtype_bytes: int,
+                    channel_scale: float = 1.0) -> dict:
+    """Named scoped-VMEM estimate of one scan-body launch whose working
+    span is ``rows`` flattened assembly rows (tile + halos, times W).
 
-    Unlike the single-kernel estimates, this models *phase-peak*
-    liveness: the chain's conv phases run sequentially over the same
-    row span, so the working set is the LARGEST single phase (its
-    input operand(s), one shifted copy, and its f32 accumulator), not
-    the sum of every intermediate — summing all of them would reject
-    every flagship shape and make the fused kernel pointless.  What
-    stays resident *across* phases (the packed ``[motion‖flow]`` x
-    part, and ``h2`` into the flow head) is charged separately in
-    ``cross_phase_residents``.
-
-    Input windows are charged per neighbor block: the combined
-    receptive field needs ``ceil(halo/th)`` neighbor blocks per side,
-    so small tiles pay for more blocks but far smaller assemblies —
-    which is why TH=4 admits Sintel bf16 while TH=8 does not.
-    """
-    d = dtype_bytes
-    c1, c2, f1, f2, co = motion_widths
-    hg = halo_gru + (halo_flow_head if flow_head else 0)
-    hm = hg + halo_motion
-    g = th * w
-    nm = -(-hm // th)                    # neighbor blocks/side, motion span
-    ng = -(-hg // th)                    # neighbor blocks/side, GRU span
-    rows_m = (th + 2 * hm) * w
-    rows_g = (th + 2 * hg) * w
-    cxm = co + 2                         # the [motion‖flow] packed x part
-    taps = 5                             # SepConv 1x5/5x1 tap count
-    weight_elems = (
-        # motion chain (matches motion_pallas.pack_weights)
-        cc * c1 + 9 * c1 * c2 + 49 * 2 * f1 + 9 * f1 * f2
-        + 9 * (c2 + f2) * co + c1 + c2 + f1 + f2 + co
-        # GRU: 2 sepconv steps x 5 taps x (c+cinp+cxm) in x 3c out + biases
-        + 2 * taps * (c + cinp + cxm) * 3 * c + 2 * 3 * c)
-    if flow_head:
-        weight_elems += 9 * c * fh_hidden + 9 * fh_hidden * 2 + fh_hidden + 2
-    # Per-row live bytes of each sequential phase (operands + shifted
-    # copy + f32 accumulator); the peak phase is motion's convc2.
-    m_phases = (
-        cc * d + 2 * d + c1 * 4,                            # convc1 (1x1)
-        2 * d + 2 * c1 * d + c2 * 4,                        # convc2 (peak)
-        2 * d + c2 * d + 2 * 2 * d + f1 * 4,                # convf1 (7x7)
-        2 * d + c2 * d + 2 * f1 * d + f2 * 4,               # convf2
-        2 * d + c2 * d + f2 * d + max(c2, f2) * d + co * 4,  # conv (cat)
-    )
-    ops_b = (c + cinp + cxm) * d
-    shift_b = max(c, cinp, cxm) * d
-    g_phases = (
-        ops_b + shift_b + 2 * c * 4,                        # zr1 / zr2
-        ops_b + 3 * c * d + shift_b + c * 4,                # q1 / q2
-    )
-    peaks = [rows_m * max(m_phases), rows_g * max(g_phases)]
-    cross = rows_g * cxm * d             # [motion‖flow] held through GRU
-    out_bytes = g * c * d
-    if flow_head:
-        peaks.append(rows_g * (2 * c * d + fh_hidden * 4))
-        cross += rows_g * c * d          # h2 held into the flow head
-        out_bytes += g * 2 * d
-    return {
-        "corr_blocks": (2 * nm + 1) * g * cc * d,
-        "flow_blocks": (2 * nm + 1) * g * 2 * d,
-        "net_blocks": (2 * ng + 1) * g * c * d,
-        "inp_blocks": (2 * ng + 1) * g * cinp * d,
-        "out_blocks": out_bytes,
-        "weights": weight_elems * d,
-        "intermediates_phase_peak": max(peaks),
-        "cross_phase_residents": cross,
-    }
+    What Mosaic puts on its stack for these kernels is proportional to
+    the assembly span and nothing else that varies at run time — block
+    windows, weights and the live intermediates all scale with it. The
+    earlier shape-arithmetic estimates ("phase-peak liveness")
+    under-counted Mosaic 1.5x (GRU) to 4.7x (motion) and admitted tiles
+    the compiler then refused, so the estimate is now Mosaic's own
+    per-row figure from ``_ROW_BYTES``; ``tests/test_chip_compile.py``
+    compiles the admitted tiles for the chip, compares the figure with
+    what the compiler used, and is the judge of it. ``channel_scale`` stretches the figure
+    for a GRU wider than the calibrated C=128/Cx=256."""
+    return {"assembly_rows_live":
+            int(rows * _ROW_BYTES[kind][dtype_bytes] * channel_scale)}
 
 
-def log_fallback(flag: str, shape: str, parts: Mapping[str, int]) -> None:
+def log_fallback(flag: str, shape: str, parts: Mapping[str, int],
+                 budget: int = SCAN_LIMIT_BYTES) -> None:
     """One loud structured line when ``<flag>=auto`` rejects a TPU launch
     and falls back to the XLA path — the estimate that failed admission,
-    at the kernel's smallest tile, against the budget and hard limit.
-    Called at trace time (once per compiled shape, not per step)."""
+    at the kernel's smallest tile, against the budget. Called at trace
+    time (once per compiled shape, not per step)."""
     mb = 2 ** 20
     _LOG.warning(
         "%s=auto: falling back to the XLA path for shape %s — smallest-"
-        "tile VMEM estimate %.2f MB exceeds the %.0f MB admission budget "
-        "(hard per-core limit %.0f MB). Set %s=0 to silence, or use a "
-        "narrower dtype/shape to admit the fused kernel.",
-        flag, shape, total_bytes(parts) / mb, BUDGET_BYTES / mb,
-        LIMIT_BYTES / mb, flag)
+        "tile VMEM estimate %.2f MB exceeds the %.0f MB admission "
+        "budget. Set %s=0 to silence, or use a narrower shape to admit "
+        "the fused kernel.",
+        flag, shape, total_bytes(parts) / mb, budget / mb, flag)

@@ -22,17 +22,6 @@ import numpy as np
 from raft_tpu.resilience import all_hosts_agree
 
 
-def _distributed_initialized() -> bool:
-    """Whether ``jax.distributed.initialize`` already ran, without
-    touching any device API. ``jax.distributed.is_initialized`` only
-    exists on newer jax; older versions expose the same fact through
-    the coordinator client's global state."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    from jax._src import distributed as _dist
-    return getattr(_dist.global_state, "client", None) is not None
-
-
 def init_distributed(coordinator_address: Optional[str] = None,
                      num_processes: Optional[int] = None,
                      process_id: Optional[int] = None) -> None:
@@ -49,7 +38,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
     make ``jax.distributed.initialize`` unconditionally fail — the
     coordinator client state is inspected instead.
     """
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return  # already initialized
     env = os.environ
     if coordinator_address is None:

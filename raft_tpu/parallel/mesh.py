@@ -21,25 +21,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DATA_AXIS = "data"
 SPATIAL_AXIS = "spatial"
 
-# jax-version-compatible shard_map: one shim shared by every caller
-# (ring_corr, the banded-kernel composition) so the next jax API move
-# is fixed in exactly one place. The replication-check kwarg is
-# detected from the function's OWN signature, not the import location —
-# jax exported top-level shard_map (0.4.35) long before renaming
-# check_rep → check_vma (0.8), so import location alone misclassifies
-# every version in between.
-try:                                    # jax>=0.4.35 top-level export
-    from jax import shard_map
-except ImportError:                     # older: experimental location
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-_SM_PARAMS = _inspect.signature(shard_map).parameters
-SHARD_MAP_NOCHECK = ({"check_vma": False} if "check_vma" in _SM_PARAMS
-                     else {"check_rep": False})
-
-
 def make_mesh(n_data: Optional[int] = None, n_spatial: int = 1,
               devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """Build a ``(data, spatial)`` mesh.

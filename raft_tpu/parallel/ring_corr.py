@@ -30,12 +30,12 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from raft_tpu.models.corr import pyramid_lookup
 from raft_tpu.ops.sampling import avg_pool2x2
-from raft_tpu.parallel.mesh import SPATIAL_AXIS, shard_map
+from raft_tpu.parallel.mesh import SPATIAL_AXIS
 
 
 def _ring_volume(fmap1: jnp.ndarray, fmap2: jnp.ndarray, n_shards: int,

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import logging
 from typing import Any, Callable, Optional
 
 import jax
@@ -27,6 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from raft_tpu.parallel.mesh import DATA_AXIS, SPATIAL_AXIS
+
+_LOG = logging.getLogger(__name__)
 
 # Trace-time spatial-mesh context (round 5, VERDICT r4 #2): XLA's SPMD
 # partitioner cannot split a Pallas custom call, so under compiler-
@@ -59,6 +62,31 @@ def spatial_kernel_mesh(mesh: Optional[Mesh]):
 
 def current_spatial_kernel_mesh() -> Optional[Mesh]:
     return _SPATIAL_KERNEL_MESH.get()
+
+
+def keeps_xla_under_partitioning(flag: str, mode: str) -> bool:
+    """Static dispatch rule for the Mosaic kernels that carry no
+    ``shard_map`` wrapper (the scan-body kernels: GRU, motion, fused
+    step). Under a kernel mesh of more than one device the program is
+    partitioned by GSPMD, which refuses them ("Mosaic kernels cannot be
+    automatically partitioned"); the correlation kernel has its wrapper
+    (``models.corr._sharded_fused_lookup``), these do not yet. Called on
+    a TPU backend at trace time: returns True when the caller must keep
+    its XLA path (said once per traced program, at WARNING); a forced
+    ``<flag>=1`` raises instead of degrading."""
+    mesh = current_spatial_kernel_mesh()
+    if mesh is None or mesh.size == 1:
+        return False
+    if mode == "1":
+        raise ValueError(
+            f"{flag}=1 but the model is traced over a {dict(mesh.shape)} "
+            f"mesh: this kernel has no shard_map wrapper and GSPMD cannot "
+            f"partition a Mosaic kernel; use auto (XLA path) on a mesh")
+    _LOG.warning(
+        "%s=auto: keeping the XLA path — the model is traced over a %s "
+        "mesh and this kernel has no shard_map wrapper (GSPMD cannot "
+        "partition a Mosaic kernel)", flag, dict(mesh.shape))
+    return True
 
 
 def image_spec(shard_batch: bool = True) -> P:

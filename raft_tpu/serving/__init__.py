@@ -79,9 +79,7 @@ from raft_tpu.serving.batcher import (PRIORITIES, PRIORITY_HIGH,
                                       ShapeBucketBatcher)
 from raft_tpu.serving.brownout import BrownoutController
 from raft_tpu.serving.engine import (WIRE_F32, WIRE_U8, ServingConfig,
-                                     ServingEngine,
-                                     enable_persistent_compile_cache,
-                                     make_engine, request_wire,
+                                     ServingEngine, make_engine, request_wire,
                                      upsample_flow, wire_cast)
 from raft_tpu.serving.fleet import (BucketRouter, FleetMetrics,
                                     FleetReloadConfig, FleetReloader,
@@ -153,7 +151,6 @@ __all__ = [
     "WorkerSpec",
     "WorkerSupervisor",
     "default_lease_store",
-    "enable_persistent_compile_cache",
     "is_routable",
     "load_step_variables",
     "make_engine",

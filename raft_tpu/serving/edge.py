@@ -120,7 +120,7 @@ _REQUEST_ID_CHARS = frozenset(
 
 def _parse_request_id(headers: Dict[str, str]) -> Optional[str]:
     """Validate an optional ``X-Request-Id``; malformed → 400 per the
-    taxonomy, absent → ``None`` (the edge mints one)."""
+    classification, absent → ``None`` (the edge mints one)."""
     raw = headers.get("x-request-id", "").strip()
     if not raw:
         return None
@@ -152,7 +152,7 @@ class _Reject(Exception):
 
 
 def classify_error(exc: BaseException) -> Tuple[int, str]:
-    """The typed taxonomy mapping every gateway outcome to an HTTP
+    """The typed classification mapping every gateway outcome to an HTTP
     status + error class. ``RequestTimedOut`` is the spent budget
     (504), ``EngineUnhealthy`` the fleet saying no (503), and
     ``BacklogFull`` — whether raised directly or surfaced as the
@@ -283,6 +283,9 @@ class EdgeServer:
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
         self._inflight = 0          # proxied requests in flight
+        #: Open client connections, so the drain can close the ones
+        #: still lingering at its deadline.
+        self._conns: set = set()
         self._buckets: Dict[str, TokenBucket] = {}
         self._draining = False
         self._closed = False
@@ -333,9 +336,11 @@ class EdgeServer:
         self._event("unready")
         if self.config.drain_grace_s:
             await asyncio.sleep(self.config.drain_grace_s)
+        # Close the listener only. ``wait_closed()`` also waits for every
+        # open connection (Python >= 3.12.1), so awaiting it here would
+        # let one wedged request hold the drain past its deadline.
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
         self._event("listener_closed")
         bound = (self.config.drain_timeout_s
                  if drain_timeout_s is None else drain_timeout_s)
@@ -347,6 +352,8 @@ class EdgeServer:
                            "still in flight", self._inflight)
         self._event("edge_drained")
         self._closed = True
+        for writer in list(self._conns):
+            writer.close()
         try:
             self.gateway.close()
         except Exception:
@@ -453,6 +460,7 @@ class EdgeServer:
 
     async def _serve_conn(self, reader: asyncio.StreamReader,
                           writer: asyncio.StreamWriter) -> None:
+        self._conns.add(writer)
         try:
             while not self._closed:
                 keep_alive = await self._serve_one(reader, writer)
@@ -464,6 +472,7 @@ class EdgeServer:
         except Exception:
             logger.exception("edge connection handler failed")
         finally:
+            self._conns.discard(writer)
             try:
                 writer.close()
             except Exception:
@@ -804,7 +813,7 @@ class EdgeServer:
                              rej: _Reject,
                              counted: bool = False,
                              request_id: Optional[str] = None) -> None:
-        """One JSON error frame per the taxonomy table; closes the
+        """One JSON error frame per the classification table; closes the
         connection (the caller returns False). ``counted`` marks
         rejections whose class counter the caller already ticked;
         ``request_id`` is echoed so a client can retry the same key."""

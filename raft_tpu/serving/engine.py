@@ -30,8 +30,9 @@ mechanisms behind one ``submit() -> Future`` API:
 * **Warmup + persistent compile cache** — ``warmup()`` pre-compiles the
   executable for every configured bucket (counted by the
   :class:`~raft_tpu.serving.metrics.CompileWatch` probe), and
-  :func:`enable_persistent_compile_cache` points XLA's on-disk cache at
-  the repo's ``.jax_cache/`` (the same wiring bench.py uses) so a
+  :func:`raft_tpu.utils.compile_cache.enable_compile_cache` turns on
+  XLA's on-disk cache (``$JAX_COMPILATION_CACHE_DIR``, else the
+  checkout's ``.jax_cache/`` — the one wiring every entry point uses) so a
   serving process restart pays seconds, not minutes, before its first
   request. The zero-compile contract extends over the trace-time kernel
   flags (``RAFT_CORR_BACKEND``/``RAFT_CORR_BAND``, ``RAFT_GRU_PALLAS``):
@@ -88,7 +89,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 import queue
 import threading
 import time
@@ -108,36 +108,13 @@ from raft_tpu.serving.brownout import BrownoutController
 from raft_tpu.serving.health import CircuitBreaker, EngineUnhealthy
 from raft_tpu.serving.metrics import (CompileWatch, ServingMetrics,
                                       xla_compile_count)
+from raft_tpu.utils.compile_cache import enable_compile_cache
 from raft_tpu.utils.padder import InputPadder
 from raft_tpu.utils.profiling import HostStageTimer
 
 # Shared no-op context for `with <stage>, <maybe-span>:` sites — the
 # disabled-tracing path must not allocate a context manager per batch.
 _NULL = contextlib.nullcontext()
-
-_REPO_ROOT = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
-
-
-def enable_persistent_compile_cache(cache_dir: Optional[str] = None) -> str:
-    """Point XLA's persistent compilation cache at ``cache_dir``.
-
-    Defaults to ``$JAX_COMPILATION_CACHE_DIR`` or the repo's
-    ``.jax_cache/`` (bench.py's location, so serving and bench share
-    warm entries). Min-compile-time/entry-size floors drop to zero so
-    every bucket executable is cached. Call before the first compile to
-    benefit the current process; later calls still help restarts.
-    Returns the directory used."""
-    import jax
-
-    cache_dir = (cache_dir
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                 or os.path.join(_REPO_ROOT, ".jax_cache"))
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    return cache_dir
-
 
 # -- wire format ---------------------------------------------------------
 #
@@ -350,8 +327,11 @@ class ServingConfig:
       donate: donate input image buffers to the executable. ``None``
         resolves to True on TPU, False elsewhere (CPU/older backends
         warn and ignore donation).
-      persistent_cache: falsy → leave XLA's cache config alone; True →
-        wire the default location; a string → wire that directory.
+      persistent_cache: falsy → leave XLA's cache config alone; truthy
+        → ``enable_compile_cache()``: ``$JAX_COMPILATION_CACHE_DIR`` if
+        set, else the checkout's ``.jax_cache/`` (a string value is
+        accepted for old configs and means the same as True — the
+        directory is placed from outside, never from code).
       breaker_threshold: consecutive dispatch/sync failures that trip
         the circuit breaker OPEN (submit then fails fast with
         :class:`~raft_tpu.serving.health.EngineUnhealthy`).
@@ -684,9 +664,7 @@ class ServingEngine:
         self.predictor = predictor
         self.config = config or ServingConfig()
         if self.config.persistent_cache:
-            cache = self.config.persistent_cache
-            enable_persistent_compile_cache(
-                cache if isinstance(cache, str) else None)
+            enable_compile_cache()
         donate = self.config.donate
         if donate is None:
             donate = jax.default_backend() == "tpu"

@@ -65,6 +65,17 @@ gateway's per-hop stall deadline can catch it).
 until SIGTERM; :func:`spawn_worker` is the supervisor-side launcher
 (plain ``subprocess.Popen`` with the parent's environment —
 ``JAX_PLATFORMS`` and the fault-injection env vars inherit).
+
+**One worker process per chip.** A TPU chip belongs to one process at a
+time: the first process that touches JAX holds it, and another that
+needs it then fails or hangs. So a chip host runs exactly one worker
+process per chip, and the processes that only route — the supervisor,
+the gateway and the edge — must never initialise a backend (they do not
+import jax at all; ``tests/test_chip_smoke.py`` checks it). Known gap,
+recorded and not yet fixed: nothing here pins a worker to a device, so
+on a four-chip host every ``spawn_worker`` child (and every in-process
+``ServingFleet`` replica) lands on chip 0; ROADMAP Design 2 decides that
+fabric's fate on measurements.
 """
 
 from __future__ import annotations

@@ -484,6 +484,8 @@ def main(argv=None):
     parser.add_argument("--log_dir", default="runs")
     parser.add_argument("--seed", type=int, default=2022)
     args = parser.parse_args(argv)
+    from raft_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     evaluate.reject_raft_only_flags(parser, args)   # incl. --iters
     # only the keypoint families consume the auxiliary sparse loss

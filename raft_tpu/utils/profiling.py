@@ -201,23 +201,39 @@ def op_breakdown(logdir: str) -> List[Tuple[str, float, int]]:
     return _collect_ops(logdir)[0]
 
 
+#: Published dense bf16 peak per chip, TFLOP/s, keyed by
+#: ``jax.Device.device_kind``. Source: Google Cloud TPU documentation,
+#: "TPU v5e" system architecture page (197 TFLOP/s bf16, 819 GB/s HBM).
+#: A device kind that is not listed is an error, never a default.
+PEAK_BF16_TFLOPS = {
+    "TPU v5 lite": 197.0,
+    "TPU v5e": 197.0,
+}
+
+
 def peak_tflops() -> Optional[float]:
     """MFU denominator in TFLOP/s: ``RAFT_PEAK_TFLOPS`` env override
-    (accepts any float; ``0``/empty = unknown), else 197 — TPU v5e bf16
-    peak per chip — when the default jax backend is a TPU, else ``None``
-    (unknown; MFU columns are suppressed rather than guessed)."""
+    (accepts any float; ``0``/empty = unknown), else the published bf16
+    peak of the default device's ``device_kind`` from
+    ``PEAK_BF16_TFLOPS``. ``None`` off-TPU (unknown; MFU columns are
+    suppressed rather than guessed); a TPU whose kind is not in the
+    table raises ``KeyError`` instead of borrowing another chip's
+    peak."""
     raw = os.environ.get("RAFT_PEAK_TFLOPS", "")
     if raw:
         v = float(raw)
         return v if v > 0 else None
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() == "tpu":
-            return 197.0
-    except Exception:  # pragma: no cover - no jax / no backend
-        pass
-    return None
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    if dev.device_kind not in PEAK_BF16_TFLOPS:
+        raise KeyError(
+            f"no published peak for device kind {dev.device_kind!r}; add "
+            f"it to raft_tpu.utils.profiling.PEAK_BF16_TFLOPS with its "
+            f"source, or set RAFT_PEAK_TFLOPS")
+    return PEAK_BF16_TFLOPS[dev.device_kind]
 
 
 def _event_flops(plane, ev, stat_names) -> int:

@@ -18,15 +18,13 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
 import jax
 import jax.numpy as jnp
+
+from raft_tpu.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 _res = os.environ.get("RAFT_KNEE_RES", "440,1024").split(",")
 if len(_res) != 2:
@@ -47,7 +45,6 @@ BATCHES = tuple(int(b) for b in
 def main():
     from raft_tpu.config import RAFTConfig
     from raft_tpu.models.raft import RAFT
-    from raft_tpu.ops.corr_pallas import run_with_band_retry
 
     rng = jax.random.PRNGKey(0)
     img1 = jax.random.uniform(rng, (1, H, W, 3), jnp.float32) * 255.0
@@ -82,16 +79,7 @@ def main():
                 rate = REPS * batch / (time.perf_counter() - t0)
                 out[f"{name}_b{batch}_pairs_per_sec"] = round(rate, 2)
 
-            if alt:
-                if not run_with_band_retry(arm, out, f"{name}_b{batch}"):
-                    break               # OOM/compile wall: stop climbing
-            else:
-                try:
-                    arm()
-                except Exception as e:
-                    out[f"{name}_b{batch}_error"] = \
-                        f"{type(e).__name__}: {e}"
-                    break
+            arm()               # an OOM or compile error ends the sweep
     print(json.dumps(out))
 
 

@@ -217,3 +217,43 @@ def test_profiling_trace_and_breakdown(tmp_path):
     assert rows, "no ops parsed from the trace"
     names = [name for name, _, _ in rows]
     assert any("dot" in n for n in names), names
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", None), ("tpu", "TPU v5 lite", 197.0),
+    ("tpu", "TPU v99", KeyError)])
+def test_peak_tflops_is_keyed_by_device_kind(monkeypatch, platform, kind,
+                                             want):
+    """The MFU denominator comes from the device kind's published peak;
+    a TPU that is not in the table is an error, never another chip's
+    number."""
+    import types
+
+    import jax
+
+    from raft_tpu.utils import profiling
+    monkeypatch.delenv("RAFT_PEAK_TFLOPS", raising=False)
+    monkeypatch.setattr(jax, "devices", lambda: [types.SimpleNamespace(
+        platform=platform, device_kind=kind)])
+    if want is KeyError:
+        with pytest.raises(KeyError, match="TPU v99"):
+            profiling.peak_tflops()
+    else:
+        assert profiling.peak_tflops() == want
+
+
+def test_kernel_census_counts_named_mosaic_kernels():
+    from raft_tpu.ops.layout import kernel_census
+    def call(op_name):
+        return ('custom-call(%x), custom_call_target="tpu_custom_call", '
+                'metadata={op_name="' + op_name + '"}')
+
+    text = "\n".join([
+        call("jit(run)/RAFT/while/body/update/raft_corr_fwd/pallas_call"),
+        call("jit(run)/RAFT/while/body/update_block/raft_step/pallas_call"),
+        call("jit(step)/transpose(jvp(raft_corr_bwd))/pallas_call"),
+        call("jit(f)/pallas_call"),
+        'fusion(%y), kind=kLoop, metadata={op_name="raft_gru"}'])
+    assert kernel_census(text) == {"corr_fwd": 1, "step": 1, "corr_bwd": 1,
+                                   "unnamed": 1}
+    assert kernel_census("HloModule cpu_only") == {}

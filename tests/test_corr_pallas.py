@@ -10,7 +10,6 @@ On CPU the kernel runs in Pallas interpreter mode; the identical code path
 compiles on TPU.
 """
 
-import os
 
 import jax
 import jax.numpy as jnp
@@ -216,7 +215,7 @@ def test_band_mode_gradients_agree(rng):
         np.testing.assert_array_equal(np.asarray(b), np.asarray(c))
 
 
-def test_band_resolve_and_retry_ladder(monkeypatch):
+def test_band_resolve(monkeypatch):
     from raft_tpu.ops import corr_pallas as cp
     # env resolution
     monkeypatch.delenv("RAFT_CORR_BAND", raising=False)
@@ -229,30 +228,6 @@ def test_band_resolve_and_retry_ladder(monkeypatch):
     assert cp._resolve_band(False) == "off"
     with pytest.raises(ValueError):
         cp._resolve_band("banded")
-    # retry ladder: dynamic fails -> static fails -> off succeeds
-    monkeypatch.delenv("RAFT_CORR_BAND", raising=False)
-    calls = []
-
-    def run():
-        mode = os.environ["RAFT_CORR_BAND"]
-        calls.append(mode)
-        if mode != "0":
-            raise RuntimeError(f"boom {mode}")
-
-    rec = {}
-    assert cp.run_with_band_retry(run, rec, "arm") is True
-    assert calls == ["1", "static", "0"]
-    assert rec["arm_band"] == "off"
-    assert "arm_band_dynamic_error" in rec
-    assert "arm_band_static_error" in rec
-    assert "RAFT_CORR_BAND" not in os.environ
-    # operator-forced static start skips the dynamic rung
-    monkeypatch.setenv("RAFT_CORR_BAND", "static")
-    calls.clear()
-    rec2 = {}
-    assert cp.run_with_band_retry(run, rec2, "arm") is True
-    assert calls == ["static", "0"]
-    assert os.environ["RAFT_CORR_BAND"] == "static"
 
 
 def test_fused_multilevel_gradients(rng):

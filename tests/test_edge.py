@@ -1,5 +1,5 @@
 """The HTTP edge: admission control, deadline propagation, abuse
-hardening, the typed error taxonomy, and coordinated graceful shutdown.
+hardening, the typed error classification, and coordinated graceful shutdown.
 
 Admission and shutdown semantics run against a FAKE gateway (recorded
 ``submit`` calls are the never-reached-the-gateway needle) and, where
@@ -158,9 +158,9 @@ class TestTokenBucket:
         assert not b.acquire()[0]
 
 
-# -- the error taxonomy --------------------------------------------------
+# -- the error classification --------------------------------------------------
 
-class TestTaxonomy:
+class TestErrorClasses:
     @pytest.mark.parametrize("exc,status,cls", [
         (RequestTimedOut("budget spent"), 504, "timeout"),
         (EngineUnhealthy("no fleet"), 503, "engine_unhealthy"),
@@ -173,7 +173,7 @@ class TestTaxonomy:
             self, exc, status, cls):
         assert classify_error(exc) == (status, cls)
 
-    def test_gateway_error_rides_the_taxonomy_to_the_client(self):
+    def test_gateway_error_rides_the_error_table_to_the_client(self):
         gw = FakeGateway()
         gw.resolve_with = EngineUnhealthy("no live lease-holder")
         es = _edge(gw)
@@ -331,7 +331,7 @@ class TestDeadlines:
 # -- abuse hardening -----------------------------------------------------
 
 class TestAbuse:
-    def test_malformed_taxonomy(self):
+    def test_malformed_error_class(self):
         gw = FakeGateway()
         es = _edge(gw)
         try:

@@ -257,31 +257,52 @@ class TestEligibility:
 
     def test_sintel_admission_table(self):
         """The pinned envelope at Sintel-eval feature shapes (H=55,
-        W=128, Ccorr=4*81=324) under the round-10 phase-peak liveness
-        model: bf16 rides the TH=16 rung; f32 — which the old
-        sum-of-intermediates estimate rejected outright — honestly
-        admits TH=4 (the multi-neighbor halo assembly this round added
-        makes halo 5 > th legal). A wider f32 shape still fits no tile
-        and falls back loudly (see the fallback-log test)."""
+        W=128, Ccorr=4*81=324) under the explicit 100 MiB scoped limit
+        and the Mosaic-calibrated estimate: bf16 rides the TH=16 rung
+        (Mosaic takes up to 73 MiB), f32 TH=8 (89 MiB at TH=16 is too
+        close); a 1080p-wide map steps down to TH=4; a 4K-wide map fits
+        no tile and falls back loudly (see the fallback-log test); a
+        corr window wider than the calibrated 384 lanes keeps the conv
+        path."""
         assert motion_pallas.choose_rows(55, 128, 324, 2) == 16
-        assert motion_pallas.choose_rows(55, 128, 324, 4) == 4
-        assert motion_pallas.choose_rows(55, 256, 324, 4) is None
+        assert motion_pallas.choose_rows(55, 128, 324, 4) == 8
+        assert motion_pallas.choose_rows(135, 240, 324, 2) == 4
+        assert motion_pallas.choose_rows(55, 512, 324, 4) is None
         assert motion_pallas.motion_eligible(55, 128, 324, jnp.bfloat16,
                                              False)
         assert motion_pallas.motion_eligible(55, 128, 324,
                                              jnp.float32, False)
-        assert not motion_pallas.motion_eligible(55, 256, 324,
+        assert not motion_pallas.motion_eligible(55, 512, 324,
                                                  jnp.float32, False)
+        assert not motion_pallas.motion_eligible(55, 128, 4 * 121,
+                                                 jnp.bfloat16, False)
+
+    @pytest.mark.parametrize("th,dtype_bytes,mosaic_mib", [
+        (16, 2, 72.98), (8, 2, 47.49), (4, 2, 28.33), (8, 4, 40.59)])
+    def test_estimate_covers_what_mosaic_reported(self, th, dtype_bytes,
+                                                  mosaic_mib):
+        """The phase-peak estimate admitted the Sintel bf16 TH=16 tile
+        at 11.7 MiB under a 13 MiB budget; Mosaic put 53.8 MiB on its
+        stack and refused it under the 16 MiB default (and takes 73 MiB
+        when nothing limits it). The calibrated estimate is at least
+        what the compiler reported at every probed tile, and the tile
+        is now admitted only because the launch carries the explicit
+        limit."""
+        est = vmem.total_bytes(
+            motion_pallas.motion_vmem_parts(55, 128, 324, th, dtype_bytes))
+        assert mosaic_mib * 2**20 <= est <= vmem.SCAN_LIMIT_BYTES
+        assert est > vmem.LIMIT_BYTES
 
     def test_preflight_raises_itemized(self):
         """An inadmissible forced launch dies in the shared VMEM
         preflight with the requested-vs-budget breakdown, not a Mosaic
         scoped-VMEM OOM."""
-        parts = motion_pallas.motion_vmem_parts(55, 128, 324, 8, 4)
-        assert not vmem.fits(parts)
+        parts = motion_pallas.motion_vmem_parts(55, 512, 324, 8, 4)
+        assert not vmem.fits(parts, vmem.SCAN_LIMIT_BYTES)
         with pytest.raises(ValueError, match="admission budget") as ei:
-            vmem.preflight(parts, "fused motion encoder (test)")
-        assert "intermediates" in str(ei.value)
+            vmem.preflight(parts, "fused motion encoder (test)",
+                           vmem.SCAN_LIMIT_BYTES)
+        assert "assembly_rows_live" in str(ei.value)
 
     def test_motion_encoder_preflights_real_launches(self, motion_setup):
         """motion_encoder(interpret=False) trips the preflight before
@@ -302,24 +323,24 @@ class TestEligibility:
         the flag, shape and budget — never a silent conv fallback."""
         monkeypatch.delenv("RAFT_MOTION_PALLAS", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        # Sintel f32 now admits a TH=4 tile (phase-peak model), so the
-        # rejection shape is a wider f32 map that genuinely overflows.
-        flow = jax.ShapeDtypeStruct((1, 55, 256, 2), jnp.float32)
-        corr = jax.ShapeDtypeStruct((1, 55, 256, 324), jnp.float32)
+        # Sintel shapes admit TH=16, so the rejection shape is a
+        # 4K-wide f32 map that overflows even the smallest tile.
+        flow = jax.ShapeDtypeStruct((1, 55, 512, 2), jnp.float32)
+        corr = jax.ShapeDtypeStruct((1, 55, 512, 324), jnp.float32)
         with caplog.at_level(logging.WARNING, logger="raft_tpu.ops.vmem"):
             assert not motion_pallas.should_fuse(flow, corr)
         assert "RAFT_MOTION_PALLAS=auto" in caplog.text
         assert "falling back to the XLA path" in caplog.text
-        assert "H=55, W=256, Ccorr=324" in caplog.text
+        assert "H=55, W=512, Ccorr=324" in caplog.text
         assert "admission budget" in caplog.text
 
     def test_auto_fallback_is_logged_gru(self, monkeypatch, caplog):
-        """Same hook for the round-6 kernel (this round retrofits the
-        logging): an f32 Sintel-shape rejection is announced."""
+        """Same hook for the round-6 kernel: an f32 rejection (a map
+        too wide for even the smallest tile) is announced."""
         monkeypatch.delenv("RAFT_GRU_PALLAS", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        h = jax.ShapeDtypeStruct((1, 55, 128, 128), jnp.float32)
-        x = jax.ShapeDtypeStruct((1, 55, 128, 256), jnp.float32)
+        h = jax.ShapeDtypeStruct((1, 55, 1024, 128), jnp.float32)
+        x = jax.ShapeDtypeStruct((1, 55, 1024, 256), jnp.float32)
         with caplog.at_level(logging.WARNING, logger="raft_tpu.ops.vmem"):
             assert not gru_pallas.should_fuse(h, x, 128)
         assert "RAFT_GRU_PALLAS=auto" in caplog.text

@@ -122,3 +122,20 @@ def test_resize_by_scale_factor_matches_cv2_fx_fy(img, scales):
     ref = cv2.resize(img, None, fx=fx, fy=fy,
                      interpolation=cv2.INTER_LINEAR)
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-3)
+
+
+def test_library_is_named_by_source_content_inside_the_checkout(monkeypatch):
+    """A binary built from another tree's augment.cpp has another name,
+    so a stale one can never be loaded; with no override the build lands
+    in the git-ignored raft_tpu/native/_build/."""
+    import hashlib
+    import os
+
+    from raft_tpu.native import build
+    monkeypatch.delenv("RAFT_TPU_NATIVE_DIR", raising=False)
+    with open(build._SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    path = build.lib_path()
+    assert os.path.basename(path) == f"libraft_augment_{digest}.so"
+    assert os.path.dirname(path) == os.path.join(
+        os.path.dirname(build._SRC), "_build")

@@ -393,15 +393,30 @@ class TestWarmup:
         assert w.compiles == 0                     # nothing recompiled
         assert eng.metrics.compiles == 0
 
-    def test_persistent_cache_wiring(self, tmp_path, monkeypatch):
+    @pytest.mark.parametrize("from_env", [True, False])
+    def test_persistent_cache_wiring(self, tmp_path, monkeypatch,
+                                     from_env):
+        """The cache directory is placed from outside: with
+        JAX_COMPILATION_CACHE_DIR set the helper reports it and sets no
+        directory in code; without it, <checkout>/.jax_cache."""
         import jax
 
-        from raft_tpu.serving import enable_persistent_compile_cache
+        from raft_tpu.utils import compile_cache
         old = jax.config.jax_compilation_cache_dir
         try:
-            used = enable_persistent_compile_cache(str(tmp_path))
-            assert used == str(tmp_path)
-            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+            if from_env:
+                monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                                   str(tmp_path))
+                assert compile_cache.enable_compile_cache() == \
+                    str(tmp_path)
+                assert jax.config.jax_compilation_cache_dir == old
+            else:
+                monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR",
+                                   raising=False)
+                want = os.path.join(os.path.dirname(os.path.dirname(
+                    os.path.abspath(__file__))), ".jax_cache")
+                assert compile_cache.enable_compile_cache() == want
+                assert jax.config.jax_compilation_cache_dir == want
         finally:
             jax.config.update("jax_compilation_cache_dir", old)
 

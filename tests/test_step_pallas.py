@@ -256,22 +256,20 @@ class TestDispatch:
         assert plan(net, inp, corr, flow, True) is None
 
     def test_auto_on_tpu_steps_down_mgf_to_mg(self, monkeypatch):
-        """Sintel-eval bf16 on a (faked) TPU backend: the flow-head
+        """Sintel-eval f32 on a (faked) TPU backend: the flow-head
         depth doesn't fit, so auto honestly steps down to 'mg' instead
-        of rejecting fusion outright; a small shape admits 'mgf'."""
+        of rejecting fusion outright; Sintel bf16 admits 'mgf'."""
         monkeypatch.delenv("RAFT_STEP_PALLAS", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-        def sds(h, w, c):
-            return jax.ShapeDtypeStruct((1, h, w, c), jnp.bfloat16)
+        def sds(c, dtype):
+            return jax.ShapeDtypeStruct((1, 55, 128, c), dtype)
 
-        args = (sds(55, 128, C), sds(55, 128, C), sds(55, 128, 324),
-                sds(55, 128, 2))
+        args = tuple(sds(c, jnp.float32) for c in (C, C, 324, 2))
         assert step_pallas.plan_fusion(*args, True) == "mg"
         assert step_pallas.plan_fusion(*args, False) == "mg"
-        small = (sds(30, 64, C), sds(30, 64, C), sds(30, 64, 324),
-                 sds(30, 64, 2))
-        assert step_pallas.plan_fusion(*small, True) == "mgf"
+        half = tuple(sds(c, jnp.bfloat16) for c in (C, C, 324, 2))
+        assert step_pallas.plan_fusion(*half, True) == "mgf"
 
     def test_forced_bad_shape_raises(self, update_setup):
         _, _, net, inp, corr, _ = update_setup
@@ -282,12 +280,12 @@ class TestDispatch:
 
     def test_forced_inadmissible_on_tpu_raises(self, monkeypatch):
         """'1' on a TPU backend must never silently degrade: when even
-        the 'mg' depth fits no tile (f32 at Sintel shapes), the forced
+        the 'mg' depth fits no tile (a 1080p feature map), the forced
         arm dies loudly at trace time."""
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
         def sds(c):
-            return jax.ShapeDtypeStruct((1, 55, 128, c), jnp.float32)
+            return jax.ShapeDtypeStruct((1, 135, 240, c), jnp.float32)
 
         with pytest.raises(ValueError, match="admits no row tile"):
             step_pallas.plan_fusion(sds(C), sds(C), sds(324),
@@ -301,8 +299,8 @@ class TestDispatch:
         monkeypatch.delenv("RAFT_STEP_PALLAS", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-        def sds(c):
-            return jax.ShapeDtypeStruct((1, 55, 128, c), jnp.float32)
+        def sds(c):     # a 1080p feature map: too wide for any tile
+            return jax.ShapeDtypeStruct((1, 135, 240, c), jnp.float32)
 
         with caplog.at_level(logging.WARNING,
                              logger="raft_tpu.ops.vmem"):
@@ -310,8 +308,57 @@ class TestDispatch:
                                            sds(2), False) is None
         assert "RAFT_STEP_PALLAS=auto" in caplog.text
         assert "falling back to the XLA path" in caplog.text
-        assert "H=55, W=128" in caplog.text
+        assert "H=135, W=240" in caplog.text
         assert "admission budget" in caplog.text
+
+    @pytest.mark.multidevice
+    @pytest.mark.parametrize("kernel", ["step", "motion", "gru", "msda"])
+    def test_mesh_keeps_the_xla_path(self, kernel, monkeypatch, caplog):
+        """GSPMD cannot partition a Mosaic kernel and the scan-body
+        kernels have no shard_map wrapper: on a (faked) TPU backend
+        under a kernel mesh of more than one device, auto keeps the XLA
+        path and says so; a forced '1' raises; a one-device mesh and no
+        mesh are unaffected."""
+        from raft_tpu.parallel import make_mesh
+        from raft_tpu.parallel.spatial import spatial_kernel_mesh
+        for flag in ("RAFT_STEP_PALLAS", "RAFT_MOTION_PALLAS",
+                     "RAFT_GRU_PALLAS"):
+            monkeypatch.delenv(flag, raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+        def sds(c):
+            return jax.ShapeDtypeStruct((2, 46, 62, c), jnp.bfloat16)
+
+        def decide(mode=None):
+            if kernel == "msda":
+                from raft_tpu.ops import msda, msda_pallas
+                if mode == "1":     # only auto consults the rule
+                    raise ValueError("no shard_map wrapper")
+                monkeypatch.setattr(msda_pallas, "ms_deform_attn_pallas",
+                                    lambda *a, **k: "kernel")
+                out = msda.ms_deform_attn(
+                    jnp.zeros((1, 44 * 60, 8, 16)), ((44, 60),),
+                    jnp.zeros((1, 256, 8, 1, 4, 2)),
+                    jnp.zeros((1, 256, 8, 1, 4)))
+                return isinstance(out, str)
+            if kernel == "step":
+                return step_pallas.plan_fusion(
+                    sds(C), sds(C), sds(324), sds(2), True, mode=mode)
+            if kernel == "motion":
+                return motion_pallas.should_fuse(sds(2), sds(324),
+                                                 mode=mode)
+            return gru_pallas.should_fuse(sds(C), sds(2 * C), C, mode=mode)
+
+        assert decide()                         # no mesh: the kernel
+        with spatial_kernel_mesh(make_mesh(devices=jax.devices()[:1])):
+            assert decide()                     # one device: the kernel
+        with spatial_kernel_mesh(make_mesh(devices=jax.devices()[:2])):
+            with caplog.at_level(logging.WARNING,
+                                 logger="raft_tpu.parallel.spatial"):
+                assert not decide()
+            assert "no shard_map wrapper" in caplog.text
+            with pytest.raises(ValueError, match="shard_map wrapper"):
+                decide(mode="1")
 
     def test_bad_env_value_fails_loudly(self, monkeypatch):
         monkeypatch.setenv("RAFT_STEP_PALLAS", "on")
@@ -328,23 +375,43 @@ class TestEligibility:
 
     def test_sintel_admission_table(self):
         """The pinned envelope at Sintel-eval feature shapes (H=55,
-        W=128, Ccorr=4*81=324) under the phase-peak liveness model:
-        bf16 admits TH=4 for 'mg' only (~12.8 MiB); the flow-head depth
-        and all of f32 fit no tile — auto steps down / falls back
-        (logged) rather than OOM Mosaic."""
-        assert step_pallas.choose_rows(55, 128, 324, 2) == 4
+        W=128, Ccorr=4*81=324) under the explicit 100 MiB scoped limit
+        and the Mosaic-calibrated estimate: bf16 admits TH=8 at both
+        depths (Mosaic takes up to 72.5 / 82.8 MiB there, 95 / 106 MiB
+        at TH=16); f32 admits 'mg' at TH=4 only; at 1080p (W=240)
+        nothing admits — auto steps down / falls back (logged) rather
+        than OOM Mosaic."""
+        assert step_pallas.choose_rows(55, 128, 324, 2) == 8
         assert step_pallas.choose_rows(55, 128, 324, 2,
-                                       flow_head=True) is None
-        assert step_pallas.choose_rows(55, 128, 324, 4) is None
+                                       flow_head=True) == 8
+        assert step_pallas.choose_rows(55, 128, 324, 4) == 4
         assert step_pallas.choose_rows(55, 128, 324, 4,
                                        flow_head=True) is None
+        assert step_pallas.choose_rows(135, 240, 324, 2) is None
+
+    @pytest.mark.parametrize("th,dtype_bytes,flow_head,mosaic_mib", [
+        (4, 2, False, 45.40), (8, 2, False, 72.51),
+        (8, 2, True, 82.75), (16, 2, True, 106.01),
+        (4, 4, False, 83.47), (16, 4, False, 111.47)])
+    def test_estimate_covers_what_mosaic_reported(self, th, dtype_bytes,
+                                                  flow_head, mosaic_mib):
+        """The phase-peak estimate admitted Sintel bf16 'mg' TH=4 at
+        12.8 MiB under a 13 MiB budget; Mosaic needs 45.4 MiB. The
+        calibrated estimate is at least what the compiler reported at
+        every probed tile, and a tile Mosaic takes more than the limit
+        for (bf16 'mgf' TH=16 at 106 MiB, f32 'mg' TH=16 at 111 MiB) is
+        no longer admitted."""
+        est = vmem.total_bytes(step_pallas.step_vmem_parts(
+            128, th, dtype_bytes, flow_head=flow_head))
+        assert est >= mosaic_mib * 2**20
+        assert (est <= vmem.SCAN_LIMIT_BYTES) == (mosaic_mib < 100)
 
     def test_small_shapes_admit_deeper_fusion(self):
-        """Smaller operating points ride higher rungs and the 'mgf'
+        """Smaller operating points ride the top rung at the 'mgf'
         depth — the serving brownout ladder's shapes stay fused."""
         assert step_pallas.choose_rows(30, 64, 324, 2) == 16
         assert step_pallas.choose_rows(30, 64, 324, 2,
-                                       flow_head=True) == 8
+                                       flow_head=True) == 16
 
     def test_fused_step_preflights_real_launches(self, update_setup):
         """fused_step(interpret=False) trips the itemized VMEM
