@@ -16,6 +16,7 @@ validation (reference ``train.py:402-409``).
 
 from __future__ import annotations
 
+import itertools
 import os
 import os.path as osp
 from typing import Callable, Dict, Optional, Tuple
@@ -26,6 +27,7 @@ import numpy as np
 
 from raft_tpu.data import datasets, frame_utils
 from raft_tpu.utils.padder import InputPadder
+from raft_tpu.utils.profiling import host_timer
 from raft_tpu.utils.warm_start import forward_interpolate
 
 
@@ -457,19 +459,33 @@ class FlowPredictor:
         ``iters`` still refuses there (:meth:`_iters_fn`)."""
         if iters is None and self.mesh is not None:
             return self.sharded_dispatch(images1, images2)
-        img1 = jnp.asarray(images1)
-        img2 = jnp.asarray(images2)
-        if iters is None:
-            fn = self._fn(img1.shape, False, str(img1.dtype))
-        else:
-            fn = self._iters_fn(img1.shape, iters, str(img1.dtype))
-        return fn(self.variables, img1, img2, None)
+        timer = host_timer()
+        # predict.h2d is the host time of the two calls: no sync is
+        # added, so a transfer the runtime only enqueues ends later
+        with timer.span("predict.h2d") as span:
+            img1 = jnp.asarray(images1)
+            img2 = jnp.asarray(images2)
+            span.nbytes = img1.nbytes + img2.nbytes
+        with timer.span("predict.dispatch"):
+            if iters is None:
+                fn = self._fn(img1.shape, False, str(img1.dtype))
+            else:
+                fn = self._iters_fn(img1.shape, iters, str(img1.dtype))
+            return fn(self.variables, img1, img2, None)
 
     def predict_batch(self, images1: np.ndarray, images2: np.ndarray):
         """Batched forward: (B, H, W, 3) stacks → ((B, H/8, W/8, 2),
         (B, H, W, 2)) numpy."""
+        timer = host_timer()
         flow_low, flow_up = self.dispatch_batch(images1, images2)
-        return np.asarray(flow_low), np.asarray(flow_up)
+        # the first np.asarray would block as long; waiting here tells
+        # the device's time apart from the copy's
+        with timer.span("predict.device_wait"):
+            jax.block_until_ready((flow_low, flow_up))
+        with timer.span("predict.d2h") as span:
+            flow_low, flow_up = np.asarray(flow_low), np.asarray(flow_up)
+            span.nbytes = flow_low.nbytes + flow_up.nbytes
+        return flow_low, flow_up
 
     # ----- streaming (session) entry points -------------------------------
     # The stateless forward runs fnet twice per pair (twin-image trick).
@@ -786,41 +802,96 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
     batches would otherwise each pay a fresh XLA compile). Falls back to
     per-sample ``__call__`` for predictors without ``predict_batch``.
     ``mode``: InputPadder mode, or None when the dataset needs no padding
-    (FlyingChairs is already /8)."""
+    (FlyingChairs is already /8).
+
+    Every call is one pass in the process host timer
+    (:func:`raft_tpu.utils.profiling.host_timer`): a root span
+    ``pass.batch`` from the first fetch after the previous batch's last
+    yield to this batch's last yield (``unit``: the batch's sequence
+    number in the pass), over ``pass.fetch`` / ``pass.pad`` per sample,
+    ``pass.stack``, the predictor's ``predict.*`` spans and
+    ``pass.unpad`` per yielded sample; what no child covers is the
+    consumer's. The root reads ``complete`` 1 once all its pairs were
+    yielded, also where the consumer closes the generator at that yield.
+    """
+    from raft_tpu.serving.metrics import xla_compile_count
+
+    timer = host_timer()
     bs = getattr(predictor, "batch_size", 1)
     batched = hasattr(predictor, "predict_batch") and bs > 1
 
-    def flush(batch):
+    def flush(batch, root):
         n = len(batch)
-        if not batched:
-            for idx, sample, padder, im1, im2 in batch:
-                _, up = predictor(im1, im2)
-                yield idx, sample, padder.unpad(up) if padder else up
-            return
-        i1 = np.stack([b[3] for b in batch])
-        i2 = np.stack([b[4] for b in batch])
-        if n < bs:
-            reps = bs - n
-            i1 = np.concatenate([i1, np.repeat(i1[-1:], reps, 0)])
-            i2 = np.concatenate([i2, np.repeat(i2[-1:], reps, 0)])
-        _, up = predictor.predict_batch(i1, i2)
-        for j in range(n):
-            idx, sample, padder = batch[j][0], batch[j][1], batch[j][2]
-            yield idx, sample, padder.unpad(up[j]) if padder else up[j]
+        root.args.update(pairs=n, padded_to=bs if batched else 1,
+                         height=batch[0][3].shape[0],
+                         width=batch[0][3].shape[1])
+        if batched:
+            with timer.span("pass.stack") as span:
+                i1 = np.stack([b[3] for b in batch])
+                i2 = np.stack([b[4] for b in batch])
+                if n < bs:
+                    reps = bs - n
+                    i1 = np.concatenate([i1, np.repeat(i1[-1:], reps, 0)])
+                    i2 = np.concatenate([i2, np.repeat(i2[-1:], reps, 0)])
+                span.nbytes = i1.nbytes + i2.nbytes
+            _, up = predictor.predict_batch(i1, i2)
+        for j, (idx, sample, padder, im1, im2) in enumerate(batch):
+            flow = up[j] if batched else predictor(im1, im2)[1]
+            if padder:
+                with timer.span("pass.unpad"):
+                    flow = padder.unpad(flow)
+            # counted before the yield: a consumer that closes the
+            # generator at the batch's last yield never resumes it
+            root.args["complete"] = int(j == n - 1)
+            yield idx, sample, flow
+
+    units = itertools.count()
+
+    def open_batch():
+        return (timer.span("pass.batch", unit=next(units), complete=0),
+                xla_compile_count())
+
+    def close_batch(span, compiles_before):
+        compiles = xla_compile_count() - compiles_before
+        if compiles:
+            span.args["compiles"] = compiles
+        span.close()
 
     buckets: Dict = {}
-    for idx in range(len(dataset)):
-        sample = dataset[idx]
-        image1, image2 = sample[0], sample[1]
-        padder = InputPadder(image1.shape, mode=mode) if mode else None
-        im1, im2 = padder.pad(image1, image2) if padder else (image1,
-                                                              image2)
-        key = im1.shape
-        buckets.setdefault(key, []).append((idx, sample, padder, im1, im2))
-        if len(buckets[key]) == bs:
-            yield from flush(buckets.pop(key))
-    for batch in buckets.values():
-        yield from flush(batch)
+    root = None            # the open batch: (span, compiles before it)
+    try:
+        for idx in range(len(dataset)):
+            root = root or open_batch()
+            with timer.span("pass.fetch"):
+                sample = dataset[idx]
+            image1, image2 = sample[0], sample[1]
+            if mode:
+                padder = InputPadder(image1.shape, mode=mode)
+                with timer.span("pass.pad"):
+                    im1, im2 = padder.pad(image1, image2)
+            else:
+                padder, im1, im2 = None, image1, image2
+            key = im1.shape
+            buckets.setdefault(key, []).append(
+                (idx, sample, padder, im1, im2))
+            if len(buckets[key]) == bs:
+                yield from flush(buckets.pop(key), root[0])
+                root = close_batch(*root)
+        for batch in buckets.values():
+            root = root or open_batch()
+            yield from flush(batch, root[0])
+            root = close_batch(*root)
+    finally:
+        if root:
+            close_batch(*root)
+
+
+def _reported_pass(predictor, dataset, mode: Optional[str] = None):
+    """:func:`_predict_dataset` for the ``validate_*`` entry points:
+    prints where the pass's host time went once it is through."""
+    before = host_timer().summary()
+    yield from _predict_dataset(predictor, dataset, mode)
+    print("host stages:", host_timer().report(since=before))
 
 
 def _epe_map(flow: np.ndarray, flow_gt: np.ndarray) -> np.ndarray:
@@ -831,7 +902,7 @@ def validate_chairs(predictor: FlowPredictor, root=None) -> Dict[str, float]:
     """FlyingChairs val-split EPE (reference ``evaluate.py:74-98``)."""
     val_dataset = datasets.FlyingChairs(split="validation", root=root)
     epe_list = []
-    for _, sample, flow in _predict_dataset(predictor, val_dataset):
+    for _, sample, flow in _reported_pass(predictor, val_dataset):
         flow_gt = sample[2]
         epe_list.append(_epe_map(flow, flow_gt).reshape(-1))
     epe = float(np.mean(np.concatenate(epe_list)))
@@ -847,8 +918,8 @@ def validate_sintel(predictor: FlowPredictor, root=None) -> Dict[str, float]:
         val_dataset = datasets.MpiSintel(split="training", dstype=dstype,
                                          root=root)
         epe_list = []
-        for _, sample, flow in _predict_dataset(predictor, val_dataset,
-                                                mode="sintel"):
+        for _, sample, flow in _reported_pass(predictor, val_dataset,
+                                              mode="sintel"):
             flow_gt = sample[2]
             epe_list.append(_epe_map(flow, flow_gt).reshape(-1))
 
@@ -875,8 +946,8 @@ def validate_sintel_occ(predictor: FlowPredictor,
         if len(val_dataset) == 0 or not val_dataset.occ_list:
             continue
         epe_list, occ_list, noc_list = [], [], []
-        for val_id, sample, flow in _predict_dataset(predictor, val_dataset,
-                                                     mode="sintel"):
+        for val_id, sample, flow in _reported_pass(predictor, val_dataset,
+                                                   mode="sintel"):
             flow_gt = sample[2]
             occ = val_dataset.read_occlusion(val_id)
             epe = _epe_map(flow, flow_gt)
@@ -902,8 +973,8 @@ def validate_kitti(predictor: FlowPredictor, root=None) -> Dict[str, float]:
     ``:285``)."""
     val_dataset = datasets.KITTI(split="training", root=root)
     epe_list, out_list = [], []
-    for _, sample, flow in _predict_dataset(predictor, val_dataset,
-                                            mode="kitti"):
+    for _, sample, flow in _reported_pass(predictor, val_dataset,
+                                          mode="kitti"):
         _, _, flow_gt, valid_gt = sample
 
         epe = _epe_map(flow, flow_gt)
@@ -1044,7 +1115,7 @@ def validate_golden(predictor: FlowPredictor, root=None,
               f"predictor runs iters={predictor.iters}; parity EPE is "
               f"only meaningful at the recorded count")
     parity, gt_epes = [], []
-    for _, sample, flow in _predict_dataset(predictor, fixture):
+    for _, sample, flow in _reported_pass(predictor, fixture):
         parity.append(float(_epe_map(flow, sample[3]).mean()))
         gt_epes.append(float(_epe_map(flow, sample[2]).mean()))
     key = "golden" if variant == "large" else f"golden_{variant}"

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 MAX_FLOW = 400.0  # reference train.py:48
@@ -48,6 +49,7 @@ def epe_metrics(flow_pred: jnp.ndarray, flow_gt: jnp.ndarray,
     }
 
 
+@jax.named_scope("sequence_loss")
 def sequence_loss(flow_preds: jnp.ndarray, flow_gt: jnp.ndarray,
                   valid: jnp.ndarray, gamma: float = 0.8,
                   max_flow: float = MAX_FLOW,

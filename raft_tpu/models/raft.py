@@ -106,14 +106,17 @@ class _UpdateStep(nn.Module):
             net_prev, coords1_prev, consec, done, used = carry
         else:
             net_prev, coords1_prev = carry
-        coords1 = jax.lax.stop_gradient(coords1_prev)
+        with jax.named_scope("coords"):
+            coords1 = jax.lax.stop_gradient(coords1_prev)
         corr = _lookup(self.config, corr_state, coords1)
         corr = corr.astype(net_prev.dtype)
-        flow = (coords1 - coords0).astype(net_prev.dtype)
+        with jax.named_scope("coords"):
+            flow = (coords1 - coords0).astype(net_prev.dtype)
         net, up_mask, delta_flow = self.update_block(
             net_prev, inp, corr, flow, compute_mask=compute_up)
-        coords1 = coords1 + delta_flow.astype(jnp.float32)
-        new_flow = coords1 - coords0
+        with jax.named_scope("coords"):
+            coords1 = coords1 + delta_flow.astype(jnp.float32)
+            new_flow = coords1 - coords0
 
         if masked:
             tol, patience = self.early_exit
@@ -148,11 +151,12 @@ class _UpdateStep(nn.Module):
         # Training / init / final test_mode iteration: upsampled flow
         # is a scan output (the sequence loss consumes all of them; the
         # test_mode caller takes the single stacked entry).
-        if up_mask is None:
-            flow_up = upflow8(new_flow)
-        else:
-            flow_up = convex_upsample(new_flow,
-                                      up_mask.astype(jnp.float32))
+        with jax.named_scope("upsample"):
+            if up_mask is None:
+                flow_up = upflow8(new_flow)
+            else:
+                flow_up = convex_upsample(new_flow,
+                                          up_mask.astype(jnp.float32))
         return (net, coords1), flow_up
 
 
@@ -176,13 +180,15 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2, inference: bool):
     tuple as static values alongside the "alt"/"allpairs" tag.
     """
     kind, meta = corr_state_meta(cfg, inference)
-    if kind == "alt":
+    with jax.named_scope("corr_build"):
+        if kind == "alt":
+            return (kind, meta,
+                    (fmap1,
+                     corr.build_feature_pyramid(fmap2, cfg.corr_levels)))
         return (kind, meta,
-                (fmap1, corr.build_feature_pyramid(fmap2, cfg.corr_levels)))
-    return (kind, meta,
-            corr.build_corr_pyramid(
-                fmap1, fmap2, cfg.corr_levels, cfg.corr_scale,
-                cfg.corr_storage(inference)))
+                corr.build_corr_pyramid(
+                    fmap1, fmap2, cfg.corr_levels, cfg.corr_scale,
+                    cfg.corr_storage(inference)))
 
 
 def corr_state_meta(cfg: RAFTConfig, inference: bool):
@@ -203,14 +209,15 @@ def corr_state_meta(cfg: RAFTConfig, inference: bool):
 
 def _lookup(cfg: RAFTConfig, corr_state, coords):
     kind, (mxu_dtype, differentiable, out_dt), payload = corr_state
-    if kind == "alt":
-        fmap1, pyramid2 = payload
-        return corr.alternate_lookup(fmap1, pyramid2, coords, cfg.radius,
-                                     cfg.corr_scale,
-                                     mxu_dtype=mxu_dtype,
-                                     differentiable=differentiable,
-                                     out_dtype=jnp.dtype(out_dt))
-    return corr.pyramid_lookup(payload, coords, cfg.radius)
+    with jax.named_scope("corr_lookup"):
+        if kind == "alt":
+            fmap1, pyramid2 = payload
+            return corr.alternate_lookup(fmap1, pyramid2, coords,
+                                         cfg.radius, cfg.corr_scale,
+                                         mxu_dtype=mxu_dtype,
+                                         differentiable=differentiable,
+                                         out_dtype=jnp.dtype(out_dt))
+        return corr.pyramid_lookup(payload, coords, cfg.radius)
 
 
 class RAFT(nn.Module):
@@ -375,10 +382,11 @@ class RAFT(nn.Module):
         inp = nn.relu(inp)
 
         B, H8, W8, _ = fmap1.shape
-        coords0 = coords_grid(B, H8, W8)
-        coords1 = coords0
-        if flow_init is not None:
-            coords1 = coords1 + flow_init
+        with jax.named_scope("coords"):
+            coords0 = coords_grid(B, H8, W8)
+            coords1 = coords0
+            if flow_init is not None:
+                coords1 = coords1 + flow_init
 
         # In test_mode only the last iteration computes the (expensive)
         # upsampling-mask head and convex upsampling; training needs every

@@ -38,8 +38,14 @@ Design constraints, both load-bearing:
   slot write is a single list item assignment.
 
 Timestamps are ``time.perf_counter_ns`` microseconds relative to the
-tracer's construction — monotonic, immune to wall-clock steps, and
-directly usable as Chrome's ``ts`` field.
+tracer's construction (:attr:`Tracer.t0_ns`, exported in the artifact)
+— monotonic, immune to wall-clock steps, and directly usable as
+Chrome's ``ts`` field.
+
+The dataset pass, the predictor and the train loop do not record here
+directly: their spans live in the always-on host timer
+(:func:`raft_tpu.utils.profiling.host_timer`), which forwards each one
+as a ``host``-category slice while a tracer is enabled.
 """
 
 from __future__ import annotations
@@ -78,7 +84,11 @@ class Tracer:
         # concurrent producers each get a unique slot without a lock.
         self._slots = itertools.count()
         self._ids = itertools.count(1)
-        self._t0 = time.perf_counter_ns()
+        #: ``time.perf_counter_ns`` at construction: ``ts`` of an event
+        #: is microseconds after it, so ``t0_ns + ts * 1e3`` puts the
+        #: artifact on the clock of the host timer's spans and of the
+        #: benchmark's device-trace anchors.
+        self.t0_ns = time.perf_counter_ns()
         self._pid = os.getpid()
         # tid -> thread name, filled lazily at record time. Plain dict
         # writes are atomic under the GIL; last-writer-wins is fine
@@ -95,7 +105,7 @@ class Tracer:
 
     def now_us(self) -> float:
         """Microseconds since tracer construction (monotonic)."""
-        return (time.perf_counter_ns() - self._t0) / 1e3
+        return (time.perf_counter_ns() - self.t0_ns) / 1e3
 
     # -- identity -------------------------------------------------------
 
@@ -234,7 +244,8 @@ class Tracer:
                 "displayTimeUnit": "ms",
                 "otherData": {"dropped_events": self.dropped,
                               "open_flows": len(self.open_flows()),
-                              "capacity": self.capacity}}
+                              "capacity": self.capacity,
+                              "t0_ns": self.t0_ns}}
 
     def write(self, path: str) -> str:
         """Serialize :meth:`chrome_trace` to ``path``; returns it."""

@@ -150,7 +150,20 @@ def fetch_optimizer(cfg: TrainConfig,
     """
     sched = schedule if schedule is not None else make_schedule(cfg)
     return optax.chain(
-        optax.clip_by_global_norm(cfg.clip),
-        optax.adamw(sched, b1=0.9, b2=0.999, eps=cfg.epsilon,
-                    weight_decay=cfg.wdecay, mask=_decay_mask),
+        _named("grad_clip", optax.clip_by_global_norm(cfg.clip)),
+        _named("optimizer_update",
+               optax.adamw(sched, b1=0.9, b2=0.999, eps=cfg.epsilon,
+                           weight_decay=cfg.wdecay, mask=_decay_mask)),
     )
+
+
+def _named(scope: str, tx: optax.GradientTransformation
+           ) -> optax.GradientTransformation:
+    """``tx`` with its update traced under ``jax.named_scope(scope)``:
+    the compiled step's ``op_name`` then tells clipping from the
+    optimizer's arithmetic. Same state, same instructions."""
+    def update(updates, state, params=None):
+        with jax.named_scope(scope):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)

@@ -40,9 +40,12 @@ class RAFTTrainState(struct.PyTreeNode):
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
 
     def apply_gradients(self, grads):
+        # the default optimizer names its own stages (`grad_clip`,
+        # `optimizer_update`: optim.fetch_optimizer)
         updates, new_opt_state = self.tx.update(
             grads, self.opt_state, self.params)
-        new_params = optax.apply_updates(self.params, updates)
+        with jax.named_scope("optimizer_update"):
+            new_params = optax.apply_updates(self.params, updates)
         return self.replace(step=self.step + 1, params=new_params,
                             opt_state=new_opt_state)
 

@@ -41,7 +41,9 @@ from raft_tpu.models.raft import RAFT
 from raft_tpu.optim import make_schedule
 from raft_tpu.parallel import (create_train_state, make_mesh,
                                make_train_step, shard_batch)
+from raft_tpu.serving.metrics import xla_compile_count
 from raft_tpu.utils.logger import TrainLogger
+from raft_tpu.utils.profiling import host_timer
 
 
 class _PreemptionGuard:
@@ -125,6 +127,37 @@ def build_model(model_family: str, mcfg: RAFTConfig):
         return RAFT(mcfg)
     raise ValueError(f"unknown model_family {model_family!r}; "
                      f"choose from {MODEL_FAMILIES}")
+
+
+def _panels_and_validation(tcfg, model, state, batch, panel_fn,
+                           validation, eval_iters, logger, step):
+    """The ``val_freq`` block after its checkpoint: the training image
+    panels of the current batch, then the validation sets."""
+    # Single-process only: sharded batch/pred arrays span
+    # non-addressable devices on multi-host meshes and device_get would
+    # raise there (panels are a debug aid, not worth an allgather of
+    # full images).
+    if jax.process_count() == 1:
+        preds = jax.device_get(panel_fn(
+            _eval_variables(state), batch["image1"], batch["image2"]))
+        i1, i2, fl = jax.device_get(
+            (batch["image1"], batch["image2"], batch["flow"]))
+        if tcfg.model_family == "sparse":
+            flow_preds, sparse_preds = preds
+        elif tcfg.model_family in ("dual_query", "two_stage",
+                                   "full_transformer"):
+            # two-list outputs; only the sparse family's 4-tuples feed
+            # the keypoint/mask panels
+            flow_preds, sparse_preds = preds[0], None
+        else:
+            flow_preds, sparse_preds = preds, None
+        logger.write_images(i1, i2, fl, flow_preds, sparse_preds,
+                            step=step)
+    if validation:
+        predictor = evaluate.FlowPredictor(
+            model, _eval_variables(state), iters=eval_iters)
+        results = evaluate.run_validation(predictor, validation)
+        logger.write_dict(results, step=step)
 
 
 def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
@@ -254,43 +287,80 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
         # pulled-but-unstepped batch is re-produced on resume.
         loader_snap = (dataloader.state().to_dict()
                        if hasattr(dataloader, "state") else None)
+        timer = host_timer()
         with guard:
             # the while-condition check also escapes a pathological spin
             # over an exhausted one-shot dataloader (local flag only; no
             # collectives run in an empty pass)
             while keep_training and not guard.requested:
-                for batch in dataloader:
-                    if total_steps % check_every == 0 and \
-                            _preemption_agreed(guard.requested):
-                        ckptr.save(state, loader_state=loader_snap)
-                        ckptr.wait_for_pending()   # commit before exit
-                        print(f"preemption checkpoint at step "
-                              f"{total_steps}; resume with --resume")
-                        return state
-                    batch = shard_batch(batch, mesh)
-                    state, metrics = step_fn(state, batch, step_rng)
-                    total_steps += 1
-                    if loader_snap is not None:
-                        # The batch is now *trained on*: snapshot the
-                        # cursor at this quiescent point for every save
-                        # until the next step.
-                        loader_snap = dataloader.state().to_dict()
-                    host_metrics = jax.device_get(metrics)
-                    # Degradation counters into the scalar stream
-                    # (logger accumulates them as run totals): per-step
-                    # skip flag from the jitted guard, substitution
-                    # delta from the loader.
-                    if loader_stats is not None:
-                        subs = loader_stats.substituted_samples
-                        host_metrics["substituted_samples"] = float(
-                            subs - last_substituted)
-                        last_substituted = subs
-                    if host_metrics.get("skipped_steps", 0.0) > 0:
-                        consecutive_skips += 1
-                    else:
-                        consecutive_skips = 0
-                    logger.push(host_metrics,
-                                lr=float(schedule(total_steps - 1)))
+                batches = iter(dataloader)
+                while True:
+                    # One root span a step in the process host timer,
+                    # from asking the loader to the end of logger.push
+                    # (`complete` 1 there); `unit` is the step's number.
+                    compiles0 = xla_compile_count()
+                    step_span = timer.span("train.step",
+                                           unit=total_steps + 1,
+                                           complete=0)
+                    try:
+                        with timer.span("train.loader_wait"):
+                            batch = next(batches, None)
+                        if batch is None:
+                            break
+                        if total_steps % check_every == 0 and \
+                                _preemption_agreed(guard.requested):
+                            ckptr.save(state, loader_state=loader_snap)
+                            ckptr.wait_for_pending()  # commit before exit
+                            print(f"preemption checkpoint at step "
+                                  f"{total_steps}; resume with --resume")
+                            return state
+                        with timer.span("train.shard_batch") as span:
+                            batch = shard_batch(batch, mesh)
+                            span.nbytes = sum(
+                                a.nbytes for a in jax.tree.leaves(batch))
+                        with timer.span("train.dispatch"):
+                            state, metrics = step_fn(state, batch, step_rng)
+                        total_steps += 1
+                        if loader_snap is not None:
+                            # The batch is now *trained on*: snapshot
+                            # the cursor at this quiescent point for
+                            # every save until the next step.
+                            with timer.span("train.loader_snapshot"):
+                                loader_snap = dataloader.state().to_dict()
+                        # device_get would block as long; waiting first
+                        # tells the device's time from the fetch's. The
+                        # copies are queued behind the step before the
+                        # wait, as device_get alone would queue them.
+                        for leaf in jax.tree.leaves(metrics):
+                            leaf.copy_to_host_async()
+                        with timer.span("train.device_wait"):
+                            jax.block_until_ready(metrics)
+                        with timer.span("train.metrics_fetch",
+                                        leaves=len(metrics)):
+                            host_metrics = jax.device_get(metrics)
+                        with timer.span("train.log"):
+                            # Degradation counters into the scalar
+                            # stream (logger accumulates them as run
+                            # totals): per-step skip flag from the
+                            # jitted guard, substitution delta from the
+                            # loader.
+                            if loader_stats is not None:
+                                subs = loader_stats.substituted_samples
+                                host_metrics["substituted_samples"] = \
+                                    float(subs - last_substituted)
+                                last_substituted = subs
+                            if host_metrics.get("skipped_steps", 0.0) > 0:
+                                consecutive_skips += 1
+                            else:
+                                consecutive_skips = 0
+                            logger.push(host_metrics,
+                                        lr=float(schedule(total_steps - 1)))
+                        step_span.args["complete"] = 1
+                    finally:
+                        compiles = xla_compile_count() - compiles0
+                        if compiles:
+                            step_span.args["compiles"] = compiles
+                        step_span.close()
                     if tcfg.max_consecutive_skips and consecutive_skips \
                             >= tcfg.max_consecutive_skips:
                         # The guard never applied a non-finite update,
@@ -305,37 +375,15 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
                             f"last finite state to {run_ckpt_dir}")
 
                     if total_steps % tcfg.val_freq == 0:
-                        ckptr.save(state, loader_state=loader_snap)
-                        # Single-process only: sharded batch/pred arrays span
-                        # non-addressable devices on multi-host meshes and
-                        # device_get would raise there (panels are a debug
-                        # aid, not worth an allgather of full images).
-                        if jax.process_count() == 1:
-                            preds = jax.device_get(panel_fn(
-                                _eval_variables(state), batch["image1"],
-                                batch["image2"]))
-                            i1, i2, fl = jax.device_get(
-                                (batch["image1"], batch["image2"],
-                                 batch["flow"]))
-                            if tcfg.model_family == "sparse":
-                                flow_preds, sparse_preds = preds
-                            elif tcfg.model_family in ("dual_query",
-                                                       "two_stage",
-                                                       "full_transformer"):
-                                # two-list outputs; only the sparse family's
-                                # 4-tuples feed the keypoint/mask panels
-                                flow_preds, sparse_preds = preds[0], None
-                            else:
-                                flow_preds, sparse_preds = preds, None
-                            logger.write_images(i1, i2, fl, flow_preds,
-                                                sparse_preds,
-                                                step=total_steps)
-                        if validation:
-                            predictor = evaluate.FlowPredictor(
-                                model, _eval_variables(state), iters=eval_iters)
-                            results = evaluate.run_validation(
-                                predictor, validation)
-                            logger.write_dict(results, step=total_steps)
+                        with timer.span("train.checkpoint",
+                                        unit=total_steps):
+                            ckptr.save(state, loader_state=loader_snap)
+                        with timer.span("train.validation",
+                                        unit=total_steps):
+                            _panels_and_validation(
+                                tcfg, model, state, batch, panel_fn,
+                                validation, eval_iters, logger,
+                                total_steps)
                         # A SIGTERM landing during the validation/panel
                         # block above must not wait for the next batch
                         # to complete: re-vote here (deterministic

@@ -24,6 +24,8 @@ import time
 from collections import defaultdict, deque
 from typing import Dict, Iterable, Optional
 
+from raft_tpu.utils.profiling import host_timer
+
 
 class SmoothedValue:
     """Window-smoothed scalar with global average
@@ -184,6 +186,7 @@ class TrainLogger:
                 from raft_tpu.utils.tb_events import EventWriter
                 self._tb = EventWriter(log_dir)
         self._t0 = time.time()
+        self._host_mark = host_timer().summary()
         # The same run totals, live on the process telemetry registry
         # (one labeled gauge family; the JSONL/TensorBoard stream stays
         # the canonical artifact — this is the scrape surface).
@@ -231,9 +234,20 @@ class TrainLogger:
             if lr is not None:
                 scalars["lr"] = lr
             scalars.update(self.counters)
+            scalars.update(self._host_stage_means())
             self.write_dict(scalars)
             self.running = {}
             self._t0 = time.time()
+
+    def _host_stage_means(self) -> Dict[str, float]:
+        """``host/<stage>_ms``: the mean of each of the train loop's
+        host spans (``train.*`` in the process host timer) that closed
+        since the last flush."""
+        timer = host_timer()
+        new = timer.summary(since=self._host_mark)
+        self._host_mark = timer.summary()
+        return {f"host/{name[len('train.'):]}_ms": row["mean_ms"]
+                for name, row in new.items() if name.startswith("train.")}
 
     def write_images(self, image1, image2, flow_gt, flow_preds,
                      sparse_preds=None, phase: str = "T",

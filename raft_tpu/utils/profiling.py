@@ -25,8 +25,11 @@ actionable without TensorBoard:
   columns, but for a subsystem" view the kernel A/B probes print.
 * :class:`HostStageTimer` — accumulated *host-side* wall time per named
   pipeline stage (pad / stack / dispatch / sync), for code whose cost
-  the device tracer can't see. The serving engine threads one through
-  its dispatch loop; a loader or eval loop can do the same.
+  the device tracer can't see, and — given a ring — the last spans
+  themselves (start, duration, unit, parent). The serving engine
+  threads a totals-only one through its dispatch loop; the dataset
+  pass, the predictor and the train loop record into the process-wide
+  one, :func:`host_timer`.
 
 Typical use::
 
@@ -49,14 +52,84 @@ import glob
 import importlib
 import os
 import os.path as osp
+import struct
 import time
 from typing import Dict, List, Optional, Tuple
 
+from raft_tpu.observability import tracer as _tracing
+
+#: what a span leaves in the ring besides its name and args: id,
+#: parent, unit, start_ns, dur_ns, nbytes
+_ROW = struct.Struct("6q")
+
+
+#: A closed span as :meth:`HostStageTimer.spans` gives it back.
+SpanRecord = collections.namedtuple(
+    "SpanRecord", "name id parent unit start_ns dur_ns nbytes args")
+
+
+class Span:
+    """One timed stretch of host work, open from its creation to
+    :meth:`close` (``with timer.span(...)`` closes it). Times are
+    absolute ``time.perf_counter_ns``, the clock the device trace is
+    anchored on. ``unit`` (a non-negative integer) is the identifier
+    all spans of one batch or one step share, ``parent`` the ``id`` of
+    the span that was open on this thread when this one began (0 for a
+    root); ``args`` holds small integers and may be filled while the
+    span is open."""
+
+    __slots__ = ("_timer", "_stack", "name", "id", "parent", "unit",
+                 "start_ns", "dur_ns", "nbytes", "args")
+
+    def __init__(self, timer, name, unit, nbytes, args):
+        self._timer = timer
+        self.name = name
+        self.nbytes = nbytes
+        self.args = args
+        self.dur_ns = None
+        try:
+            stack = timer._open.stack
+        except AttributeError:
+            stack = timer._open.stack = []
+        self._stack = stack
+        if stack:
+            self.parent = stack[-1].id
+            self.unit = stack[-1].unit if unit is None else unit
+        else:
+            self.parent = 0
+            self.unit = unit
+        self.id = next(timer._ids)
+        stack.append(self)
+        self.start_ns = time.perf_counter_ns()
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    def close(self) -> None:
+        """End the span now and record it. Closing twice records once."""
+        if self.dur_ns is not None:
+            return
+        self.dur_ns = time.perf_counter_ns() - self.start_ns
+        # Usually the top of its thread's stack of open spans; a
+        # generator's root span can be closed under a consumer's span,
+        # or by the garbage collector on another thread.
+        stack = self._stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:
+            stack.remove(self)
+        self._timer._record(self)
+
 
 class HostStageTimer:
-    """Thread-safe accumulator of host-side wall time per named stage.
+    """Thread-safe accumulator of host-side wall time per named stage,
+    and a bounded ring of the last spans.
 
-    ``with timer.stage("pad"): ...`` around each host-pipeline section;
+    ``with timer.span("pad"): ...`` around each host-pipeline section
+    (:meth:`stage` is the same call under its older name);
     :meth:`summary` returns ``{stage: {total_ms, count, mean_ms,
     total_bytes}}`` and :meth:`report` a one-line table. Stages may be
     entered concurrently from several threads (client threads pad while
@@ -64,51 +137,132 @@ class HostStageTimer:
     threads the totals measure *work*, not wall clock.
 
     Stages that move memory can also account bytes: pass ``nbytes`` to
-    :meth:`stage` when the amount is known up front (e.g. the staging
+    :meth:`span` when the amount is known up front (e.g. the staging
     arena memcpy), or call :meth:`add_bytes` when it is only known
     mid-stage (e.g. per-output device→host syncs). Byte totals turn the
     stage table into a bandwidth story — "stack" time divided by
     "stack" bytes is the host memcpy rate the wire format is cutting.
+
+    ``ring``: how many closed spans to keep (:meth:`spans`, oldest
+    first, as :class:`SpanRecord`; :attr:`dropped` counts overwrites).
+    A span's parent is whatever span is open on the same thread, so
+    the predictor's spans fall under the dataset pass's batch that
+    called it. With a ring, each span of a unit is also forwarded to
+    the process :class:`~raft_tpu.observability.Tracer` while one is
+    enabled (as a slice of category ``host``); a span outside any unit
+    is not (the serving engine's calls into the predictor: its own
+    call sites write request-keyed slices, into the tracer the engine
+    captured when it was built). ``ring=0`` keeps totals only: the
+    serving engine's instance.
     """
 
-    def __init__(self):
+    def __init__(self, ring: int = 0):
+        import itertools
         import threading
 
+        if ring < 0:
+            raise ValueError(f"ring must be >= 0, got {ring}")
+        self.ring = int(ring)
         self._lock = threading.Lock()
-        self._total_s: Dict[str, float] = collections.defaultdict(float)
+        self._total_ns: Dict[str, int] = collections.defaultdict(int)
         self._count: Dict[str, int] = collections.defaultdict(int)
         self._bytes: Dict[str, int] = collections.defaultdict(int)
+        # The ring, as columns: a closed span leaves no Python object
+        # behind. Keeping the Span objects (390 a Sintel batch) moved
+        # the garbage collector's cadence, and with it when JAX lets go
+        # of a batch's staging arrays: the large pass lost 8 % to page
+        # faults (PERF.md, Findings of PR 27).
+        self._names: List[Optional[str]] = [None] * self.ring
+        self._args: List[Optional[dict]] = [None] * self.ring
+        self._rows = bytearray(_ROW.size * self.ring)
+        self._recorded = 0
+        self._ids = itertools.count(1)
+        self._open = threading.local()
 
-    @contextlib.contextmanager
-    def stage(self, name: str, nbytes: int = 0):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self._total_s[name] += dt
-                self._count[name] += 1
-                if nbytes:
-                    self._bytes[name] += int(nbytes)
+    def span(self, name: str, unit: Optional[int] = None, nbytes: int = 0,
+             **args: int) -> Span:
+        """Open a span; close it with ``with`` or :meth:`Span.close`.
+        ``unit`` defaults to the enclosing span's."""
+        return Span(self, name, unit, nbytes, args)
+
+    stage = span
+
+    def _record(self, span: Span) -> None:
+        with self._lock:
+            self._total_ns[span.name] += span.dur_ns
+            self._count[span.name] += 1
+            if span.nbytes:
+                self._bytes[span.name] += int(span.nbytes)
+            if self.ring:
+                slot = self._recorded % self.ring
+                self._names[slot] = span.name
+                self._args[slot] = span.args or None
+                _ROW.pack_into(
+                    self._rows, _ROW.size * slot, span.id, span.parent,
+                    -1 if span.unit is None else span.unit, span.start_ns,
+                    span.dur_ns, int(span.nbytes))
+                self._recorded += 1
+        if self.ring and span.unit is not None:
+            tracer = _tracing.current()
+            if tracer is not None:
+                tracer.complete(
+                    span.name, span.dur_ns / 1e9, cat="host",
+                    end_ts_us=(span.start_ns + span.dur_ns
+                               - tracer.t0_ns) / 1e3,
+                    args={"unit": span.unit, "nbytes": span.nbytes,
+                          **span.args})
 
     def add_bytes(self, name: str, n: int) -> None:
-        """Attribute ``n`` bytes to ``name`` outside a ``stage()``
+        """Attribute ``n`` bytes to ``name`` outside a ``span()``
         block (or when the amount is only known mid-stage)."""
         with self._lock:
             self._bytes[name] += int(n)
 
-    def summary(self) -> Dict[str, Dict[str, float]]:
+    @property
+    def dropped(self) -> int:
+        """Spans the ring has overwritten."""
         with self._lock:
-            return {
-                name: {"total_ms": tot * 1e3,
-                       "count": float(self._count[name]),
-                       "mean_ms": tot * 1e3 / max(self._count[name], 1),
-                       "total_bytes": float(self._bytes[name])}
-                for name, tot in self._total_s.items()}
+            return max(0, self._recorded - self.ring)
 
-    def report(self) -> str:
-        rows = sorted(self.summary().items(),
+    def spans(self) -> List[SpanRecord]:
+        """The ring's closed spans, oldest first (by when they closed:
+        children before their parent)."""
+        with self._lock:
+            kept = min(self._recorded, self.ring)
+            slots = [(self._recorded - kept + k) % self.ring
+                     for k in range(kept)]
+            rows = [(self._names[i], self._args[i],
+                     _ROW.unpack_from(self._rows, _ROW.size * i))
+                    for i in slots]
+        return [SpanRecord(name, ints[0], ints[1],
+                           None if ints[2] < 0 else ints[2], ints[3],
+                           ints[4], ints[5], dict(args or {}))
+                for name, args, ints in rows]
+
+    def summary(self, since: Optional[Dict[str, Dict[str, float]]] = None
+                ) -> Dict[str, Dict[str, float]]:
+        """Totals by stage; with ``since`` (an earlier summary), what
+        was added after it, stages with nothing new left out."""
+        with self._lock:
+            rows = {name: (tot / 1e6, float(self._count[name]),
+                           float(self._bytes[name]))
+                    for name, tot in self._total_ns.items()}
+        out = {}
+        for name, (ms, count, nbytes) in rows.items():
+            if since is not None and name in since:
+                ms -= since[name]["total_ms"]
+                count -= since[name]["count"]
+                nbytes -= since[name]["total_bytes"]
+                if not count:
+                    continue
+            out[name] = {"total_ms": ms, "count": count,
+                         "mean_ms": ms / max(count, 1),
+                         "total_bytes": nbytes}
+        return out
+
+    def report(self, since: Optional[Dict[str, Dict[str, float]]] = None
+               ) -> str:
+        rows = sorted(self.summary(since).items(),
                       key=lambda kv: -kv[1]["total_ms"])
         return " | ".join(
             f"{name}: {v['total_ms']:.1f}ms/{int(v['count'])} "
@@ -116,6 +270,21 @@ class HostStageTimer:
             + (f", {v['total_bytes'] / 1e6:.2f}MB" if v["total_bytes"]
                else "")
             + ")" for name, v in rows) or "(empty)"
+
+
+#: Spans the process-wide timer keeps: a Sintel pass batch of 128 is
+#: ~390 spans, a train step 9, so the ring holds the last ~40 batches
+#: or ~1800 steps.
+HOST_RING = 16384
+
+_HOST_TIMER = HostStageTimer(ring=HOST_RING)
+
+
+def host_timer() -> HostStageTimer:
+    """The process-wide timer the dataset pass, the predictor and the
+    train loop record into, always on. Look it up at call time: a
+    reader needs no handle on the predictor or the loop."""
+    return _HOST_TIMER
 
 
 class _Trace:
@@ -134,30 +303,6 @@ def trace(logdir: Optional[str] = None):
     t = _Trace(logdir)
     with jax.profiler.trace(logdir):
         yield t
-
-
-@contextlib.contextmanager
-def profiled_span(name: str, logdir: Optional[str] = None, tracer=None):
-    """Bridge a device-profiler capture into the request tracer: run
-    ``jax.profiler`` over the with-block AND record the block as one
-    named slice on the observability tracer, with the profiler logdir
-    in the slice args — the trace artifact then says exactly which
-    wall-clock window the xplane capture covers.
-
-    ``tracer`` defaults to the process tracer
-    (:func:`raft_tpu.observability.current_tracer`); with tracing
-    disabled this is just :func:`trace`. Yields the :func:`trace`
-    object (``.logdir``)."""
-    if tracer is None:
-        from raft_tpu.observability.tracer import current
-        tracer = current()
-    with trace(logdir) as t:
-        if tracer is None:
-            yield t
-        else:
-            with tracer.span(name, args={"logdir": t.logdir},
-                             cat="profiler"):
-                yield t
 
 
 def _load_xspace(logdir: str):

@@ -1,0 +1,387 @@
+"""The host spans inside the dataset pass, the predictor and the train
+loop (``raft_tpu.utils.profiling.host_timer``), the benchmark reader
+that turns them into per-batch and per-step milliseconds, and the
+named scopes of the model stages Flax leaves unnamed."""
+
+import re
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.readers import program_spans
+from raft_tpu.config import RAFTConfig, TrainConfig
+from raft_tpu.evaluate import FlowPredictor, _predict_dataset
+from raft_tpu.models import RAFT
+from raft_tpu.utils import profiling
+
+H, W = 30, 44            # pads to 32x48 in sintel mode
+BS = 3
+BATCH_LEVEL = ("pass.batch", "pass.stack", "predict.h2d", "predict.dispatch",
+               "predict.device_wait", "predict.d2h")
+STEP_CHILDREN = ("train.loader_wait", "train.shard_batch", "train.dispatch",
+                 "train.device_wait", "train.metrics_fetch", "train.log")
+
+
+class Pairs:
+    """Seven unpadded pairs: two full batches of three and a short one."""
+
+    def __len__(self):
+        return 7
+
+    def __getitem__(self, i):
+        r = np.random.default_rng(i)
+        return tuple(r.uniform(0, 255, (H, W, 3)).astype(np.float32)
+                     for _ in range(2))
+
+
+@pytest.fixture(scope="module")
+def predictor():
+    model = RAFT(RAFTConfig(small=True, iters=2))
+    key = jax.random.PRNGKey(0)
+    dummy = jnp.zeros((1, 32, 48, 3))
+    variables = model.init({"params": key, "dropout": key}, dummy, dummy,
+                           iters=1)
+    return FlowPredictor(model, variables, iters=2, batch_size=BS)
+
+
+@pytest.fixture
+def timer(monkeypatch):
+    """A fresh process timer for one test."""
+    fresh = profiling.HostStageTimer(ring=4096)
+    monkeypatch.setattr(profiling, "_HOST_TIMER", fresh)
+    return fresh
+
+
+def run_pass(predictor):
+    return [flow for _, _, flow in _predict_dataset(predictor, Pairs(),
+                                                    mode="sintel")]
+
+
+def by_name(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def test_pass_spans(predictor, timer, monkeypatch):
+    flows = run_pass(predictor)
+    spans = timer.spans()
+    roots = by_name(spans, "pass.batch")
+    assert [r.unit for r in roots] == [0, 1, 2]
+    assert [r.args["pairs"] for r in roots] == [3, 3, 1]
+    assert all(r.args["padded_to"] == BS and r.args["complete"] == 1
+               and (r.args["height"], r.args["width"]) == (32, 48)
+               and r.parent == 0 for r in roots)
+    frames = BS * 32 * 48 * 3 * 4
+    for root in roots:
+        kids = [s for s in spans if s.parent == root.id]
+        pairs = root.args["pairs"]
+        for name in BATCH_LEVEL[1:]:
+            assert len(by_name(kids, name)) == 1, name
+        for name in ("pass.fetch", "pass.pad", "pass.unpad"):
+            assert len(by_name(kids, name)) == pairs, name
+        assert all(k.unit == root.unit for k in kids)
+        assert all(k.start_ns >= root.start_ns
+                   and k.start_ns + k.dur_ns <= root.start_ns + root.dur_ns
+                   for k in kids)
+        assert by_name(kids, "pass.stack")[0].nbytes == 2 * frames
+        assert by_name(kids, "predict.h2d")[0].nbytes == 2 * frames
+        assert by_name(kids, "predict.d2h")[0].nbytes == (
+            BS * 32 * 48 * 2 * 4 + BS * 4 * 6 * 2 * 4)
+    assert len(spans) == len(roots) * len(BATCH_LEVEL) + 3 * 7
+    assert timer.dropped == 0
+    assert timer.summary()["pass.fetch"]["count"] == 7
+
+    # a second call is a new pass: its units start again
+    run_pass(predictor)
+    assert [r.unit for r in by_name(timer.spans(), "pass.batch")] == [
+        0, 1, 2, 0, 1, 2]
+
+    # without a ring: totals only, and the same flows to the last bit
+    bare = profiling.HostStageTimer(ring=0)
+    monkeypatch.setattr(profiling, "_HOST_TIMER", bare)
+    again = run_pass(predictor)
+    assert bare.spans() == [] and bare.dropped == 0
+    assert bare.summary()["pass.batch"]["count"] == 3
+    assert len(again) == len(flows) == 7
+    for a, b in zip(flows, again):
+        assert a.shape == (H, W, 2)
+        np.testing.assert_array_equal(a, b)
+
+
+def test_pass_closed_at_a_yield(predictor, timer):
+    """The benchmark closes the generator at a batch's last yield: that
+    batch is complete; one closed a yield earlier is not. Either way no
+    span stays open."""
+    for stop_after, complete in ((BS, 1), (BS - 1, 0)):
+        gen = _predict_dataset(predictor, Pairs(), mode="sintel")
+        for n, _ in enumerate(gen, 1):
+            if n == stop_after:
+                break
+        gen.close()
+        root = by_name(timer.spans(), "pass.batch")[-1]
+        assert root.args["complete"] == complete
+        with timer.span("probe") as probe:
+            pass
+        assert probe.parent == 0
+
+
+def test_reader_reads_the_newest_pass(predictor, timer):
+    run_pass(predictor)
+    run_pass(predictor)
+    ctx = {"run": {"batches": 3}}
+    spans = timer.spans()
+    last = by_name(spans, "pass.batch")[-3:]
+    ids = {r.id for r in last}
+    for stages in (["pass.fetch", "pass.pad"], ["predict.device_wait"]):
+        want = sum(s.dur_ns for s in spans
+                   if s.parent in ids and s.name in stages) / 3 / 1e6
+        got = program_spans.per_unit_ms(ctx, "pass.batch", stages,
+                                        "batches")
+        assert got == pytest.approx(want) and got > 0
+    # more units than the newest pass holds: not one run's
+    assert program_spans.per_unit_ms({"run": {"batches": 4}}, "pass.batch",
+                                     ["pass.stack"], "batches") is None
+    assert program_spans.per_unit_ms({"run": {}}, "pass.batch",
+                                     ["pass.stack"], "batches") is None
+
+
+def test_ring_overflow_silences_the_reader(predictor, monkeypatch):
+    small = profiling.HostStageTimer(ring=40)    # a pass is 39 spans
+    monkeypatch.setattr(profiling, "_HOST_TIMER", small)
+    run_pass(predictor)
+    ctx = {"run": {"batches": 3}}
+    args = ("pass.batch", ["pass.stack"], "batches")
+    assert small.dropped == 0
+    assert program_spans.per_unit_ms(ctx, *args) > 0
+    run_pass(predictor)
+    assert small.dropped == 38 and len(small.spans()) == 40
+    # the oldest span kept is the first pass's last root: the newest
+    # pass is whole
+    assert program_spans.per_unit_ms(ctx, *args) > 0
+    with small.span("one"), small.span("two"):
+        pass
+    assert small.dropped == 40
+    assert program_spans.per_unit_ms(ctx, *args) is None
+
+
+def test_a_closed_span_leaves_no_object_for_the_collector():
+    """The ring keeps columns, not objects: 390 kept objects a batch
+    moved the collector's cadence, and with it when JAX frees a batch's
+    staging arrays (8 % of the large pass on the chip, PERF.md PR 27)."""
+    import gc
+
+    timer = profiling.HostStageTimer(ring=256)
+    with timer.span("warm", unit=0):
+        pass
+    gc.collect()
+    gc.disable()
+    try:
+        before = gc.get_count()[0]
+        for unit in range(1000):
+            with timer.span("pass.batch", unit=unit):
+                with timer.span("pass.pad"):
+                    pass
+        grown = gc.get_count()[0] - before
+    finally:
+        gc.enable()
+    assert grown < 20, grown
+    assert timer.dropped == 2001 - 256
+    last = timer.spans()[-1]
+    assert (last.name, last.unit, last.parent, last.args) == (
+        "pass.batch", 999, 0, {})
+
+
+def _span(name, ident, parent=0, unit=0, dur_ns=0, **args):
+    return types.SimpleNamespace(name=name, id=ident, parent=parent,
+                                 unit=unit, dur_ns=dur_ns, args=args)
+
+
+def test_reader_on_hand_made_spans():
+    spans = []
+    for unit in range(4):
+        root = 10 * (unit + 1)
+        spans += [_span("train.loader_wait", root + 1, root, unit, 2_000_000),
+                  _span("train.log", root + 2, root, unit, 500_000),
+                  _span("train.loader_snapshot", root + 3, root, unit,
+                        250_000),
+                  _span("train.step", root, 0, unit, 9_000_000,
+                        complete=int(unit != 3))]
+    read = program_spans.stage_ms_per_unit
+    # the step whose next() raised (unit 3) does not count
+    assert read(spans, 0, "train.step", ["train.loader_wait"], 3) == 2.0
+    assert read(spans, 0, "train.step",
+                ["train.log", "train.loader_snapshot"], 2) == 0.75
+    assert read(spans, 0, "train.step", ["train.loader_wait"], 4) is None
+    assert read(spans, 0, "train.step", ["train.dispatch"], 3) is None
+    assert read(spans, 0, "pass.batch", ["train.log"], 1) is None
+    assert read(spans, 0, "train.step", ["train.log"], 0) is None
+    # dropped spans: fine while the oldest one kept is not theirs
+    assert read(spans, 7, "train.step", ["train.log"], 2) == 0.5
+    assert read(spans, 7, "train.step", ["train.log"], 3) is None
+    assert read(spans[3:], 7, "train.step", ["train.log"], 2) == 0.5
+    # two runs' units do not follow one another
+    spans[7].unit = 0
+    assert read(spans, 0, "train.step", ["train.log"], 2) is None
+
+
+def test_spans_reach_the_chrome_trace(predictor, timer):
+    from raft_tpu.observability import disable_tracing, enable_tracing
+    import time
+
+    tracer = enable_tracing()
+    try:
+        before = time.perf_counter_ns()
+        run_pass(predictor)
+        doc = tracer.chrome_trace()
+    finally:
+        disable_tracing()
+    host = [e for e in doc["traceEvents"] if e.get("cat") == "host"]
+    assert {e["name"] for e in host} == set(BATCH_LEVEL) | {
+        "pass.fetch", "pass.pad", "pass.unpad"}
+    assert len(host) == len(timer.spans())
+    batch = next(e for e in host if e["name"] == "pass.batch")
+    assert batch["args"]["pairs"] == 3 and batch["args"]["unit"] == 0
+    # on the timer's clock through the artifact's t0_ns
+    t0 = doc["otherData"]["t0_ns"]
+    first = by_name(timer.spans(), "pass.batch")[0]
+    assert t0 + batch["ts"] * 1e3 == pytest.approx(first.start_ns, abs=2e3)
+    assert first.start_ns >= before
+    # tracing off again: nothing more is forwarded
+    run_pass(predictor)
+    assert len(tracer.events()) == len(doc["traceEvents"]) - sum(
+        e["ph"] == "M" for e in doc["traceEvents"])
+
+
+# ------------------------------------------------------------- train loop
+
+TH, TW = 32, 48
+
+
+class Loader:
+    """``n`` batches, then either the end or an exception."""
+
+    def __init__(self, n, raises=None):
+        self.n, self.raises = n, raises
+
+    def __iter__(self):
+        rng = np.random.default_rng(0)
+        for _ in range(self.n):
+            image = rng.uniform(0, 255, (8, TH, TW, 3)).astype(np.float32)
+            yield {"image1": image, "image2": np.roll(image, 2, axis=2),
+                   "flow": np.full((8, TH, TW, 2), 2.0, np.float32),
+                   "valid": np.ones((8, TH, TW), np.float32)}
+        if self.raises is not None:
+            raise self.raises
+
+
+class Closed(Exception):
+    pass
+
+
+def test_train_spans(tmp_path, timer):
+    """Four steps, then a loader that raises: ``train()`` lets the
+    exception through (no exit checkpoint) and the open spans end."""
+    import json
+
+    from raft_tpu.train import train
+    from raft_tpu.utils.logger import TrainLogger
+
+    tcfg = TrainConfig(name="t", num_steps=10, batch_size=8,
+                       image_size=(TH, TW), iters=2, val_freq=1000,
+                       sum_freq=2)
+    logger = TrainLogger(str(tmp_path / "logs"), sum_freq=2,
+                         tensorboard=False)
+    with pytest.raises(Closed):
+        train(tcfg, RAFTConfig(small=True, iters=2),
+              ckpt_dir=str(tmp_path / "ckpts"),
+              log_dir=str(tmp_path / "logs"),
+              dataloader=Loader(4, raises=Closed()), logger=logger)
+    logger.close()
+    assert not (tmp_path / "ckpts" / "t").exists() or not any(
+        (tmp_path / "ckpts" / "t").iterdir())
+
+    spans = timer.spans()
+    steps = by_name(spans, "train.step")
+    assert [(s.unit, s.args["complete"]) for s in steps] == [
+        (1, 1), (2, 1), (3, 1), (4, 1), (5, 0)]
+    assert all(s.parent == 0 for s in steps)
+    assert steps[0].args["compiles"] >= 1
+    assert "compiles" not in steps[3].args
+    for step in steps[:4]:
+        kids = [s for s in spans if s.parent == step.id]
+        assert sorted(k.name for k in kids) == sorted(STEP_CHILDREN)
+        assert all(k.unit == step.unit for k in kids)
+        assert all(k.start_ns >= step.start_ns
+                   and k.start_ns + k.dur_ns <= step.start_ns + step.dur_ns
+                   for k in kids)
+        assert by_name(kids, "train.shard_batch")[0].nbytes == (
+            8 * TH * TW * (3 + 3 + 2 + 1) * 4)
+        assert by_name(kids, "train.metrics_fetch")[0].args["leaves"] >= 5
+    # the step whose next() raised: its wait, closed, and nothing else
+    assert [s.name for s in spans if s.parent == steps[4].id] == [
+        "train.loader_wait"]
+    with timer.span("probe") as probe:
+        pass
+    assert probe.parent == 0
+
+    for stages in (["train.loader_wait"], ["train.log",
+                                           "train.loader_snapshot"]):
+        assert program_spans.per_unit_ms(
+            {"run": {"steps": 4}}, "train.step", stages, "steps") > 0
+    assert program_spans.per_unit_ms(
+        {"run": {"steps": 5}}, "train.step", ["train.dispatch"],
+        "steps") is None
+
+    # the operator's line: means of the spans closed since the last flush
+    lines = [json.loads(line)
+             for line in open(tmp_path / "logs" / "scalars.jsonl")]
+    assert len(lines) == 2 and np.isfinite(lines[0]["loss"])
+    for line in lines:
+        for name in STEP_CHILDREN[:-1] + ("train.step",):
+            assert line["host/" + name[len("train."):] + "_ms"] > 0
+
+
+# ----------------------------------------------------------- named scopes
+
+def _op_names(lowered):
+    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+
+
+def _holds(names, scope):
+    return any(re.search(rf"(^|[/(]){re.escape(scope)}($|[/)])", n)
+               for n in names)
+
+
+MODEL_SCOPES = ("corr_build", "corr_lookup", "coords", "upsample")
+
+
+def test_forward_names_its_stages(predictor):
+    image = jax.ShapeDtypeStruct((BS, 32, 48, 3), jnp.float32)
+    names = _op_names(predictor._fn(image.shape, False, "float32").lower(
+        predictor.variables, image, image, None))
+    for scope in MODEL_SCOPES + ("RAFT/fnet", "RAFT/cnet", "update_block"):
+        assert _holds(names, scope), scope
+
+
+def test_train_step_names_its_stages():
+    from raft_tpu.parallel import create_train_state, make_train_step
+
+    tcfg = TrainConfig(name="t", num_steps=4, batch_size=1,
+                       image_size=(TH, TW), iters=2)
+    model = RAFT(RAFTConfig(small=True, iters=2))
+    state = create_train_state(jax.random.PRNGKey(0), model, tcfg, (TH, TW))
+    batch = {"image1": jnp.zeros((1, TH, TW, 3)),
+             "image2": jnp.zeros((1, TH, TW, 3)),
+             "flow": jnp.zeros((1, TH, TW, 2)),
+             "valid": jnp.ones((1, TH, TW))}
+    names = _op_names(make_train_step(tcfg).lower(
+        state, batch, jax.random.PRNGKey(1)))
+    for scope in MODEL_SCOPES + ("sequence_loss", "grad_clip",
+                                 "optimizer_update", "fnet", "cnet",
+                                 "update_block"):
+        assert _holds(names, scope), scope
+    # the backward pass keeps the forward's names
+    assert any("transpose(jvp(RAFT))/corr_build" in n for n in names)
+    assert any("transpose(jvp(sequence_loss))" in n for n in names)
