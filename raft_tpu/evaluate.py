@@ -19,6 +19,7 @@ from __future__ import annotations
 import itertools
 import os
 import os.path as osp
+import time
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -28,6 +29,7 @@ import numpy as np
 from raft_tpu.data import datasets, frame_utils
 from raft_tpu.utils.padder import InputPadder
 from raft_tpu.utils.profiling import host_timer
+from raft_tpu.utils.staging import StagingArena
 from raft_tpu.utils.warm_start import forward_interpolate
 
 
@@ -168,6 +170,10 @@ class FlowPredictor:
         # mid-run recompiles rather than corrupting cached callables.
         self.donate_images = False
         self._cache: Dict = {}
+        # Host staging buffers of the dataset pass (_predict_dataset):
+        # they live as long as the predictor, so a second pass over the
+        # same shape (Sintel clean, then final) starts warm.
+        self.staging = StagingArena()
 
     def _pick_engine(self, shape, n_sp: int = 1, n_dt: int = 1):
         """corr_impl='auto' per-shape engine choice, shared by the
@@ -793,6 +799,20 @@ class FlowPredictor:
         return self._cache[key](self.variables, carry)
 
 
+class _OpenBatch:
+    """A padded shape's batch while it fills: its ``(idx, sample,
+    padder)`` items and, for a batched predictor, the two ``(bs, H, W,
+    C)`` staging buffers its frames are padded into, with how many of
+    them the arena had to allocate. In the per-sample fallback an item
+    carries its two padded frames instead."""
+
+    __slots__ = ("shape", "items", "buffers", "fresh")
+
+    def __init__(self, shape, buffers=(), fresh=0):
+        self.shape, self.items = shape, []
+        self.buffers, self.fresh = buffers, fresh
+
+
 def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
     """Yield ``(idx, sample, flow_up)`` for every dataset element, running
     the model in fixed-size batches bucketed by padded shape.
@@ -804,39 +824,73 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
     ``mode``: InputPadder mode, or None when the dataset needs no padding
     (FlyingChairs is already /8).
 
+    Batches are staged through an arena (``predictor.staging``, else one
+    of this pass's own): a bucket's first sample acquires two ``(bs, H,
+    W, C)`` buffers of the sample's dtype, every sample is padded once,
+    straight into its slot, and the pair goes back to the arena when
+    ``predict_batch`` has returned, so from a shape's second batch on
+    the frames land in warm pages and nothing is allocated. The arena
+    holds what the open buckets need plus one idle pair a shape. No
+    consumer sees arena memory: a yielded ``sample`` is the dataset's
+    own and a yielded flow is a view of the predictor's output.
+
     Every call is one pass in the process host timer
     (:func:`raft_tpu.utils.profiling.host_timer`): a root span
     ``pass.batch`` from the first fetch after the previous batch's last
     yield to this batch's last yield (``unit``: the batch's sequence
-    number in the pass), over ``pass.fetch`` / ``pass.pad`` per sample,
-    ``pass.stack``, the predictor's ``predict.*`` spans and
-    ``pass.unpad`` per yielded sample; what no child covers is the
+    number in the pass), over ``pass.fetch`` / ``pass.pad`` per sample
+    (``pass.pad``: the one copy of both frames into their slots; a
+    bucket's first also takes the buffers from the arena),
+    ``pass.stack`` once a batch (what is left of stacking: the tail
+    slots' fill and the hand-off), the predictor's ``predict.*`` spans
+    and ``pass.unpad`` per yielded sample; what no child covers is the
     consumer's. The root reads ``complete`` 1 once all its pairs were
-    yielded, also where the consumer closes the generator at that yield.
+    yielded, also where the consumer closes the generator at that yield,
+    and ``arena_fresh``: how many of the batch's two buffers had to be
+    allocated (2 on a shape's first batch, 0 after).
     """
     from raft_tpu.serving.metrics import xla_compile_count
 
     timer = host_timer()
     bs = getattr(predictor, "batch_size", 1)
     batched = hasattr(predictor, "predict_batch") and bs > 1
+    arena = getattr(predictor, "staging", None) or StagingArena()
+
+    def open_bucket(shape, dtype):
+        before = arena.allocated
+        buffers = tuple(arena.acquire((bs,) + shape, dtype)
+                        for _ in range(2))
+        return _OpenBatch(shape, buffers, arena.allocated - before)
+
+    def stage(batch, padder, images):
+        slot = len(batch.items)
+        for dst, image in zip(batch.buffers, images):
+            if padder:
+                padder.pad_into(dst[slot], image)
+            else:
+                dst[slot] = image
 
     def flush(batch, root):
-        n = len(batch)
+        n = len(batch.items)
         root.args.update(pairs=n, padded_to=bs if batched else 1,
-                         height=batch[0][3].shape[0],
-                         width=batch[0][3].shape[1])
+                         height=batch.shape[0], width=batch.shape[1])
         if batched:
-            with timer.span("pass.stack") as span:
-                i1 = np.stack([b[3] for b in batch])
-                i2 = np.stack([b[4] for b in batch])
-                if n < bs:
-                    reps = bs - n
-                    i1 = np.concatenate([i1, np.repeat(i1[-1:], reps, 0)])
-                    i2 = np.concatenate([i2, np.repeat(i2[-1:], reps, 0)])
-                span.nbytes = i1.nbytes + i2.nbytes
+            i1, i2 = batch.buffers
+            root.args["arena_fresh"] = batch.fresh
+            with timer.span("pass.stack", nbytes=i1.nbytes + i2.nbytes):
+                if n < bs:      # every tail slot rewritten: no stale frame
+                    i1[n:] = i1[n - 1]
+                    i2[n:] = i2[n - 1]
             _, up = predictor.predict_batch(i1, i2)
-        for j, (idx, sample, padder, im1, im2) in enumerate(batch):
-            flow = up[j] if batched else predictor(im1, im2)[1]
+            # Only now may the buffers be written again. jnp.asarray
+            # merely enqueues the H2D copy and reads the host array
+            # until the transfer is done; predict_batch returns after
+            # block_until_ready on the outputs, which the executable
+            # wrote after it had its inputs whole. A batch that raised
+            # never gets here: its buffers are dropped, not pooled.
+            arena.release(i1, i2)
+        for j, (idx, sample, padder, *frames) in enumerate(batch.items):
+            flow = up[j] if batched else predictor(*frames)[1]
             if padder:
                 with timer.span("pass.unpad"):
                     flow = padder.unpad(flow)
@@ -864,34 +918,55 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
             root = root or open_batch()
             with timer.span("pass.fetch"):
                 sample = dataset[idx]
-            image1, image2 = sample[0], sample[1]
-            if mode:
-                padder = InputPadder(image1.shape, mode=mode)
+            images = sample[0], sample[1]
+            padder = InputPadder(images[0].shape, mode=mode) if mode else None
+            shape = ((padder.padded_shape if padder else images[0].shape[:2])
+                     + images[0].shape[2:])
+            key = shape, images[0].dtype.str
+            batch = buckets.get(key)
+            if batched:
                 with timer.span("pass.pad"):
-                    im1, im2 = padder.pad(image1, image2)
+                    if batch is None:
+                        batch = buckets[key] = open_bucket(
+                            shape, images[0].dtype)
+                    stage(batch, padder, images)
+                images = ()
             else:
-                padder, im1, im2 = None, image1, image2
-            key = im1.shape
-            buckets.setdefault(key, []).append(
-                (idx, sample, padder, im1, im2))
-            if len(buckets[key]) == bs:
+                if batch is None:
+                    batch = buckets[key] = _OpenBatch(shape)
+                if padder:
+                    with timer.span("pass.pad"):
+                        images = padder.pad(*images)
+            batch.items.append((idx, sample, padder, *images))
+            if len(batch.items) == bs:
                 yield from flush(buckets.pop(key), root[0])
                 root = close_batch(*root)
-        for batch in buckets.values():
+        for key in list(buckets):
             root = root or open_batch()
-            yield from flush(batch, root[0])
+            yield from flush(buckets.pop(key), root[0])
             root = close_batch(*root)
     finally:
         if root:
             close_batch(*root)
+        # a pass given up with buckets still filling: nothing of theirs
+        # is in flight, so their buffers go back too
+        for batch in buckets.values():
+            arena.release(*batch.buffers)
 
 
 def _reported_pass(predictor, dataset, mode: Optional[str] = None):
     """:func:`_predict_dataset` for the ``validate_*`` entry points:
-    prints where the pass's host time went once it is through."""
-    before = host_timer().summary()
+    prints where the pass's host time went once it is through, and how
+    many of its batches' staging buffers came from the arena warm."""
+    timer = host_timer()
+    before, began = timer.summary(), time.perf_counter_ns()
     yield from _predict_dataset(predictor, dataset, mode)
-    print("host stages:", host_timer().report(since=before))
+    fresh = [s.args["arena_fresh"] for s in timer.spans()
+             if s.name == "pass.batch" and s.start_ns >= began
+             and "arena_fresh" in s.args]
+    reuse = (f" | arena reuse: {1 - sum(fresh) / (2 * len(fresh)):.0%} of "
+             f"{2 * len(fresh)} buffers" if fresh else "")
+    print("host stages:", timer.report(since=before) + reuse)
 
 
 def _epe_map(flow: np.ndarray, flow_gt: np.ndarray) -> np.ndarray:

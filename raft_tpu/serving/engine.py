@@ -48,10 +48,11 @@ mechanisms behind one ``submit() -> Future`` API:
   wire dtype tags the bucket key and the executable cache key, and
   warmup compiles BOTH dtypes per bucket so mixed traffic never
   compiles. Batches are staged into preallocated recycled host buffers
-  (:class:`_StagingArena` — one memcpy per request, no per-batch
-  pad-then-stack allocation), and ``submit(low_res=True)`` shrinks the
-  return path too: the 1/8-grid flow, 64x fewer D2H bytes, with
-  host-side :func:`upsample_flow` recovery.
+  (:class:`~raft_tpu.utils.staging.StagingArena` — one memcpy per
+  request, no per-batch pad-then-stack allocation), and
+  ``submit(low_res=True)`` shrinks the return path too: the 1/8-grid
+  flow, 64x fewer D2H bytes, with host-side :func:`upsample_flow`
+  recovery.
 
 On top of those sits the **robustness layer** (Clipper-style: degrade
 gracefully, never let one failure take out its co-batched neighbors):
@@ -111,6 +112,7 @@ from raft_tpu.serving.metrics import (CompileWatch, ServingMetrics,
 from raft_tpu.utils.compile_cache import enable_compile_cache
 from raft_tpu.utils.padder import InputPadder
 from raft_tpu.utils.profiling import HostStageTimer
+from raft_tpu.utils.staging import StagingArena
 
 # Shared no-op context for `with <stage>, <maybe-span>:` sites — the
 # disabled-tracing path must not allocate a context manager per batch.
@@ -230,56 +232,6 @@ def _upsample_flow_impl(flow_low, padder, factor) -> np.ndarray:
     if padder is not None:
         out = padder.unpad(out)
     return np.ascontiguousarray(out)
-
-
-class _StagingArena:
-    """Per-(shape, dtype) pool of preallocated host staging buffers —
-    the zero-copy replacement for per-batch pad-then-stack allocation.
-
-    The dispatch thread ``acquire``s one buffer per stacked input,
-    writes each request's frame ONCE directly into its batch slot (a
-    single memcpy per request; no intermediate padded array, no
-    ``np.stack`` allocation per batch), and the buffer rides the
-    in-flight tuple until the completion thread has synced the batch's
-    outputs — only then is it ``release``d back to the pool, so
-    recycling can never overwrite bytes an executable might still read
-    (donation-compatible: donation consumes the *device* copy, never
-    the host buffer). Every slot — tail-pad included — is rewritten on
-    each acquire-fill cycle, so stale bytes from the previous batch
-    can't leak. Buffers from failed batches are dropped, not pooled
-    (the rare path keeps no aliasing questions open).
-    """
-
-    # Per-key cap: pipeline_depth batches in flight + one being staged
-    # covers steady state; beyond that, fall back to allocation rather
-    # than hold unbounded idle buffers.
-    _MAX_PER_KEY = 4
-
-    def __init__(self):
-        self._pools: Dict[Tuple, List[np.ndarray]] = {}
-        self._lock = threading.Lock()
-
-    def acquire(self, shape: Tuple, dtype) -> np.ndarray:
-        key = (tuple(int(s) for s in shape), np.dtype(dtype).str)
-        with self._lock:
-            pool = self._pools.get(key)
-            if pool:
-                return pool.pop()
-        return np.empty(key[0], dtype)
-
-    def release(self, *buffers) -> None:
-        for b in buffers:
-            if b is None:
-                continue
-            key = (b.shape, b.dtype.str)
-            with self._lock:
-                pool = self._pools.setdefault(key, [])
-                if len(pool) < self._MAX_PER_KEY:
-                    pool.append(b)
-
-    def pooled_buffers(self) -> int:
-        with self._lock:
-            return sum(len(p) for p in self._pools.values())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -709,8 +661,8 @@ class ServingEngine:
         self.metrics = ServingMetrics()
         self.stages = HostStageTimer()
         # Preallocated host staging buffers, recycled batch-to-batch by
-        # the completion threads (see _StagingArena).
-        self.arena = _StagingArena()
+        # the completion threads (see StagingArena).
+        self.arena = StagingArena()
         self.breaker = CircuitBreaker(
             threshold=self.config.breaker_threshold,
             cooldown_s=self.config.breaker_cooldown_s)

@@ -42,6 +42,31 @@ class InputPadder:
             out.append(np.pad(x, widths, mode="edge"))
         return out if len(out) > 1 else out[0]
 
+    def pad_into(self, dst, x):
+        """Write ``pad(x)`` into ``dst`` (a batch slot of a staging
+        buffer) without building it first: one copy of ``x`` into the
+        interior, then the edge columns and rows replicated in place.
+        Bit-identical to :meth:`pad`; every element of ``dst`` is
+        rewritten, so what it held before does not matter."""
+        l, r, t, b = self._pad
+        h, w = x.shape[-3], x.shape[-2]
+        want = x.shape[:-3] + (t + h + b, l + w + r, x.shape[-1])
+        if dst.shape != want or dst.dtype != x.dtype:
+            raise ValueError(
+                f"pad_into: a {x.dtype} frame of shape {x.shape} pads to "
+                f"{want}, got a {dst.dtype} destination of {dst.shape}")
+        dst[..., t:t + h, l:l + w, :] = x
+        rows = dst[..., t:t + h, :, :]
+        if l:
+            rows[..., :l, :] = rows[..., l:l + 1, :]
+        if r:
+            rows[..., l + w:, :] = rows[..., l + w - 1:l + w, :]
+        if t:
+            dst[..., :t, :, :] = dst[..., t:t + 1, :, :]
+        if b:
+            dst[..., t + h:, :, :] = dst[..., t + h - 1:t + h, :, :]
+        return dst
+
     def unpad(self, x):
         l, r, t, b = self._pad
         ht, wd = x.shape[-3], x.shape[-2]

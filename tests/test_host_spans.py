@@ -16,6 +16,8 @@ from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.evaluate import FlowPredictor, _predict_dataset
 from raft_tpu.models import RAFT
 from raft_tpu.utils import profiling
+from raft_tpu.utils.padder import InputPadder
+from raft_tpu.utils.staging import StagingArena
 
 H, W = 30, 44            # pads to 32x48 in sintel mode
 BS = 3
@@ -65,6 +67,7 @@ def by_name(spans, name):
 
 
 def test_pass_spans(predictor, timer, monkeypatch):
+    monkeypatch.setattr(predictor, "staging", StagingArena())
     flows = run_pass(predictor)
     spans = timer.spans()
     roots = by_name(spans, "pass.batch")
@@ -85,18 +88,33 @@ def test_pass_spans(predictor, timer, monkeypatch):
         assert all(k.start_ns >= root.start_ns
                    and k.start_ns + k.dur_ns <= root.start_ns + root.dur_ns
                    for k in kids)
-        assert by_name(kids, "pass.stack")[0].nbytes == 2 * frames
-        assert by_name(kids, "predict.h2d")[0].nbytes == 2 * frames
+        # pass.pad: each sample's one copy into its slot of the two
+        # staging buffers; pass.stack: what is left of stacking (the
+        # tail slots' fill and the hand-off), after the batch's last
+        # pad and before the predictor has the buffers
+        stack, h2d = by_name(kids, "pass.stack")[0], by_name(
+            kids, "predict.h2d")[0]
+        last_pad = by_name(kids, "pass.pad")[-1]
+        assert (last_pad.start_ns + last_pad.dur_ns <= stack.start_ns
+                and stack.start_ns + stack.dur_ns <= h2d.start_ns)
+        assert stack.nbytes == h2d.nbytes == 2 * frames
         assert by_name(kids, "predict.d2h")[0].nbytes == (
             BS * 32 * 48 * 2 * 4 + BS * 4 * 6 * 2 * 4)
     assert len(spans) == len(roots) * len(BATCH_LEVEL) + 3 * 7
     assert timer.dropped == 0
+    # the first batch's two buffers were allocated, the next two
+    # batches (the short one too) ran on the same pair, and it is back
+    assert [r.args["arena_fresh"] for r in roots] == [2, 0, 0]
+    assert predictor.staging.allocated == 2
+    assert predictor.staging.pooled_buffers() == 2
     assert timer.summary()["pass.fetch"]["count"] == 7
 
     # a second call is a new pass: its units start again
     run_pass(predictor)
-    assert [r.unit for r in by_name(timer.spans(), "pass.batch")] == [
-        0, 1, 2, 0, 1, 2]
+    roots = by_name(timer.spans(), "pass.batch")
+    assert [r.unit for r in roots] == [0, 1, 2, 0, 1, 2]
+    # and it finds the predictor's arena warm
+    assert [r.args["arena_fresh"] for r in roots[3:]] == [0, 0, 0]
 
     # without a ring: totals only, and the same flows to the last bit
     bare = profiling.HostStageTimer(ring=0)
@@ -108,6 +126,186 @@ def test_pass_spans(predictor, timer, monkeypatch):
     for a, b in zip(flows, again):
         assert a.shape == (H, W, 2)
         np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------- staging arena
+
+SHAPES = {"a": (30, 44), "b": (22, 36)}
+#: ten "a" pairs (three batches of three and one left over) with four
+#: "b" pairs (a batch and one left over) in between
+ORDER = "abaaabaaabaaba"
+
+
+class MixedPairs:
+    def __init__(self, dtype):
+        self.samples = []
+        for i, kind in enumerate(ORDER):
+            r = np.random.default_rng(i)
+            self.samples.append(tuple(
+                r.integers(0, 256, SHAPES[kind] + (3,)).astype(dtype)
+                for _ in range(2)))
+
+    def __len__(self):
+        return len(self.samples)
+
+    def __getitem__(self, i):
+        return self.samples[i]
+
+
+class LoggingArena(StagingArena):
+    def __init__(self, log):
+        super().__init__()
+        self.log = log
+
+    def release(self, *buffers):
+        self.log.append(("released", tuple(address(b) for b in buffers)))
+        super().release(*buffers)
+
+
+def address(array):
+    return array.__array_interface__["data"][0]
+
+
+class Recorder:
+    """Stands in for a batched predictor: keeps what it is handed."""
+
+    batch_size = BS
+
+    def __init__(self):
+        self.log, self.handed = [], []
+        self.staging = LoggingArena(self.log)
+
+    def predict_batch(self, images1, images2):
+        # while the predictor has them the buffers are not in the pool
+        assert self.staging.pooled_buffers() in (0, 2)   # the other shape's
+        assert not any(b is images1 or b is images2
+                       for pool in self.staging._pools.values()
+                       for b in pool)
+        self.handed.append((address(images1), address(images2),
+                            images1.copy(), images2.copy()))
+        up = images1[..., :2].astype(np.float32) - images2[..., :2]
+        self.log.append(("returned", (address(images1), address(images2))))
+        return up[:, ::8, ::8], up
+
+
+def old_batches(dataset, mode, bs):
+    """What ``_predict_dataset`` built before the arena: per-sample
+    ``np.pad``, ``np.stack`` a full bucket, ``np.concatenate`` the
+    repeated last frame onto a short one. Returns the stacks in flush
+    order and the dataset indices in yield order."""
+    stacks, order, buckets = [], [], {}
+
+    def flush(items):
+        i1, i2 = (np.stack([it[k] for it in items]) for k in (1, 2))
+        if len(items) < bs:
+            reps = bs - len(items)
+            i1 = np.concatenate([i1, np.repeat(i1[-1:], reps, 0)])
+            i2 = np.concatenate([i2, np.repeat(i2[-1:], reps, 0)])
+        stacks.append((i1, i2))
+        order.extend(it[0] for it in items)
+
+    for idx in range(len(dataset)):
+        im1, im2 = dataset[idx]
+        if mode:
+            im1, im2 = InputPadder(im1.shape, mode=mode).pad(im1, im2)
+        buckets.setdefault(im1.shape, []).append((idx, im1, im2))
+        if len(buckets[im1.shape]) == bs:
+            flush(buckets.pop(im1.shape))
+    for items in buckets.values():
+        flush(items)
+    return stacks, order
+
+
+@pytest.mark.parametrize("mode,dtype", [
+    ("sintel", np.float32), ("kitti", np.uint8), (None, np.float32)])
+def test_pass_stages_through_the_arena(timer, mode, dtype):
+    dataset, stub = MixedPairs(dtype), Recorder()
+    want, order = old_batches(dataset, mode, BS)
+    got = list(_predict_dataset(stub, dataset, mode))
+
+    # the predictor was handed what pad -> stack -> concatenate built
+    assert len(stub.handed) == len(want) == 6
+    for (_, _, i1, i2), (w1, w2) in zip(stub.handed, want):
+        assert i1.dtype == w1.dtype == dtype and i1.shape == w1.shape
+        assert i1.tobytes() == w1.tobytes() and i2.tobytes() == w2.tobytes()
+
+    # yields: the old order, the dataset's own samples, flows that are
+    # the predictor's output and no arena memory
+    assert [idx for idx, _, _ in got] == order
+    buffers = [b for pool in stub.staging._pools.values() for b in pool]
+    for idx, sample, flow in got:
+        assert sample is dataset.samples[idx]
+        im1, im2 = sample
+        np.testing.assert_array_equal(
+            flow, im1[..., :2].astype(np.float32) - im2[..., :2])
+        assert not any(np.shares_memory(flow, b) for b in buffers)
+
+    # one pair of buffers a shape: allocated for its first batch, the
+    # same two from its second batch on, and idle in the pool at the end
+    by_shape = {}
+    for a1, a2, i1, _ in stub.handed:
+        by_shape.setdefault(i1.shape, []).append({a1, a2})
+    assert sorted(len(v) for v in by_shape.values()) == [2, 4]
+    for pairs in by_shape.values():
+        assert len(pairs[0]) == 2 and all(p == pairs[0] for p in pairs)
+    assert stub.staging.allocated == 4 == len(buffers)
+    roots = by_name(timer.spans(), "pass.batch")
+    fresh = [r.args["arena_fresh"] for r in roots]
+    assert sorted(fresh) == [0, 0, 0, 0, 2, 2]
+    first = {}
+    for r, (_, _, i1, _) in zip(roots, stub.handed):
+        assert r.args["arena_fresh"] == (0 if i1.shape in first else 2)
+        first[i1.shape] = True
+        assert (r.args["height"], r.args["width"]) == i1.shape[1:3]
+
+    # a pair goes back only once predict_batch has returned it
+    assert [kind for kind, _ in stub.log] == ["returned", "released"] * 6
+    assert all(a == b for (_, a), (_, b) in zip(stub.log[::2],
+                                                stub.log[1::2]))
+
+    # a second pass over the same predictor starts warm
+    again = list(_predict_dataset(stub, dataset, mode))
+    assert stub.staging.allocated == 4
+    assert [r.args["arena_fresh"] for r in by_name(
+        timer.spans(), "pass.batch")[6:]] == [0] * 6
+    for (_, _, flow), (_, _, flow2) in zip(got, again):
+        np.testing.assert_array_equal(flow, flow2)
+
+
+def test_an_abandoned_pass_returns_its_open_buffers(timer):
+    """Closed while a bucket is still filling: nothing of that bucket is
+    in flight, so its pair goes back; a batch whose ``predict_batch``
+    raised does not."""
+    dataset, stub = MixedPairs(np.float32), Recorder()
+    gen = _predict_dataset(stub, dataset, "sintel")
+    next(gen)            # the first "a" batch yields; one "b" is waiting
+    gen.close()
+    assert stub.staging.pooled_buffers() == 4
+    assert len({address(b) for pool in stub.staging._pools.values()
+                for b in pool}) == 4
+
+    class Failing(Recorder):
+        def predict_batch(self, images1, images2):
+            raise RuntimeError("device lost")
+
+    failing = Failing()
+    with pytest.raises(RuntimeError, match="device lost"):
+        list(_predict_dataset(failing, dataset, "sintel"))
+    # the failed "a" pair is dropped; the waiting "b" pair is returned
+    assert failing.staging.pooled_buffers() == 2
+    assert all(b.shape == (BS, 24, 40, 3)
+               for pool in failing.staging._pools.values() for b in pool)
+
+
+def test_reported_pass_prints_the_reuse_share(timer, capsys):
+    from raft_tpu.evaluate import _reported_pass
+
+    stub = Recorder()
+    assert len(list(_reported_pass(stub, MixedPairs(np.float32),
+                                   "sintel"))) == len(ORDER)
+    line = capsys.readouterr().out
+    assert "host stages:" in line and "pass.stack" in line
+    assert "arena reuse: 67% of 12 buffers" in line
 
 
 def test_pass_closed_at_a_yield(predictor, timer):

@@ -59,6 +59,50 @@ def test_padder_noop_when_divisible():
     np.testing.assert_array_equal(p.pad(x), x)
 
 
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+@pytest.mark.parametrize("mode", ["sintel", "kitti"])
+@pytest.mark.parametrize("hw", [
+    (64, 128),     # divisible: nothing to pad
+    (436, 96),     # 4 rows: 2 + 2 in sintel mode, 4 below in kitti mode
+    (61, 43),      # odd on both axes: 3 rows (1 + 2), 5 columns (2 + 3)
+    (63, 47),      # one row, one column: all of it on the far side
+    (58, 90),      # even: 6 rows, 6 columns
+])
+def test_pad_into_equals_np_pad(hw, mode, dtype):
+    """``pad_into`` writes what ``np.pad(mode="edge")`` builds, bit for
+    bit, whatever the destination held before, and touches nothing
+    outside its slot."""
+    rng = np.random.default_rng(hw[0] * 1000 + hw[1])
+    x = rng.integers(0, 256, hw + (3,)).astype(dtype)
+    if dtype is np.float32:
+        x += rng.random(x.shape, np.float32)     # not integral
+    p = InputPadder(x.shape, mode=mode)
+    l, r, t, b = p._pad
+    want = np.pad(x, ((t, b), (l, r), (0, 0)), mode="edge")
+    np.testing.assert_array_equal(p.pad(x), want)
+    stale = rng.integers(0, 256, (3,) + want.shape).astype(dtype)
+    batch = stale.copy()
+    out = p.pad_into(batch[1], x)
+    assert out.dtype == dtype and np.shares_memory(out, batch)
+    assert out.tobytes() == want.tobytes()
+    np.testing.assert_array_equal(batch[0], stale[0])
+    np.testing.assert_array_equal(batch[2], stale[2])
+    np.testing.assert_array_equal(p.unpad(batch[1]), x)
+    # a whole stack at once, as pad() takes one
+    stack = np.stack([x, x[::-1]])
+    dst = np.empty((2,) + want.shape, dtype)
+    np.testing.assert_array_equal(p.pad_into(dst, stack), p.pad(stack))
+
+
+def test_pad_into_refuses_a_wrong_destination():
+    p = InputPadder((30, 44, 3), mode="sintel")
+    x = np.zeros((30, 44, 3), np.float32)
+    with pytest.raises(ValueError, match="pads to"):
+        p.pad_into(np.empty((30, 44, 3), np.float32), x)
+    with pytest.raises(ValueError, match="uint8 destination"):
+        p.pad_into(np.empty((32, 48, 3), np.uint8), x)
+
+
 def test_forward_interpolate_zero_flow_is_zero():
     flow = np.zeros((8, 10, 2), np.float32)
     out = forward_interpolate(flow)

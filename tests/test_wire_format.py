@@ -19,8 +19,9 @@ import pytest
 from raft_tpu.serving import (WIRE_F32, WIRE_U8, request_wire,
                               upsample_flow, wire_cast)
 from raft_tpu.serving.batcher import QueuedRequest
-from raft_tpu.serving.engine import _StagingArena, _base_of, _wire_of
+from raft_tpu.serving.engine import _base_of, _wire_of
 from raft_tpu.utils.padder import InputPadder
+from raft_tpu.utils.staging import StagingArena
 
 
 # -- wire detection (pure numpy) ----------------------------------------
@@ -125,7 +126,7 @@ class TestUint8PadderRoundTrip:
 
 class TestStagingArena:
     def test_acquire_shape_dtype_and_recycle_identity(self):
-        arena = _StagingArena()
+        arena = StagingArena()
         b = arena.acquire((4, 40, 64, 3), np.uint8)
         assert b.shape == (4, 40, 64, 3) and b.dtype == np.uint8
         arena.release(b)
@@ -135,7 +136,7 @@ class TestStagingArena:
         assert arena.pooled_buffers() == 0
 
     def test_dtype_keys_are_disjoint(self):
-        arena = _StagingArena()
+        arena = StagingArena()
         b = arena.acquire((2, 2), np.uint8)
         arena.release(b)
         other = arena.acquire((2, 2), np.float32)
@@ -143,10 +144,10 @@ class TestStagingArena:
         assert arena.pooled_buffers() == 1    # u8 buffer still pooled
 
     def test_per_key_cap_and_none_release(self):
-        arena = _StagingArena()
+        arena = StagingArena()
         bufs = [arena.acquire((3, 3), np.float32) for _ in range(6)]
         arena.release(None, *bufs, None)      # None slots are no-ops
-        assert arena.pooled_buffers() == _StagingArena._MAX_PER_KEY
+        assert arena.pooled_buffers() == StagingArena._MAX_PER_KEY
 
 
 # -- upsample_flow (host-side low_res recovery) -------------------------
