@@ -204,7 +204,87 @@ def sparse_corr_from_env() -> bool:
 # experiment snapshots (reference core/ours_02/03/04/06.py lineages —
 # raft_tpu/models/variants.py). Single source for every CLI's choices.
 MODEL_FAMILIES = ("raft", "sparse", "keypoint_transformer", "dual_query",
-                  "two_stage", "full_transformer")
+                  "two_stage", "full_transformer", "lfm2_moe")
+
+#: Families whose batches are packed token sequences, not image pairs:
+#: no panels, no validation sets, no BatchNorm to freeze, no
+#: ``image_size``; ``evaluate.py`` and ``demo.py`` offer the others.
+TOKEN_FAMILIES = ("lfm2_moe",)
+FLOW_FAMILIES = tuple(f for f in MODEL_FAMILIES if f not in TOKEN_FAMILIES)
+
+#: The published layer pattern of LFM2-24B-A2B: attention at every
+#: fourth layer from layer 2 on, gated short convolutions elsewhere.
+_LFM2_LAYER_TYPES = tuple(
+    "full_attention" if i % 4 == 2 else "conv" for i in range(40))
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """The ``lfm2_moe`` token family (LiquidAI LFM2-24B-A2B's
+    ``config.json`` keys, defaults as published) plus this chip's share
+    of an expert- and vocabulary-parallel deployment: the router always
+    scores all ``num_experts``; ``experts_held`` of them, from
+    ``expert_offset`` on, live here and only their terms are added up;
+    ``vocab_held`` rows of the (tied) embedding live here and the
+    logits and the loss are over them."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 11776        # dense SwiGLU (leading layers)
+    moe_intermediate_size: int = 1536     # each expert's SwiGLU
+    num_hidden_layers: int = 40
+    layer_types: Tuple[str, ...] = _LFM2_LAYER_TYPES
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    use_expert_bias: bool = True
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    vocab_size: int = 65536
+    # the chip's share (all of it by default)
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    vocab_held: Optional[int] = None
+    # bfloat16 operands with float32 accumulation; parameters, router
+    # scores, norm statistics, softmax and loss stay float32
+    mixed_precision: bool = True
+
+    def __post_init__(self):
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"num_hidden_layers is {self.num_hidden_layers}")
+        bad = set(self.layer_types) - {"conv", "full_attention"}
+        if bad:
+            raise ValueError(f"unknown layer types {sorted(bad)}")
+        if self.num_attention_heads % self.num_key_value_heads or \
+                self.hidden_size % self.num_attention_heads:
+            raise ValueError("heads must divide hidden_size and be a "
+                             "multiple of num_key_value_heads")
+        if not 0 < self.held <= self.num_experts - self.expert_offset:
+            raise ValueError(
+                f"experts {self.expert_offset}.."
+                f"{self.expert_offset + self.held} are not among the "
+                f"router's {self.num_experts}")
+        if not 0 < self.vocab <= self.vocab_size:
+            raise ValueError(f"vocab_held {self.vocab} of {self.vocab_size}")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def held(self) -> int:
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held)
+
+    @property
+    def vocab(self) -> int:
+        return self.vocab_size if self.vocab_held is None else self.vocab_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,6 +301,9 @@ class TrainConfig:
     num_steps: int = 100000
     batch_size: int = 8
     image_size: Tuple[int, int] = (368, 496)
+    # tokens a sequence; read by the token family (``lfm2_moe``) only,
+    # which has no ``image_size``
+    seq_len: int = 8192
     wdecay: float = 1e-4
     epsilon: float = 1e-8
     clip: float = 1.0

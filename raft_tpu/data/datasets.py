@@ -780,10 +780,17 @@ def fetch_dataloader(stage: str, batch_size: int,
                      num_workers: Optional[int] = None, seed: int = 0,
                      root: Optional[str] = None,
                      full_mix: bool = True,
-                     loader: str = "auto") -> DataLoader:
+                     loader: str = "auto",
+                     tokens: Optional[dict] = None) -> DataLoader:
     """Stage-specific dataset mixtures (reference
     ``core/datasets.py:205-240``). ``loader``/``num_workers``: see
-    :func:`select_loader`."""
+    :func:`select_loader`. ``tokens`` (``seq_len``, ``vocab``, optional
+    ``token_file``) asks for the token family's packed sequences
+    instead (``raft_tpu/data/tokens.py``); ``stage`` and ``image_size``
+    are not read then."""
+    if tokens is not None:
+        from raft_tpu.data.tokens import TokenLoader
+        return TokenLoader(batch_size, seed=seed, **tokens)
     cls, num_workers = select_loader(loader, num_workers)
     crop = {"crop_size": image_size}
     if stage == "chairs":

@@ -16,7 +16,7 @@ from glob import glob
 import numpy as np
 from PIL import Image
 
-from raft_tpu.config import MODEL_FAMILIES
+from raft_tpu.config import FLOW_FAMILIES
 from raft_tpu.evaluate import (ASSETS_DIR, load_predictor,
                                reject_raft_only_flags)
 from raft_tpu.utils.flow_viz import flow_to_image
@@ -70,7 +70,7 @@ def main(argv=None):
                              "repo-owned assets/demo-frames fixtures)")
     parser.add_argument("--out", default="demo_out")
     parser.add_argument("--model_family", default="raft",
-                        choices=list(MODEL_FAMILIES))
+                        choices=list(FLOW_FAMILIES))
     parser.add_argument("--small", action="store_true")
     parser.add_argument("--iters", type=int, default=None,
                         help="refinement iterations (canonical RAFT "

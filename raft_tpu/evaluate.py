@@ -1373,9 +1373,9 @@ def main(argv=None):
                         choices=list(_VALIDATORS) + ["sintel_submission",
                                                      "kitti_submission"])
     parser.add_argument("--small", action="store_true")
-    from raft_tpu.config import MODEL_FAMILIES
+    from raft_tpu.config import FLOW_FAMILIES
     parser.add_argument("--model_family", default="raft",
-                        choices=list(MODEL_FAMILIES))
+                        choices=list(FLOW_FAMILIES))
     parser.add_argument("--iters", type=int, default=None)
     parser.add_argument("--alternate_corr", action="store_true")
     parser.add_argument("--mixed_precision", action="store_true")

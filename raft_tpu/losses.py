@@ -167,3 +167,29 @@ def sparse_keypoint_loss(sparse_preds, flow_gt: jnp.ndarray,
         l1 = jnp.abs(key_flows - gt_at_kp) * v_at_kp
         total = total + l1.sum() / jnp.maximum(v_at_kp.sum() * 2.0, 1.0)
     return total / max(len(sparse_preds), 1)
+
+
+def token_cross_entropy(logits, tokens, segment_ids):
+    """Mean next-token cross-entropy over the positions whose target
+    lies in the same document.
+
+    ``logits`` (B, S, V) float32 over the vocabulary held, ``tokens``
+    and ``segment_ids`` (B, S): position ``t`` predicts ``tokens[t+1]``
+    where ``segment_ids[t+1] == segment_ids[t]``; the last position of
+    a sequence and the last of each document predict nothing. Returns
+    ``(loss, metrics)``; ``metrics["tokens"]`` counts the positions
+    that entered the mean.
+    """
+    with jax.named_scope("token_loss"):
+        # rolled, not sliced: a (B, S-1, V) copy of the logits is 1 GB
+        targets = jnp.roll(tokens, -1, axis=1)
+        counted = (jnp.roll(segment_ids, -1, axis=1) == segment_ids) \
+            & (jnp.arange(tokens.shape[1]) < tokens.shape[1] - 1)
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, targets[..., None],
+                                     axis=-1)[..., 0]
+        n = counted.sum()
+        loss = jnp.where(counted, logz - picked, 0.0).sum() \
+            / jnp.maximum(n, 1)
+    return loss, {"loss": loss, "tokens": n.astype(jnp.int32)}

@@ -77,7 +77,8 @@ from jax.experimental import pallas as pl
 #: kernels a program really lowered (``kernel_census``) without parsing
 #: kernel bodies.
 KERNEL_NAMES = {key: f"raft_{key}" for key in (
-    "corr_fwd", "corr_bwd", "gru", "motion", "step", "msda_fwd", "msda_bwd")}
+    "corr_fwd", "corr_bwd", "gru", "motion", "step", "msda_fwd", "msda_bwd",
+    "expert_gmm", "attn")}
 
 
 def kernel_census(compiled_text: str) -> dict:

@@ -123,14 +123,16 @@ def _decay_mask(params):
     off them too or they would shrink by ``(1 - lr*wd)`` every step. In
     torch they are buffers, which AdamW never touches — this mask restores
     that semantics. A frozen-BN subtree is recognized by its
-    ``running_mean``/``running_var`` keys.
+    ``running_mean``/``running_var`` keys. The expert router's selection
+    bias (``expert_bias``, ``models/lfm2.py``) is a buffer in the same
+    sense: no gradient reaches it, and decay must not shrink it.
     """
-    def mask_tree(tree):
+    def mask_tree(tree, name=None):
         if isinstance(tree, dict):
             if "running_mean" in tree and "running_var" in tree:
                 return {k: False for k in tree}
-            return {k: mask_tree(v) for k, v in tree.items()}
-        return True
+            return {k: mask_tree(v, k) for k, v in tree.items()}
+        return name != "expert_bias"
 
     # unwrap FrozenDict-likes into plain dicts for optax
     plain = jax.tree_util.tree_map(lambda x: x, params)

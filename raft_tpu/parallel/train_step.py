@@ -11,7 +11,7 @@ all-reduce (the TPU equivalent of ``nn.DataParallel``'s gather +
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,16 +51,16 @@ class RAFTTrainState(struct.PyTreeNode):
 
 
 def create_train_state(rng, model, tcfg: TrainConfig,
-                       image_shape: Tuple[int, int],
+                       image_shape: Optional[Tuple[int, int]] = None,
                        tx: Optional[optax.GradientTransformation] = None,
                        mesh: Optional[Mesh] = None) -> RAFTTrainState:
-    """Initialize params + opt state (replicated over ``mesh`` if given)."""
+    """Initialize params + opt state (replicated over ``mesh`` if given).
+    The family's row of ``FAMILIES`` makes the inputs the model is
+    initialised on (``image_shape`` is read by the flow families only)."""
     from raft_tpu.optim import fetch_optimizer
 
-    H, W = image_shape
-    dummy = jnp.zeros((1, H, W, 3), jnp.float32)
-    variables = model.init({"params": rng, "dropout": rng},
-                           dummy, dummy, iters=1)
+    args, kwargs = family_of(tcfg).init_inputs(tcfg, image_shape)
+    variables = model.init({"params": rng, "dropout": rng}, *args, **kwargs)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", core.FrozenDict({}))
     tx = tx if tx is not None else fetch_optimizer(tcfg)
@@ -92,6 +92,150 @@ def _all_finite(tree) -> jnp.ndarray:
     return functools.reduce(jnp.logical_and, leaves, jnp.bool_(True))
 
 
+# ------------------------------------------------------------ the families
+#
+# One row a ``model_family``: how to make the inputs its model is
+# initialised on, and how to turn ``(apply_fn, variables, batch, rngs,
+# step)`` into ``(loss, metrics, mutated)``. Everything else in the step
+# (the guard, ``apply_gradients``, donation, jit) is one code path.
+
+class Family(NamedTuple):
+    #: ``(tcfg, image_shape) -> (args, kwargs)`` of ``model.init``
+    init_inputs: Callable
+    #: ``(tcfg, freeze_bn) -> loss_fn(apply_fn, variables, batch, rngs,
+    #: step) -> (loss, metrics, mutated)``; ``rngs`` holds ``noise`` and
+    #: ``dropout`` keys already folded with the step
+    make_loss: Callable
+
+
+def _flow_init_inputs(tcfg, image_shape):
+    H, W = image_shape if image_shape is not None else tcfg.image_size
+    dummy = jnp.zeros((1, H, W, 3), jnp.float32)
+    return (dummy, dummy), {"iters": 1}
+
+
+def _flow_apply(tcfg, freeze_bn, apply_fn, variables, batch, rngs):
+    image1, image2 = batch["image1"], batch["image2"]
+    if tcfg.add_noise:
+        image1, image2 = _maybe_add_noise(rngs["noise"], image1, image2)
+    return apply_fn(
+        variables, image1, image2, iters=tcfg.iters,
+        train=True, freeze_bn=freeze_bn,
+        rngs={"dropout": rngs["dropout"]},
+        mutable=["batch_stats"])
+
+
+def _raft_loss(tcfg, freeze_bn):
+    def loss_fn(apply_fn, variables, batch, rngs, step):
+        out, mutated = _flow_apply(tcfg, freeze_bn, apply_fn, variables,
+                                   batch, rngs)
+        loss, metrics = sequence_loss(
+            out, batch["flow"], batch["valid"], gamma=tcfg.gamma,
+            normalization=tcfg.loss_normalization)
+        return loss, metrics, mutated
+    return loss_fn
+
+
+def _flow_list_loss(tcfg, freeze_bn):
+    """ours_02 snapshot: a plain list of dense flows."""
+    def loss_fn(apply_fn, variables, batch, rngs, step):
+        flow_preds, mutated = _flow_apply(tcfg, freeze_bn, apply_fn,
+                                          variables, batch, rngs)
+        loss, metrics = sequence_loss(
+            jnp.stack(list(flow_preds)), batch["flow"],
+            batch["valid"], gamma=tcfg.gamma,
+            normalization=tcfg.loss_normalization)
+        return loss, metrics, mutated
+    return loss_fn
+
+
+def _flow_corr_loss(tcfg, freeze_bn):
+    """The two-list snapshot trainer (reference train_02.py:54-81): flow
+    + corr predictions, each under a uniformly-weighted masked L1."""
+    def loss_fn(apply_fn, variables, batch, rngs, step):
+        from raft_tpu.losses import sequence_corr_loss
+        (flow_preds, corr_preds), mutated = _flow_apply(
+            tcfg, freeze_bn, apply_fn, variables, batch, rngs)
+        loss, metrics = sequence_corr_loss(
+            jnp.stack(list(flow_preds)),
+            jnp.stack(list(corr_preds)),
+            batch["flow"], batch["valid"])
+        return loss, metrics, mutated
+    return loss_fn
+
+
+def _sparse_loss(tcfg, freeze_bn):
+    """The fork's active trainer (reference train.py:19 ->
+    core/ours.py): list of per-outer-iteration dense flows plus sparse
+    keypoint predictions ((ref, key_flow, ...) tuples —
+    TwoStageKeypointRAFT emits the same contract), with the auxiliary
+    sparse loss gated to the first sparse_lambda_steps (reference
+    train.py:379-383)."""
+    def loss_fn(apply_fn, variables, batch, rngs, step):
+        (flow_preds, sparse_preds), mutated = _flow_apply(
+            tcfg, freeze_bn, apply_fn, variables, batch, rngs)
+        out = jnp.stack(list(flow_preds))
+        loss, metrics = sequence_loss(
+            out, batch["flow"], batch["valid"], gamma=tcfg.gamma,
+            normalization=tcfg.loss_normalization)
+        if tcfg.sparse_lambda > 0:
+            from raft_tpu.losses import sparse_keypoint_loss
+            # key flows are normalized src-dst offsets; the loss
+            # compares in pixels, scaled by (W-1, H-1) like the
+            # reference (train.py:73-82)
+            _, H_, W_, _ = batch["flow"].shape
+            scale = jnp.asarray([W_ - 1, H_ - 1], jnp.float32)
+            sparse = sparse_keypoint_loss(
+                [(p[0], p[1] * scale) for p in sparse_preds],
+                batch["flow"], batch["valid"])
+            lam = tcfg.sparse_lambda * (step < tcfg.sparse_lambda_steps)
+            loss = loss + lam * sparse
+            metrics["sparse_loss"] = sparse
+            metrics["loss"] = loss
+        return loss, metrics, mutated
+    return loss_fn
+
+
+def _token_init_inputs(tcfg, image_shape):
+    # parameter shapes do not depend on the sequence's length: a short
+    # one keeps the initialising forward off the kernels' tilings
+    dummy = jnp.zeros((1, min(tcfg.seq_len, 8)), jnp.int32)
+    return (dummy, dummy, dummy), {}
+
+
+def _token_loss(tcfg, freeze_bn):
+    """Next-token cross-entropy over the vocabulary held; the routing
+    counters of the expert layers ride the metrics."""
+    def loss_fn(apply_fn, variables, batch, rngs, step):
+        from raft_tpu.losses import token_cross_entropy
+        logits, counters = apply_fn(
+            {"params": variables["params"]}, batch["tokens"],
+            batch["segment_ids"], batch["positions"], train=True)
+        loss, metrics = token_cross_entropy(logits, batch["tokens"],
+                                            batch["segment_ids"])
+        metrics.update(counters)
+        return loss, metrics, {}
+    return loss_fn
+
+
+FAMILIES: Dict[str, Family] = {
+    "raft": Family(_flow_init_inputs, _raft_loss),
+    "keypoint_transformer": Family(_flow_init_inputs, _flow_list_loss),
+    "dual_query": Family(_flow_init_inputs, _flow_corr_loss),
+    "full_transformer": Family(_flow_init_inputs, _flow_corr_loss),
+    "sparse": Family(_flow_init_inputs, _sparse_loss),
+    "two_stage": Family(_flow_init_inputs, _sparse_loss),
+    "lfm2_moe": Family(_token_init_inputs, _token_loss),
+}
+
+def family_of(tcfg: TrainConfig) -> Family:
+    try:
+        return FAMILIES[tcfg.model_family]
+    except KeyError:
+        raise ValueError(f"unknown model_family {tcfg.model_family!r}; "
+                         f"choose from {sorted(FAMILIES)}") from None
+
+
 def make_train_step(tcfg: TrainConfig, freeze_bn: bool = False,
                     mesh: Optional[Mesh] = None,
                     donate: bool = True,
@@ -118,78 +262,25 @@ def make_train_step(tcfg: TrainConfig, freeze_bn: bool = False,
     no injection nodes are traced.
 
     Returns ``step_fn(state, batch, rng) -> (state, metrics)`` where
-    ``batch`` is a dict with ``image1/image2`` (B,H,W,3) float [0,255],
-    ``flow`` (B,H,W,2), ``valid`` (B,H,W).
+    ``batch`` is what the family's loss reads: for the flow families a
+    dict with ``image1/image2`` (B,H,W,3) float [0,255], ``flow``
+    (B,H,W,2), ``valid`` (B,H,W); for the token family ``tokens``,
+    ``segment_ids``, ``positions`` (B,S) int32.
     """
     nan_steps = tuple(active_injector().nan_loss_steps)
+
+    family_loss = family_of(tcfg).make_loss(tcfg, freeze_bn)
 
     def step_fn(state: RAFTTrainState, batch: Dict[str, jnp.ndarray], rng):
         noise_rng, dropout_rng = jax.random.split(
             jax.random.fold_in(rng, state.step))
-        image1, image2 = batch["image1"], batch["image2"]
-        if tcfg.add_noise:
-            image1, image2 = _maybe_add_noise(noise_rng, image1, image2)
+        rngs = {"noise": noise_rng, "dropout": dropout_rng}
 
         def loss_fn(params):
             variables = {"params": params,
                          "batch_stats": state.batch_stats}
-
-            def apply(v):
-                return state.apply_fn(
-                    v, image1, image2, iters=tcfg.iters,
-                    train=True, freeze_bn=freeze_bn,
-                    rngs={"dropout": dropout_rng},
-                    mutable=["batch_stats"])
-
-            if tcfg.model_family in ("dual_query", "full_transformer"):
-                # The two-list snapshot trainer (reference
-                # train_02.py:54-81): flow + corr predictions, each under
-                # a uniformly-weighted masked L1.
-                from raft_tpu.losses import sequence_corr_loss
-                (flow_preds, corr_preds), mutated = apply(variables)
-                loss, metrics = sequence_corr_loss(
-                    jnp.stack(list(flow_preds)),
-                    jnp.stack(list(corr_preds)),
-                    batch["flow"], batch["valid"])
-            elif tcfg.model_family == "keypoint_transformer":
-                # ours_02 snapshot: a plain list of dense flows.
-                flow_preds, mutated = apply(variables)
-                loss, metrics = sequence_loss(
-                    jnp.stack(list(flow_preds)), batch["flow"],
-                    batch["valid"], gamma=tcfg.gamma,
-                    normalization=tcfg.loss_normalization)
-            elif tcfg.model_family in ("sparse", "two_stage"):
-                # The fork's active trainer (reference train.py:19 →
-                # core/ours.py): list of per-outer-iteration dense flows
-                # plus sparse keypoint predictions ((ref, key_flow, ...)
-                # tuples — TwoStageKeypointRAFT emits the same contract),
-                # with the auxiliary sparse loss gated to the first
-                # sparse_lambda_steps (reference train.py:379-383).
-                (flow_preds, sparse_preds), mutated = apply(variables)
-                out = jnp.stack(list(flow_preds))
-                loss, metrics = sequence_loss(
-                    out, batch["flow"], batch["valid"], gamma=tcfg.gamma,
-                    normalization=tcfg.loss_normalization)
-                if tcfg.sparse_lambda > 0:
-                    from raft_tpu.losses import sparse_keypoint_loss
-                    # key flows are normalized src-dst offsets; the loss
-                    # compares in pixels, scaled by (W-1, H-1) like the
-                    # reference (train.py:73-82)
-                    _, H_, W_, _ = batch["flow"].shape
-                    scale = jnp.asarray([W_ - 1, H_ - 1], jnp.float32)
-                    sparse = sparse_keypoint_loss(
-                        [(p[0], p[1] * scale) for p in sparse_preds],
-                        batch["flow"], batch["valid"])
-                    lam = tcfg.sparse_lambda * (
-                        state.step < tcfg.sparse_lambda_steps)
-                    loss = loss + lam * sparse
-                    metrics["sparse_loss"] = sparse
-                    metrics["loss"] = loss
-            else:
-                out, mutated = apply(variables)
-                loss, metrics = sequence_loss(
-                    out, batch["flow"], batch["valid"], gamma=tcfg.gamma,
-                    normalization=tcfg.loss_normalization)
+            loss, metrics, mutated = family_loss(
+                state.apply_fn, variables, batch, rngs, state.step)
             # Under freeze_bn (or a BN-free model) nothing is written to
             # the batch_stats collection; keep the existing stats then.
             new_bs = mutated.get("batch_stats")

@@ -35,7 +35,8 @@ import threading
 
 from raft_tpu import checkpoint as ckpt_lib
 from raft_tpu import evaluate
-from raft_tpu.config import MODEL_FAMILIES, RAFTConfig, TrainConfig
+from raft_tpu.config import (MODEL_FAMILIES, TOKEN_FAMILIES, LMConfig,
+                             RAFTConfig, TrainConfig)
 from raft_tpu.resilience import TrainingDiverged, all_hosts_agree
 from raft_tpu.models.raft import RAFT
 from raft_tpu.optim import make_schedule
@@ -103,7 +104,17 @@ def _eval_variables(state):
     return {"params": state.params, "batch_stats": state.batch_stats}
 
 
-def build_model(model_family: str, mcfg: RAFTConfig):
+#: the routing counters a token family's step reports; ``train()`` puts
+#: them on the step's span as integers and into the scalar stream
+STEP_COUNTERS = ("tokens", "routed_here", "expert_load_max", "dropped")
+
+
+def build_model(model_family: str, mcfg):
+    """``mcfg``: a ``RAFTConfig`` for the flow families, an ``LMConfig``
+    for the token family."""
+    if model_family == "lfm2_moe":
+        from raft_tpu.models.lfm2 import LFM2
+        return LFM2(mcfg)
     if model_family == "sparse":
         from raft_tpu.config import OursConfig, sparse_corr_from_env
         from raft_tpu.models import SparseRAFT
@@ -160,7 +171,7 @@ def _panels_and_validation(tcfg, model, state, batch, panel_fn,
         logger.write_dict(results, step=step)
 
 
-def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
+def train(tcfg: TrainConfig, mcfg, *,
           data_root: Optional[str] = None,
           ckpt_dir: str = "checkpoints",
           log_dir: str = "runs",
@@ -180,13 +191,22 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
     ``spatial_shards`` > 1 splits image rows over that many mesh columns
     (sequence parallelism; canonical family only — the 2-D data x
     spatial step is what ``dryrun_multichip`` validates).
+
+    A token family (``TOKEN_FAMILIES``; ``mcfg`` an ``LMConfig``) goes
+    through the same loop, state, optimizer, guard, checkpointer and
+    spans; it has no image panels, validation sets, BatchNorm to freeze
+    or ``image_size``, its loader yields packed sequences
+    (``data_root`` names an optional token file), and its routing
+    counters (``STEP_COUNTERS``) ride each ``train.step`` span.
     """
+    tokens = tcfg.model_family in TOKEN_FAMILIES
+    image_size = None if tokens else tcfg.image_size
     rng = jax.random.PRNGKey(tcfg.seed)
     np.random.seed(tcfg.seed)                 # host-side aug reproducibility
 
     from raft_tpu.parallel.mesh import validate_spatial_shards
     validate_spatial_shards(spatial_shards, tcfg.model_family,
-                            image_height=tcfg.image_size[0])
+                            image_height=image_size and image_size[0])
     mesh = make_mesh(n_spatial=spatial_shards)
     model = build_model(tcfg.model_family, mcfg)
     run_ckpt_dir = os.path.join(ckpt_dir, tcfg.name)
@@ -206,7 +226,7 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
     restored_loader_state = None
     resumed = False
     with ckptr, mesh:
-        state = create_train_state(rng, model, tcfg, tcfg.image_size,
+        state = create_train_state(rng, model, tcfg, image_size,
                                    mesh=mesh)
         if resume and ckptr.latest_step() is not None:
             state = ckptr.restore(state)
@@ -223,16 +243,17 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
 
         # Post-chairs BN freeze (reference train.py:414-415,
         # core/raft.py:60-63).
-        freeze_bn = tcfg.stage != "chairs"
+        freeze_bn = tcfg.stage != "chairs" and not tokens
         step_fn = make_train_step(tcfg, freeze_bn=freeze_bn, mesh=mesh)
         schedule = make_schedule(tcfg)
 
         if dataloader is None:
             from raft_tpu.data.datasets import fetch_dataloader
-            dataloader = fetch_dataloader(tcfg.stage, tcfg.batch_size,
-                                          tcfg.image_size, seed=tcfg.seed,
-                                          root=data_root, loader=loader,
-                                          num_workers=num_workers)
+            dataloader = fetch_dataloader(
+                tcfg.stage, tcfg.batch_size, image_size, seed=tcfg.seed,
+                root=data_root, loader=loader, num_workers=num_workers,
+                tokens={"seq_len": tcfg.seq_len, "vocab": mcfg.vocab,
+                        "token_file": data_root} if tokens else None)
         # Exact-cursor resume: restore this process's input-pipeline
         # state BEFORE the first post-resume batch, so the stream
         # continues at the precise sample the checkpointed step had
@@ -254,7 +275,7 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
         # One extra jitted forward per val_freq to render the reference's
         # training image panels (train.py:395-396 → :170-334) from the
         # current batch with current params.
-        panel_fn = jax.jit(
+        panel_fn = None if tokens else jax.jit(
             lambda variables, i1, i2: model.apply(variables, i1, i2,
                                                   iters=tcfg.iters))
 
@@ -338,6 +359,9 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
                         with timer.span("train.metrics_fetch",
                                         leaves=len(metrics)):
                             host_metrics = jax.device_get(metrics)
+                        for key in STEP_COUNTERS:
+                            if key in host_metrics:
+                                step_span.args[key] = int(host_metrics[key])
                         with timer.span("train.log"):
                             # Degradation counters into the scalar
                             # stream (logger accumulates them as run
@@ -378,12 +402,13 @@ def train(tcfg: TrainConfig, mcfg: RAFTConfig, *,
                         with timer.span("train.checkpoint",
                                         unit=total_steps):
                             ckptr.save(state, loader_state=loader_snap)
-                        with timer.span("train.validation",
-                                        unit=total_steps):
-                            _panels_and_validation(
-                                tcfg, model, state, batch, panel_fn,
-                                validation, eval_iters, logger,
-                                total_steps)
+                        if not tokens:
+                            with timer.span("train.validation",
+                                            unit=total_steps):
+                                _panels_and_validation(
+                                    tcfg, model, state, batch, panel_fn,
+                                    validation, eval_iters, logger,
+                                    total_steps)
                         # A SIGTERM landing during the validation/panel
                         # block above must not wait for the next batch
                         # to complete: re-vote here (deterministic
@@ -444,6 +469,20 @@ def resolve_train_corr_engine(model_family, corr_impl, alternate_corr,
                                         spatial_shards=spatial_shards))
 
 
+def lm_config_from_json(path: Optional[str]) -> LMConfig:
+    """``LMConfig`` from a JSON file's ``model`` object (or its top
+    level); ``None`` gives the published model whole."""
+    if path is None:
+        return LMConfig()
+    import json
+    with open(path) as f:
+        keys = json.load(f)
+    keys = dict(keys.get("model", keys))
+    if "layer_types" in keys:
+        keys["layer_types"] = tuple(keys["layer_types"])
+    return LMConfig(**keys)
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser(
         description="Train RAFT (TPU-native). Flags mirror the reference "
@@ -454,9 +493,26 @@ def main(argv=None):
     parser.add_argument("--model_family", default="raft",
                         choices=list(MODEL_FAMILIES),
                         help="canonical RAFT, the fork's sparse-keypoint "
-                             "(ours) family, or a rebuilt experiment "
+                             "(ours) family, a rebuilt experiment "
                              "snapshot (keypoint_transformer=ours_02, "
-                             "dual_query=ours_04, two_stage=ours_06)")
+                             "dual_query=ours_04, two_stage=ours_06), or "
+                             "lfm2_moe: the LFM2-MoE language model "
+                             "(gated short convolutions, grouped-query "
+                             "attention, sigmoid-routed experts) on packed "
+                             "token sequences; see --lm_config, --seq_len")
+    parser.add_argument("--lm_config", default=None,
+                        help="lfm2_moe only: a JSON file whose `model` "
+                             "object (or top level) holds LMConfig keys: "
+                             "the published sizes and this chip's share "
+                             "(experts_held, expert_offset, vocab_held), "
+                             "e.g. benchmark/configs/lfm2_24b_a2b.json; "
+                             "default: the published 40-layer model whole")
+    parser.add_argument("--seq_len", type=int, default=8192,
+                        help="lfm2_moe only: tokens a packed sequence "
+                             "(--batch_size counts sequences; --data_root "
+                             "names an optional .npz token file with "
+                             "`tokens` and document `offsets`, else the "
+                             "stream is seeded)")
     parser.add_argument("--sparse_lambda", type=float, default=0.0,
                         help="auxiliary sparse loss weight (first 20k "
                              "steps; reference train.py:379-383)")
@@ -512,8 +568,8 @@ def main(argv=None):
                              "training: 'auto' (default for the raft "
                              "family) trains through the on-demand "
                              "banded kernel on TPU when the crop fits "
-                             "its backward VMEM budget — measured +34% "
-                             "samples/s at chairs b4 and +49% at b8 "
+                             "its backward VMEM budget — measured +34%% "
+                             "samples/s at chairs b4 and +49%% at b8 "
                              "with ~1.4 GB less HBM, numerics "
                              "identical; 'fixed' honors "
                              "--alternate_corr as given")
@@ -536,6 +592,13 @@ def main(argv=None):
     enable_compile_cache()
 
     evaluate.reject_raft_only_flags(parser, args)   # incl. --iters
+    tokens = args.model_family in TOKEN_FAMILIES
+    if not tokens and args.lm_config:
+        parser.error("--lm_config applies to the lfm2_moe family only")
+    if tokens and (args.validation or args.spatial_shards != 1
+                   or args.restore_ckpt):
+        parser.error("--validation, --spatial_shards and --restore_ckpt "
+                     "apply to the flow families only")
     # only the keypoint families consume the auxiliary sparse loss
     if args.sparse_lambda > 0 and args.model_family not in ("sparse",
                                                             "two_stage"):
@@ -557,16 +620,20 @@ def main(argv=None):
         model_family=args.model_family, sparse_lambda=args.sparse_lambda,
         lr=args.lr,
         num_steps=args.num_steps, batch_size=args.batch_size,
-        image_size=tuple(args.image_size), wdecay=args.wdecay,
+        image_size=tuple(args.image_size), seq_len=args.seq_len,
+        wdecay=args.wdecay,
         epsilon=args.epsilon, clip=args.clip, gamma=args.gamma,
         add_noise=args.add_noise, iters=iters,
         val_freq=args.val_freq, scheduler=args.scheduler, seed=args.seed,
         async_checkpointing=args.async_ckpt)
-    mcfg = RAFTConfig(
-        small=args.small, dropout=args.dropout, iters=iters,
-        alternate_corr=alternate,
-        mixed_precision=args.mixed_precision,
-        corr_dtype=args.corr_dtype or "auto")
+    if tokens:
+        mcfg = lm_config_from_json(args.lm_config)
+    else:
+        mcfg = RAFTConfig(
+            small=args.small, dropout=args.dropout, iters=iters,
+            alternate_corr=alternate,
+            mixed_precision=args.mixed_precision,
+            corr_dtype=args.corr_dtype or "auto")
 
     t0 = time.time()
     train(tcfg, mcfg, data_root=args.data_root, ckpt_dir=args.ckpt_dir,

@@ -162,6 +162,45 @@ def _msda_dense():
     return fn, shapes, None
 
 
+def _expert_gmm(rows, tiling=None):
+    """The expert layer's grouped products at LFM2-24B-A2B's widths
+    (hidden 2048, expert width 1536), 8 of 64 experts held, over the
+    sorted buffer of a 32768-token step's assignments: forward and both
+    backward products."""
+    from raft_tpu.ops import gmm
+
+    def fn(lhs, rhs, sizes):
+        def loss(lhs, rhs):
+            kw = {} if tiling is None else {"tiling": tiling}
+            out = gmm.expert_gmm(lhs, rhs, sizes, 8, impl="pallas",
+                                 interpret=False, **kw)
+            return out.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1))(lhs, rhs)
+
+    shapes = [((rows, 2048), jnp.bfloat16), ((8, 2048, 1536), jnp.bfloat16),
+              ((64,), jnp.int32)]
+    return fn, shapes, None
+
+
+def _attn(seq, block=None):
+    """Blocked causal attention at LFM2-24B-A2B's heads (32 query, 8
+    key-value, 64 wide), two packed sequences: forward and backward."""
+    from raft_tpu.ops import attention
+
+    def fn(q, k, v, seg):
+        def loss(q, k, v):
+            kw = {} if block is None else {"block": block}
+            out = attention.causal_attention(q, k, v, seg, scale=0.125,
+                                             impl="pallas", **kw)
+            return out.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    shapes = [((2, 32, seq, 64), jnp.bfloat16),
+              ((2, 8, seq, 64), jnp.bfloat16),
+              ((2, 8, seq, 64), jnp.bfloat16), ((2, seq), jnp.int32)]
+    return fn, shapes, None
+
+
 BF16, F32 = jnp.bfloat16, jnp.float32
 
 # (build, kernel expected in the compiled text, or ValueError if the
@@ -179,6 +218,13 @@ CASES = {
     "step_mg_bf16_chairs": (lambda: _step(BF16, CHAIRS, False), "step"),
     "step_mgf_bf16_sintel": (lambda: _step(BF16, SINTEL, True), "step"),
     "msda_dense_352x480": (_msda_dense, "msda_fwd"),
+    "expert_gmm_bf16_131072_rows": (lambda: _expert_gmm(131072),
+                                    "expert_gmm"),
+    "attn_bf16_8192": (lambda: _attn(8192), "attn"),
+    # refused before Mosaic: tiles whose double buffers pass 13 MiB
+    "refuse_expert_gmm_tiles_of_2048": (
+        lambda: _expert_gmm(131072, (2048, 2048, 1536)), ValueError),
+    "refuse_attn_blocks_of_2048": (lambda: _attn(8192, 2048), ValueError),
     # Refused by the static admission rule, before Mosaic: the fused step
     # at a 1080p feature map (no tile fits), and tiles Mosaic was seen to
     # take more than the limit for (f32 GRU TH=16 at 1080p: 104.7 MiB;
