@@ -22,13 +22,28 @@ Design (TPU-first, not a CUDA translation):
 
   2. **Separable bilinear windows**: a bilinear sample at ``(cx+ox, cy+oy)``
      factors into 1-D "hat" weights ``max(0, 1-|y-(cy+oy)|)`` times
-     ``max(0, 1-|x-(cx+ox)|)``. Sweeping the target rows ``y`` in order, each
-     row's correlation slice is folded into the ``2r+1`` y-offset
-     accumulators with its scalar hat weight; a final x-side hat contraction
+     ``max(0, 1-|x-(cx+ox)|)``. Each of the ``2r+1`` y-offsets keeps an
+     accumulator of the target rows' correlation slices weighted by the
+     y-side hat, summed in order of ``y``; a final x-side hat contraction
      emits the window. Pure multiply-accumulate on the VPU — no gather, no
      scatter. Rows/columns outside the image simply never contribute, which
      reproduces ``grid_sample(padding_mode='zeros')`` exactly (the
      semantics of ``raft_tpu.ops.sampling.bilinear_sampler``).
+
+  3. **Diagonal y-sweep** (forward): row ``y`` meets y-offset ``off`` with
+     weight ``hat(y - off - cy_n)``, which is zero for every query of the
+     tile unless the diagonal ``d = y - off`` lies in ``[floor(min cy),
+     ceil(max cy)]``. The kernel parks the band's chunk products in a VMEM
+     scratch and folds diagonal by diagonal, ``_DIAG_BLOCK`` at a time, so
+     that an accumulator is read and written once a block: of the ``(rows
+     of the band) x (2r+1)`` pairs a dense sweep folds it visits ``(live
+     diagonals) x (2r+1)`` (``sweep_stats`` counts both). The pairs left
+     out have weight exactly 0 and the rest keep their order, so the
+     result is bit-identical to the dense sweep's. A tile whose band is
+     taller than the scratch or whose diagonals would fold no fewer pairs
+     (flow spread over much of the image), a level too short to hold one
+     block, and ``band="off"`` keep the dense sweep: every row of a chunk
+     into every y-offset.
 
   Everything is strictly 2-D inside the kernel (Mosaic's vector layout
   requirement) and laid out **query-minor**: the query-tile axis is the lane
@@ -76,14 +91,21 @@ materialized path at KITTI eval, 12.1 vs 18.1 pairs/s):
   never written, ``correlation_kernel.cu:307``) and the per-iteration
   ``coords1.detach()`` upstream (reference ``core/raft.py:124``).
 
-VMEM envelope: the pooled target levels (Σ_l ``H2l*W2lp x C``) plus
-per-tile scratch must co-reside in ~16 MB of VMEM; the banded backward no
-longer needs its former ``(H2*W2p x TQ)`` cotangent scratch. At stride-8
-feature resolution this holds for full Sintel and KITTI eval forward
-passes and for all reference training crop sizes. Residency is set by the
-*input* dtype: bfloat16 feature maps (the mixed-precision policy) halve
-the envelope; ``mxu_dtype`` alone only changes the per-chunk cast, not
-what is staged.
+VMEM envelope: a launch lives under Mosaic's scoped limit, 16 MiB by
+default (``vmem.LIMIT_BYTES``), and is admitted when its named buffers
+(``corr_vmem_parts``: the pooled target levels, Σ_l ``H2lp*W2lp x C``,
+plus per-tile scratch) come to 13 MiB (``vmem.BUDGET_BYTES``) or less;
+the rest is what Mosaic takes beyond the estimate (it double-buffers the
+resident levels: 13.9 MB at Sintel over bfloat16 features, compiled for a
+v5e at batch 128). The forward's band scratch rides on top: the launch
+asks for the default limit plus the scratch and ``_BAND_HEADROOM``, and
+is admitted against the budget plus the scratch, so what fitted without
+it fits with it. The banded backward no longer needs its former ``(H2*W2p
+x TQ)`` cotangent scratch. At stride-8 feature resolution this holds for
+full Sintel and KITTI eval forward passes and for all reference training
+crop sizes. Residency is set by the *input* dtype: bfloat16 feature maps
+(the mixed-precision policy) halve the envelope; ``mxu_dtype`` alone only
+changes the per-chunk cast, not what is staged.
 
 Numerics: accumulation in float32 regardless of input or MXU dtype; parity
 with the jnp reference ``raft_tpu.models.corr.windowed_correlation`` is
@@ -171,6 +193,24 @@ def _band_chunks(cy, radius, h2l, nchunks):
     return c_lo, c_hi
 
 
+def _guarded(pred, body):
+    @pl.when(pred)
+    def _():
+        body()
+
+
+def _span_loop(band: str, lo, hi, most: int, body):
+    """Run ``body(i)`` (effects-only: VMEM-ref stores, no carry) for ``i``
+    in the traced range ``[lo, hi)`` of ``[0, most)``: a traced-bound
+    ``fori_loop`` (``"dynamic"``), or a static trip count of ``most``
+    with a ``pl.when`` on each step (``"static"``)."""
+    if band == "dynamic":
+        jax.lax.fori_loop(lo, hi, lambda i, c: (body(i), c)[1], 0)
+        return
+    jax.lax.fori_loop(0, most, lambda i, c: (_guarded(
+        jnp.logical_and(i >= lo, i < hi), lambda: body(i)), c)[1], 0)
+
+
 def _chunk_loop(band: str, cy, radius, h2l, nchunks, body):
     """Run ``body(yc)`` (effects-only: VMEM-ref stores, no carry) over the
     row chunks a query tile can touch, under one of three band modes:
@@ -190,30 +230,43 @@ def _chunk_loop(band: str, cy, radius, h2l, nchunks, body):
         jax.lax.fori_loop(0, nchunks, lambda yc, c: (body(yc), c)[1], 0)
         return
     c_lo, c_hi = _band_chunks(cy, radius, h2l, nchunks)
-    if band == "dynamic":
-        jax.lax.fori_loop(c_lo, c_hi, lambda yc, c: (body(yc), c)[1], 0)
-        return
+    _span_loop(band, c_lo, c_hi, nchunks, body)
 
-    def guarded(yc, c):
-        @pl.when(jnp.logical_and(yc >= c_lo, yc < c_hi))
-        def _():
-            body(yc)
-        return c
 
-    jax.lax.fori_loop(0, nchunks, guarded, 0)
+# Diagonals folded per step of the diagonal sweep: each y-offset
+# accumulator is read and written once a step, so a step's partial sums
+# stay in registers across its diagonals; a step past the last live
+# diagonal folds dead ones. Of 1, 2, 3, 4 and 8, 2 was fastest at both
+# Sintel launches on the v5e (PERF.md section 6, PR 30).
+_DIAG_BLOCK = 2
+
+
+def _live_diagonals(cy, radius, h2l):
+    """Integer range ``[d_lo, d_hi]`` of the diagonals ``d = y - offset``
+    that can carry a nonzero y-side weight for ANY query of the tile:
+    ``hat(d - cy_n) > 0`` iff ``|d - cy_n| < 1``, so only
+    ``floor(min cy) <= d <= ceil(max cy)`` (exact under the float32
+    rounding of ``cy + offset``: rounding is monotone and the bounds are
+    integers), cut to the diagonals that cross the level's rows at all."""
+    lo = jnp.clip(jnp.floor(jnp.min(cy)), -radius, h2l + radius)
+    hi = jnp.clip(jnp.ceil(jnp.max(cy)), -radius - 1, h2l - 1 + radius)
+    return lo.astype(jnp.int32), hi.astype(jnp.int32)
 
 
 def _fwd_kernel(cx_ref, cy_ref, f1_ref, *refs, radius: int, scale: bool,
                 levels: tuple, mxu_dtype: str, band: str,
-                rescale: bool, tout: bool = False):
-    """refs = (f2_l0..f2_lN, out, t1_scratch); levels = ((h2l, h2lp, w2pl),…)
-    with h2lp the CHUNK-padded row count (padded rows are zero features →
-    zero contribution). ``tout``: store the output block transposed —
-    (TQ, L*win*win) instead of (L*win*win, TQ) — so the wrapper's
-    swapaxes disappears (the b64 profile measured the XLA transpose
-    copy at ~12 ms/step); one in-VMEM transpose per tile instead."""
+                rescale: bool, tout: bool = False, band_rows: int = 0):
+    """refs = (f2_l0..f2_lN, out, t1_scratch[, band_scratch]); levels =
+    ((h2l, h2lp, w2pl),…) with h2lp the CHUNK-padded row count (padded
+    rows are zero features → zero contribution). ``band_rows``: rows of
+    a level the band scratch holds (0: no scratch, dense sweep only).
+    ``tout``: store the output block transposed — (TQ, L*win*win) instead
+    of (L*win*win, TQ) — so the wrapper's swapaxes disappears (the b64
+    profile measured the XLA transpose copy at ~12 ms/step); one in-VMEM
+    transpose per tile instead."""
     nl = len(levels)
     f2_refs, out_ref, t1_ref = refs[:nl], refs[nl], refs[nl + 1]
+    band_ref = refs[nl + 2] if band_rows else None
     win = 2 * radius + 1
     mdt = _mxu(mxu_dtype)
     f1 = f1_ref[0].astype(mdt)                           # (TQ, C)
@@ -233,14 +286,20 @@ def _fwd_kernel(cx_ref, cy_ref, f1_ref, *refs, radius: int, scale: bool,
         nchunks = h2lp // _CHUNK
         t1_ref[0:win * w2pl, :] = jnp.zeros((win * w2pl, tq), jnp.float32)
 
-        def body(yc, l=l, w2pl=w2pl, cy=cy):
-            # The query tile's slice of the all-pairs volume for this row
-            # chunk: one MXU matmul, consumed immediately.
+        # The closures below are traced where they are defined, inside
+        # this level's pass of the loop: they read its variables as is.
+        def chunk_corr(yc):
+            # The query tile's slice of the all-pairs volume for one row
+            # chunk: one MXU matmul, consumed by the sweep that follows.
             f2c = f2_refs[l][0, pl.ds(yc * (_CHUNK * w2pl), _CHUNK * w2pl), :]
-            corr = jax.lax.dot_general(
+            return jax.lax.dot_general(
                 f2c.astype(mdt), f1, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=_dot_precision(mdt))              # (CHUNK*W2PL, TQ)
+
+        def dense_body(yc):
+            # Dense sweep: every row of the chunk into all y-offsets.
+            corr = chunk_corr(yc)
             y0f = (yc * _CHUNK).astype(jnp.float32)
             for r_i in range(_CHUNK):
                 row = corr[r_i * w2pl:(r_i + 1) * w2pl, :]
@@ -248,7 +307,66 @@ def _fwd_kernel(cx_ref, cy_ref, f1_ref, *refs, radius: int, scale: bool,
                     wy = _hat(y0f + r_i - (cy + (i - radius)))  # (1, TQ)
                     t1_ref[i * w2pl:(i + 1) * w2pl, :] += wy * row
 
-        _chunk_loop(band, cy, radius, h2l, nchunks, body)
+        def dense_sweep():
+            _chunk_loop(band, cy, radius, h2l, nchunks, dense_body)
+
+        # Chunks of this level the band scratch can hold at once.
+        held = min(band_rows, h2lp) // _CHUNK
+        if band == "off" or held * _CHUNK < win + _DIAG_BLOCK:
+            dense_sweep()
+        else:
+            c_lo, c_hi = _band_chunks(cy, radius, h2l, nchunks)
+            d_lo, d_hi = _live_diagonals(cy, radius, h2l)
+            nblk = (d_hi - d_lo + _DIAG_BLOCK) // _DIAG_BLOCK
+
+            def diagonal_sweep():
+                # Park the band's chunk products, then fold diagonal by
+                # diagonal: on diagonal d, y-offset ``off`` meets the one
+                # row y = d + off. Every (row, offset) pair left out has
+                # weight exactly 0 for the whole tile and the rest keep
+                # their order of y, so each accumulator sees the same
+                # nonzero terms in the same order as the dense sweep:
+                # bit-identical. A row outside the image, or a diagonal
+                # past d_hi, is folded with weight 0 from a row that was
+                # computed (never from stale scratch).
+                for k in range(held):
+                    def park(k=k):
+                        band_ref[k * _CHUNK * w2pl:(k + 1) * _CHUNK * w2pl,
+                                 :] = chunk_corr(c_lo + k)
+                    _guarded(c_lo + k < c_hi, park)
+                y_lo, y_hi = c_lo * _CHUNK, c_hi * _CHUNK - 1
+
+                def block(jb):
+                    d0 = d_lo + jb * _DIAG_BLOCK
+                    for i in range(win):                 # y-offset index
+                        off = i - radius
+                        acc = t1_ref[i * w2pl:(i + 1) * w2pl, :]
+                        for jj in range(_DIAG_BLOCK):
+                            y = d0 + jj + off
+                            live = jnp.logical_and(
+                                d0 + jj <= d_hi,
+                                jnp.logical_and(y >= 0, y < h2l))
+                            wy = _hat(y.astype(jnp.float32) - (cy + off))
+                            wy = wy * live.astype(jnp.float32)  # (1, TQ)
+                            at = (jnp.clip(y, y_lo, y_hi) - y_lo) * w2pl
+                            acc = acc + wy * band_ref[
+                                pl.ds(pl.multiple_of(at, 8), w2pl), :]
+                        t1_ref[i * w2pl:(i + 1) * w2pl, :] = acc
+
+                _span_loop(band, 0, nblk, held * _CHUNK // _DIAG_BLOCK,
+                           block)
+
+            # The dense sweep stays for a band of more chunks than the
+            # scratch holds, and wherever it folds no more pairs than the
+            # diagonals would (flow spread over much of the image;
+            # diagonals that mostly miss a short level): the worst case
+            # is the dense sweep's. An empty band is the dense sweep's
+            # too: its chunk loop runs no chunk.
+            diagonal = jnp.logical_and(
+                c_hi - c_lo <= held,
+                nblk * _DIAG_BLOCK < (c_hi - c_lo) * _CHUNK)
+            _guarded(diagonal, diagonal_sweep)
+            _guarded(jnp.logical_not(diagonal), dense_sweep)
 
         # x-side hat contraction → window rows in the reference order
         # (core/corr.py delta grid: first window axis moves x).
@@ -383,9 +501,14 @@ def _pallas_fwd(f1, f2s, cx, cy, radius, scale, interpret, levels, tq,
     grid = (b, np_ // tq)
     w2p_max = max(w2pl for (_, _, w2pl) in levels)
 
+    band_rows = _band_scratch_rows(levels, radius)
     kernel = functools.partial(_fwd_kernel, radius=radius, scale=scale,
                                levels=levels, mxu_dtype=mxu_dtype,
-                               band=band, rescale=rescale, tout=tout)
+                               band=band, rescale=rescale, tout=tout,
+                               band_rows=band_rows)
+    scratch = [pltpu.VMEM((win * w2p_max, tq), jnp.float32)]
+    if band_rows:
+        scratch.append(pltpu.VMEM((band_rows * w2p_max, tq), jnp.float32))
     # Layout-contract invariant 3: output tiled over the query axis; the
     # consumer-major order pairs with the kernel's transposed store.
     out_specs, out_shape = klayout.query_tiled_out(
@@ -403,7 +526,10 @@ def _pallas_fwd(f1, f2s, cx, cy, radius, scale, interpret, levels, tq,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((win * w2p_max, tq), jnp.float32)],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem.LIMIT_BYTES + _BAND_HEADROOM
+            + _band_scratch_bytes(levels, radius, tq)),
         interpret=interpret,
         name=klayout.KERNEL_NAMES["corr_fwd"],
     )(cx, cy, f1, *f2s)
@@ -489,6 +615,63 @@ def _windowed_bwd(radius, scale, interpret, levels, tq, mxu_dtype, band,
 _windowed.defvjp(_windowed_fwd, _windowed_bwd)
 
 
+def sweep_stats(coords, pyramid_shapes, radius: int, tq: int | None = None
+                ) -> dict:
+    """How much of the y-sweep the diagonal rule leaves, for a batch of
+    lookup coordinates: the (target row, y-offset) pairs the forward
+    kernel folds (``diagonal``: whole blocks of diagonals where it takes
+    the diagonal sweep, the dense count where it keeps the dense one)
+    beside the pairs a dense sweep of the same chunk-aligned band folds,
+    all ``2r+1`` offsets of every row (``dense``); ``live`` is how many
+    of either can carry a nonzero weight (a row of the image on a live
+    diagonal). Per level and in total over all query tiles. Host-side:
+    the kernel's own ``_band_chunks`` and ``_live_diagonals`` mapped over
+    the tiles and its choice between the sweeps in numpy, no kernel
+    launch; canonical ``rescale=True`` levels, any band mode but "off".
+
+    ``coords``: ``(B, H, W, 2)`` pixel coords (x, y) at level-0 scale;
+    ``pyramid_shapes``: per-level ``(h2l, w2l)``; ``tq``: the query tile
+    (default: the wrapper's choice for ``H * W``)."""
+    import numpy as np
+
+    cy = np.asarray(coords, np.float32)[..., 1]
+    b = cy.shape[0]
+    n = cy[0].size
+    tq = tq or _choose_tile(n)
+    cy = np.pad(cy.reshape(b, n), ((0, 0), (0, _round_up(n, tq) - n)),
+                mode="edge").reshape(-1, tq)             # (tiles, TQ)
+    win = 2 * radius + 1
+    offs = np.arange(win) - radius                       # (win,)
+    geometry = _level_geometry(pyramid_shapes)
+    band_rows = _band_scratch_rows(geometry, radius)
+    levels = []
+    for l, (h2l, h2lp, _) in enumerate(geometry):
+        cyl = cy * np.float32(1.0 / 2 ** l)
+        c_lo, c_hi = (np.asarray(v, np.int64) for v in jax.vmap(
+            lambda t: _band_chunks(t, radius, h2l, h2lp // _CHUNK))(cyl))
+        d_lo, d_hi = (np.asarray(v, np.int64) for v in jax.vmap(
+            lambda t: _live_diagonals(t, radius, h2l))(cyl))
+        chunks = np.maximum(c_hi - c_lo, 0)
+        dense = chunks * _CHUNK * win
+        nblk = (d_hi - d_lo + _DIAG_BLOCK) // _DIAG_BLOCK
+        held = min(band_rows, h2lp) // _CHUNK
+        diagonal = ((held * _CHUNK >= win + _DIAG_BLOCK) & (chunks <= held)
+                    & (nblk * _DIAG_BLOCK < chunks * _CHUNK))
+        # rows of the image that offset ``off`` meets on a live diagonal
+        y_lo = np.maximum(d_lo[:, None] + offs, 0)
+        y_hi = np.minimum(d_hi[:, None] + offs + 1, h2l)
+        live = np.where(chunks > 0, np.maximum(y_hi - y_lo, 0).sum(axis=1), 0)
+        levels.append({
+            "diagonal": int(np.where(diagonal, nblk * _DIAG_BLOCK * win,
+                                     dense).sum()),
+            "dense": int(dense.sum()), "live": int(live.sum()),
+            "tiles_diagonal": int(diagonal.sum())})
+    out = {key: sum(v[key] for v in levels)
+           for key in ("diagonal", "dense", "live")}
+    out.update(levels=levels, tiles=int(cy.shape[0]), tq=int(tq))
+    return out
+
+
 def _resolve_band(band) -> str:
     """Normalize the band argument to one of ``{"dynamic","static","off"}``.
     ``None`` reads ``RAFT_CORR_BAND`` (unset/"1" → dynamic, "static" →
@@ -507,38 +690,76 @@ def _resolve_band(band) -> str:
     return band
 
 
+#: Rows of chunk products (of the widest level) the forward kernel parks
+#: for the diagonal sweep: room for a band of 32 - 2 (r + 1) - 7 live
+#: diagonals wherever its first row falls in a chunk.
+_BAND_ROWS = 32
+
+
+#: What the diagonal sweep's own temporaries were seen to take beyond the
+#: scratch (0.9 MB at Sintel, compiled for a v5e at batch 32 and 128).
+_BAND_HEADROOM = 2 * 2 ** 20
+
+
+def _band_scratch_rows(levels, radius: int) -> int:
+    """Rows of the forward kernel's band scratch: ``_BAND_ROWS``, no more
+    than the tallest level has, and 0 (dense sweep only) where not even
+    one block of diagonals with its ``2r + 1`` rows would fit."""
+    rows = min(_BAND_ROWS, max((h2lp for (_, h2lp, _) in levels), default=0))
+    return rows if rows >= 2 * radius + 1 + _DIAG_BLOCK else 0
+
+
+def _band_scratch_bytes(levels, radius: int, tq: int) -> int:
+    """The band scratch rides on top of Mosaic's default scoped limit:
+    the forward launch asks for this much more (``_pallas_fwd``) and is
+    admitted against this much more (``fused_eligible``), so a shape
+    that fitted without the scratch fits with it."""
+    w2p_max = max([8] + [w2pl for (_, _, w2pl) in levels])
+    return _band_scratch_rows(levels, radius) * w2p_max * tq * 4
+
+
 def corr_vmem_parts(pyramid_shapes, channels: int,
                     dtype_bytes: int = 4, radius: int = 4,
                     differentiable: bool = False,
                     tq: int = 256) -> dict:
-    """Named scoped-VMEM buffer estimate for the fused corr kernel —
-    the shared currency of ``raft_tpu.ops.vmem`` (``fits`` for the
-    eligibility gate, ``preflight`` for the loud pre-launch check).
+    """Named scoped-VMEM buffer estimate of one launch of the fused corr
+    kernel — the forward, or with ``differentiable`` the backward, which
+    holds more — in the shared currency of ``raft_tpu.ops.vmem``
+    (``fits`` for the eligibility gate, ``preflight`` for the loud
+    pre-launch check).
 
     ``tq`` defaults to the worst admissible query tile (256) so the
     eligibility gate stays tile-independent; the pre-launch preflight
     passes the actual tile."""
+    levels = _level_geometry(pyramid_shapes)
     win = 2 * radius + 1
-    resident = 0
-    df2 = 0
-    w2p_max = 8
-    for (h2, w2) in pyramid_shapes:
-        w2p = _round_up(w2, 8)
-        w2p_max = max(w2p_max, w2p)
-        level = _round_up(h2, _CHUNK) * w2p * channels
-        resident += level * dtype_bytes
-        if differentiable:
-            df2 += level * 4                     # f32 df2 output block
-    parts = {"pyramid_resident": resident}
-    # t1/u accumulator scratch at the actual window size, f32 — doubled
-    # for margin (chunk matmul operands, out block)
-    parts["tile_scratch"] = 2 * win * w2p_max * tq * 4
+    w2p_max = max([8] + [w2pl for (_, _, w2pl) in levels])
+    parts = {
+        "pyramid_resident": sum(h2lp * w2pl * channels * dtype_bytes
+                                for (_, h2lp, w2pl) in levels),
+        # t1/u accumulator scratch at the actual window size, f32 —
+        # doubled for margin (chunk matmul operands, out block)
+        "tile_scratch": 2 * win * w2p_max * tq * 4,
+    }
     if differentiable:
-        parts["df2_blocks_f32"] = df2
+        # f32 df2 output blocks, one a level
+        parts["df2_blocks_f32"] = sum(h2lp * w2pl * channels * 4
+                                      for (_, h2lp, w2pl) in levels)
         # g block (L*win^2, TQ) + df1 scratch/out (TQ, C), all f32
-        parts["bwd_g_df1"] = (len(pyramid_shapes) * win * win * tq
+        parts["bwd_g_df1"] = (len(levels) * win * win * tq
                               + 2 * tq * channels) * 4
+        return parts
+    band = _band_scratch_bytes(levels, radius, tq)
+    if band:
+        # the band's chunk products, parked for the diagonal sweep
+        parts["band_corr_f32"] = band
     return parts
+
+
+def _admission_budget(parts) -> int:
+    """The default budget, plus the band scratch the launch asks for on
+    top of the default limit."""
+    return vmem.BUDGET_BYTES + parts.get("band_corr_f32", 0)
 
 
 def fused_eligible(pyramid_shapes, channels: int,
@@ -562,9 +783,9 @@ def fused_eligible(pyramid_shapes, channels: int,
             # short-circuits it to zero windows; the kernel's BlockSpecs
             # can't express a zero-size input block.
             return False
-    return vmem.fits(corr_vmem_parts(pyramid_shapes, channels,
-                                     dtype_bytes, radius,
-                                     differentiable))
+    parts = corr_vmem_parts(pyramid_shapes, channels, dtype_bytes, radius,
+                            differentiable)
+    return vmem.fits(parts, _admission_budget(parts))
 
 
 def windowed_correlation_pallas_fused(
@@ -642,11 +863,11 @@ def windowed_correlation_pallas_fused(
     # Forward-pass estimate — the launch being admitted here; interpret
     # mode has no VMEM to budget.
     if not interpret:
-        vmem.preflight(
-            corr_vmem_parts([f2.shape[1:3] for f2 in pyramid2], c,
-                            jnp.dtype(fmap1.dtype).itemsize, radius,
-                            tq=tq),
-            f"corr fused kernel (tq={tq})")
+        parts = corr_vmem_parts([f2.shape[1:3] for f2 in pyramid2], c,
+                                jnp.dtype(fmap1.dtype).itemsize, radius,
+                                tq=tq)
+        vmem.preflight(parts, f"corr fused kernel (tq={tq})",
+                       _admission_budget(parts))
 
     # Transposed output store (default ON): the kernel emits each output
     # tile query-major — (TQ, L*win*win) — deleting the XLA swapaxes

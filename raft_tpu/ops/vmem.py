@@ -43,7 +43,8 @@ from typing import Mapping
 _LOG = logging.getLogger(__name__)
 
 #: Mosaic's default scoped-VMEM limit: what a ``pallas_call`` that passes
-#: no compiler params is held to. The corr and MSDA kernels live under it.
+#: no compiler params is held to. The corr and MSDA kernels live under it
+#: (the corr forward asks for its band scratch on top of it).
 LIMIT_BYTES = 16 * 2 ** 20
 
 #: Conservative admission budget under the default limit: leaves ~3 MiB

@@ -76,16 +76,21 @@ def _flow_head_mats():
                                       (_z(3, 3, 256, 2), _z(2)))
 
 
-def _corr(dtype, grad, hw, batch):
+def _corr(dtype, grad, hw, batch, fnet=FNET, radius=4, features=jnp.float32):
     """The fused banded lookup (dynamic band, the default), forward or
-    forward+backward, over a 4-level pooled pyramid."""
+    forward+backward, over a 4-level pooled pyramid. With bfloat16
+    ``features`` (what mixed-precision inference hands it: the two pass
+    cells' launches, RAFT-large's and RAFT-small's at ``fnet`` 128,
+    radius 3) the forward holds the diagonal sweep's band scratch; over
+    float32 features at Sintel there is no room for it and the launch
+    keeps the dense sweep."""
     h, w = hw
-    pyramid = tuple((batch, max(h >> l, 1), max(w >> l, 1), FNET)
+    pyramid = tuple((batch, max(h >> l, 1), max(w >> l, 1), fnet)
                     for l in range(4))
 
     def fwd(f1, coords, *pyr):
         return corr_pallas.windowed_correlation_pallas_fused(
-            f1, pyr, coords, 4, interpret=False, band="dynamic",
+            f1, pyr, coords, radius, interpret=False, band="dynamic",
             mxu_dtype=jnp.dtype(dtype).name,
             out_dtype=dtype)
 
@@ -93,9 +98,9 @@ def _corr(dtype, grad, hw, batch):
         return jnp.sum(fwd(f1, coords, *pyr).astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 2)) if grad else fwd
-    shapes = [((batch, h, w, FNET), jnp.float32),
+    shapes = [((batch, h, w, fnet), features),
               ((batch, h, w, 2), jnp.float32)]
-    shapes += [(p, jnp.float32) for p in pyramid]
+    shapes += [(p, features) for p in pyramid]
     return fn, shapes, None
 
 
@@ -208,6 +213,11 @@ BF16, F32 = jnp.bfloat16, jnp.float32
 CASES = {
     "corr_fwd_bf16_sintel": (lambda: _corr(BF16, False, SINTEL, 2),
                              "corr_fwd"),
+    "corr_fwd_bf16_features_sintel": (
+        lambda: _corr(BF16, False, SINTEL, 32, features=BF16), "corr_fwd"),
+    "corr_fwd_bf16_features_sintel_small": (
+        lambda: _corr(BF16, False, SINTEL, 32, fnet=128, radius=3,
+                      features=BF16), "corr_fwd"),
     "corr_fwd_f32_sintel": (lambda: _corr(F32, False, SINTEL, 2),
                             "corr_fwd"),
     "corr_bwd_f32_chairs_b8": (lambda: _corr(F32, True, CHAIRS, 8),
