@@ -200,18 +200,6 @@ def sparse_corr_from_env() -> bool:
     return _os.environ.get("RAFT_SPARSE_CORR", "ondemand") != "materialized"
 
 
-# Trainable/evaluable model families: the two live ones plus the rebuilt
-# experiment snapshots (reference core/ours_02/03/04/06.py lineages —
-# raft_tpu/models/variants.py). Single source for every CLI's choices.
-MODEL_FAMILIES = ("raft", "sparse", "keypoint_transformer", "dual_query",
-                  "two_stage", "full_transformer", "lfm2_moe")
-
-#: Families whose batches are packed token sequences, not image pairs:
-#: no panels, no validation sets, no BatchNorm to freeze, no
-#: ``image_size``; ``evaluate.py`` and ``demo.py`` offer the others.
-TOKEN_FAMILIES = ("lfm2_moe",)
-FLOW_FAMILIES = tuple(f for f in MODEL_FAMILIES if f not in TOKEN_FAMILIES)
-
 #: The published layer pattern of LFM2-24B-A2B: attention at every
 #: fourth layer from layer 2 on, gated short convolutions elsewhere.
 _LFM2_LAYER_TYPES = tuple(
@@ -294,8 +282,9 @@ class TrainConfig:
 
     name: str = "raft"
     stage: str = "chairs"
-    # "raft" (canonical) or "sparse" (the fork's active "ours" trainer,
-    # reference train.py:19 → core/ours.py)
+    # a row of raft_tpu/families.py: "raft" (canonical), "sparse" (the
+    # fork's active "ours" trainer, reference train.py:19 → core/ours.py)
+    # or "lfm2_moe" (packed token sequences)
     model_family: str = "raft"
     lr: float = 4e-4
     num_steps: int = 100000

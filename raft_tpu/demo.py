@@ -16,9 +16,9 @@ from glob import glob
 import numpy as np
 from PIL import Image
 
-from raft_tpu.config import FLOW_FAMILIES
 from raft_tpu.evaluate import (ASSETS_DIR, load_predictor,
                                reject_raft_only_flags)
+from raft_tpu.families import FLOW_FAMILIES
 from raft_tpu.utils.flow_viz import flow_to_image
 from raft_tpu.utils.padder import InputPadder
 

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu.data import datasets, frame_utils
+from raft_tpu.families import FLOW_FAMILIES, family_of
+from raft_tpu.utils.compile_count import xla_compile_count
 from raft_tpu.utils.padder import InputPadder
 from raft_tpu.utils.profiling import host_timer
 from raft_tpu.utils.staging import StagingArena
@@ -849,8 +851,6 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
     and ``arena_fresh``: how many of the batch's two buffers had to be
     allocated (2 on a shape's first batch, 0 after).
     """
-    from raft_tpu.serving.metrics import xla_compile_count
-
     timer = host_timer()
     bs = getattr(predictor, "batch_size", 1)
     batched = hasattr(predictor, "predict_batch") and bs > 1
@@ -1249,8 +1249,12 @@ def load_predictor(model_path: str, small: bool = False,
     those levers are honored as passed."""
     from raft_tpu import checkpoint as ckpt_lib
     from raft_tpu.config import RAFTConfig
-    from raft_tpu.models.raft import RAFT
 
+    family = family_of(model_family)
+    if family.tokens:
+        raise ValueError(
+            f"the {model_family} family trains only; a predictor needs "
+            f"one of {FLOW_FAMILIES}")
     if corr_impl is None:
         # Mirror resolve_train_corr_engine: an explicit engine/storage
         # selection (--alternate_corr, --corr_dtype) pins "fixed" (use
@@ -1263,8 +1267,8 @@ def load_predictor(model_path: str, small: bool = False,
             # the banded kernel composes with row sharding via shard_map
             # (falls back to the materialized engine per shape when rows
             # don't divide or VMEM doesn't admit the kernel)
-            corr_impl = "auto" if model_family == "raft" else "fixed"
-    if model_family != "raft":
+            corr_impl = "auto" if family.raft_options else "fixed"
+    if not family.raft_options:
         dropped = [name for name, on in _raft_only_selections(
             small, alternate_corr, corr_dtype) if on]
         if dropped:
@@ -1274,19 +1278,16 @@ def load_predictor(model_path: str, small: bool = False,
                 f"RAFT family only; the {model_family} family is built "
                 "from its own config and would silently ignore "
                 f"{'it' if len(dropped) == 1 else 'them'}")
-        if model_path.endswith((".pth", ".pt", ".npz")):
-            raise ValueError(
-                "torch-checkpoint conversion covers the canonical RAFT "
-                f"family only (no published {model_family} weights "
-                "exist); load this family from an orbax run directory")
-        from raft_tpu.train import build_model
-        model = build_model(model_family,
-                            RAFTConfig(mixed_precision=mixed_precision))
-    else:
-        cfg = RAFTConfig(small=small, alternate_corr=alternate_corr,
-                         mixed_precision=mixed_precision,
-                         corr_dtype=corr_dtype or "auto")
-        model = RAFT(cfg)
+    if not family.torch_weights and model_path.endswith(
+            (".pth", ".pt", ".npz")):
+        raise ValueError(
+            "torch-checkpoint conversion covers the canonical RAFT "
+            f"family only (no published {model_family} weights "
+            "exist); load this family from an orbax run directory")
+    # the selections refused above stand at RAFTConfig's defaults
+    model = family.build(RAFTConfig(
+        small=small, alternate_corr=alternate_corr,
+        mixed_precision=mixed_precision, corr_dtype=corr_dtype or "auto"))
 
     mesh = None
     if spatial_shards > 1:
@@ -1346,7 +1347,7 @@ def reject_raft_only_flags(parser, args) -> None:
     not be silently dropped when another family builds from its own
     config.  ``--iters`` (``default=None`` in every CLI) is included —
     every non-raft family fixes its iteration count architecturally."""
-    if args.model_family == "raft":
+    if family_of(args.model_family).raft_options:
         return
     for name, on in _raft_only_selections(args.small, args.alternate_corr,
                                           args.corr_dtype):
@@ -1373,7 +1374,6 @@ def main(argv=None):
                         choices=list(_VALIDATORS) + ["sintel_submission",
                                                      "kitti_submission"])
     parser.add_argument("--small", action="store_true")
-    from raft_tpu.config import FLOW_FAMILIES
     parser.add_argument("--model_family", default="raft",
                         choices=list(FLOW_FAMILIES))
     parser.add_argument("--iters", type=int, default=None)
@@ -1423,7 +1423,7 @@ def main(argv=None):
     if args.dataset == "golden" and args.small:
         parser.error("--dataset golden compares against RAFT-large "
                      "goldens; use --dataset golden_small for --small")
-    if args.model_family != "raft" and args.warm_start:
+    if args.warm_start and not family_of(args.model_family).flow_init:
         parser.error("--warm_start requires the canonical RAFT family "
                      f"(the {args.model_family} family does not support "
                      "flow_init)")

@@ -102,30 +102,6 @@ def sequence_loss(flow_preds: jnp.ndarray, flow_gt: jnp.ndarray,
     return loss, metrics
 
 
-def sequence_corr_loss(flow_preds: jnp.ndarray, corr_preds: jnp.ndarray,
-                       flow_gt: jnp.ndarray, valid: jnp.ndarray,
-                       max_flow: float = MAX_FLOW,
-                       ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """The ``train_02.py`` two-list loss (``train_02.py:54-81``): the model
-    returns ``(flow_predictions, corr_predictions)`` (the dual-decoder
-    snapshot, :class:`raft_tpu.models.variants.DualQueryRAFT`) and both
-    lists take a uniformly-weighted (``i_weight = 1.0``) masked L1 against
-    the same ground truth; total = flow_loss + corr_loss.
-
-    Shapes as :func:`sequence_loss`; metrics come from the final *flow*
-    prediction plus the two loss components.
-    """
-    flow_loss, metrics = sequence_loss(flow_preds, flow_gt, valid,
-                                       gamma=1.0, max_flow=max_flow,
-                                       normalization="all")
-    corr_loss, _ = sequence_loss(corr_preds, flow_gt, valid, gamma=1.0,
-                                 max_flow=max_flow, normalization="all")
-    loss = flow_loss + corr_loss
-    metrics = dict(metrics)
-    metrics.update(loss=loss, flow_loss=flow_loss, corr_loss=corr_loss)
-    return loss, metrics
-
-
 def sparse_keypoint_loss(sparse_preds, flow_gt: jnp.ndarray,
                          valid: jnp.ndarray,
                          max_flow: float = MAX_FLOW) -> jnp.ndarray:

@@ -10,6 +10,3 @@ from raft_tpu.models.deformable import (  # noqa: F401
 from raft_tpu.models.relative import (  # noqa: F401
     MultiHeadAttentionLayer, RelativePosition,
     RelativeTransformerDecoderLayer)
-from raft_tpu.models.variants import (  # noqa: F401
-    DualQueryRAFT, FullTransformerRAFT, KeypointTransformerRAFT,
-    StageEncoder, TwoStageKeypointRAFT)

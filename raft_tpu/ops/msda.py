@@ -13,8 +13,9 @@ orders of magnitude smaller than the token grid. The op is
 bandwidth-trivial; what matters is that the gathers vectorize and fuse under
 XLA, so the core is expressed as one batched ``bilinear_sampler`` call per
 level (static level loop) and a single weighted reduction. Dense-query
-*encoder* layers (``ours_07`` lineage / ``full_transformer``: every HW
-token is a query) are a different regime — per-scalar gathers cost a full
+*encoder* layers (the ``ours_07`` encoder stacks,
+``OursConfig.encoder_iterations``: every HW token is a query) are a
+different regime — per-scalar gathers cost a full
 HBM tile each there, so ``backend='auto'`` dispatches them to the
 hat-matmul Pallas kernel (:mod:`raft_tpu.ops.msda_pallas`) on TPU.
 

@@ -9,8 +9,8 @@ with a predicted attention weight — *without per-sample gathers*.
 Why a kernel at all: the vectorized jnp core (`raft_tpu.ops.msda`) is the
 right tool for the live sparse model's 100-keypoint decoder (the gathers
 are bandwidth-trivial there), but the dense-query *encoder* regime
-(``ours_07`` lineage / ``full_transformer`` family: every HW token is a
-query) pays a full (8, 128) HBM tile per scalar gather — measured at
+(the ``ours_07`` encoder stacks, ``OursConfig.encoder_iterations``:
+every HW token is a query) pays a full (8, 128) HBM tile per scalar gather — measured at
 21.8 ms for ONE encoder layer at 10.5k tokens on v5e (TPU_EXTRAS.json
 ``msda_dense``), slower than an entire 12-iteration RAFT forward.
 

@@ -18,6 +18,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from raft_tpu.families import family_of
+
 DATA_AXIS = "data"
 SPATIAL_AXIS = "spatial"
 
@@ -55,7 +57,7 @@ def validate_spatial_shards(spatial_shards: int, model_family: str,
             f"spatial_shards must be >= 1 (got {spatial_shards})")
     if spatial_shards == 1:
         return
-    if model_family != "raft":
+    if not family_of(model_family).spatial_shards:
         raise ValueError(
             "spatial sharding supports the canonical RAFT family only "
             f"(got model_family={model_family!r})")

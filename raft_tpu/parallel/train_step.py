@@ -11,7 +11,7 @@ all-reduce (the TPU equivalent of ``nn.DataParallel``'s gather +
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +19,8 @@ import optax
 from flax import core, struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from raft_tpu.config import RAFTConfig, TrainConfig
-from raft_tpu.losses import sequence_loss
+from raft_tpu.config import TrainConfig
+from raft_tpu.families import family_of
 from raft_tpu.resilience import active_injector
 
 
@@ -55,11 +55,12 @@ def create_train_state(rng, model, tcfg: TrainConfig,
                        tx: Optional[optax.GradientTransformation] = None,
                        mesh: Optional[Mesh] = None) -> RAFTTrainState:
     """Initialize params + opt state (replicated over ``mesh`` if given).
-    The family's row of ``FAMILIES`` makes the inputs the model is
-    initialised on (``image_shape`` is read by the flow families only)."""
+    The family's row (``raft_tpu/families.py``) makes the inputs the
+    model is initialised on (``image_shape`` is read by the rows of
+    image pairs only)."""
     from raft_tpu.optim import fetch_optimizer
 
-    args, kwargs = family_of(tcfg).init_inputs(tcfg, image_shape)
+    args, kwargs = family_of(tcfg.model_family).init_inputs(tcfg, image_shape)
     variables = model.init({"params": rng, "dropout": rng}, *args, **kwargs)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", core.FrozenDict({}))
@@ -74,166 +75,10 @@ def create_train_state(rng, model, tcfg: TrainConfig,
     return state
 
 
-def _maybe_add_noise(rng, image1, image2):
-    """Per-batch gaussian noise aug (reference ``train.py:373-376``):
-    stdv ~ U(0, 5), images perturbed then clamped to [0, 255]."""
-    k0, k1, k2 = jax.random.split(rng, 3)
-    stdv = jax.random.uniform(k0, (), minval=0.0, maxval=5.0)
-    image1 = jnp.clip(
-        image1 + stdv * jax.random.normal(k1, image1.shape), 0.0, 255.0)
-    image2 = jnp.clip(
-        image2 + stdv * jax.random.normal(k2, image2.shape), 0.0, 255.0)
-    return image1, image2
-
-
 def _all_finite(tree) -> jnp.ndarray:
     """Scalar bool: every leaf of ``tree`` is entirely finite."""
     leaves = [jnp.all(jnp.isfinite(g)) for g in jax.tree.leaves(tree)]
     return functools.reduce(jnp.logical_and, leaves, jnp.bool_(True))
-
-
-# ------------------------------------------------------------ the families
-#
-# One row a ``model_family``: how to make the inputs its model is
-# initialised on, and how to turn ``(apply_fn, variables, batch, rngs,
-# step)`` into ``(loss, metrics, mutated)``. Everything else in the step
-# (the guard, ``apply_gradients``, donation, jit) is one code path.
-
-class Family(NamedTuple):
-    #: ``(tcfg, image_shape) -> (args, kwargs)`` of ``model.init``
-    init_inputs: Callable
-    #: ``(tcfg, freeze_bn) -> loss_fn(apply_fn, variables, batch, rngs,
-    #: step) -> (loss, metrics, mutated)``; ``rngs`` holds ``noise`` and
-    #: ``dropout`` keys already folded with the step
-    make_loss: Callable
-
-
-def _flow_init_inputs(tcfg, image_shape):
-    H, W = image_shape if image_shape is not None else tcfg.image_size
-    dummy = jnp.zeros((1, H, W, 3), jnp.float32)
-    return (dummy, dummy), {"iters": 1}
-
-
-def _flow_apply(tcfg, freeze_bn, apply_fn, variables, batch, rngs):
-    image1, image2 = batch["image1"], batch["image2"]
-    if tcfg.add_noise:
-        image1, image2 = _maybe_add_noise(rngs["noise"], image1, image2)
-    return apply_fn(
-        variables, image1, image2, iters=tcfg.iters,
-        train=True, freeze_bn=freeze_bn,
-        rngs={"dropout": rngs["dropout"]},
-        mutable=["batch_stats"])
-
-
-def _raft_loss(tcfg, freeze_bn):
-    def loss_fn(apply_fn, variables, batch, rngs, step):
-        out, mutated = _flow_apply(tcfg, freeze_bn, apply_fn, variables,
-                                   batch, rngs)
-        loss, metrics = sequence_loss(
-            out, batch["flow"], batch["valid"], gamma=tcfg.gamma,
-            normalization=tcfg.loss_normalization)
-        return loss, metrics, mutated
-    return loss_fn
-
-
-def _flow_list_loss(tcfg, freeze_bn):
-    """ours_02 snapshot: a plain list of dense flows."""
-    def loss_fn(apply_fn, variables, batch, rngs, step):
-        flow_preds, mutated = _flow_apply(tcfg, freeze_bn, apply_fn,
-                                          variables, batch, rngs)
-        loss, metrics = sequence_loss(
-            jnp.stack(list(flow_preds)), batch["flow"],
-            batch["valid"], gamma=tcfg.gamma,
-            normalization=tcfg.loss_normalization)
-        return loss, metrics, mutated
-    return loss_fn
-
-
-def _flow_corr_loss(tcfg, freeze_bn):
-    """The two-list snapshot trainer (reference train_02.py:54-81): flow
-    + corr predictions, each under a uniformly-weighted masked L1."""
-    def loss_fn(apply_fn, variables, batch, rngs, step):
-        from raft_tpu.losses import sequence_corr_loss
-        (flow_preds, corr_preds), mutated = _flow_apply(
-            tcfg, freeze_bn, apply_fn, variables, batch, rngs)
-        loss, metrics = sequence_corr_loss(
-            jnp.stack(list(flow_preds)),
-            jnp.stack(list(corr_preds)),
-            batch["flow"], batch["valid"])
-        return loss, metrics, mutated
-    return loss_fn
-
-
-def _sparse_loss(tcfg, freeze_bn):
-    """The fork's active trainer (reference train.py:19 ->
-    core/ours.py): list of per-outer-iteration dense flows plus sparse
-    keypoint predictions ((ref, key_flow, ...) tuples —
-    TwoStageKeypointRAFT emits the same contract), with the auxiliary
-    sparse loss gated to the first sparse_lambda_steps (reference
-    train.py:379-383)."""
-    def loss_fn(apply_fn, variables, batch, rngs, step):
-        (flow_preds, sparse_preds), mutated = _flow_apply(
-            tcfg, freeze_bn, apply_fn, variables, batch, rngs)
-        out = jnp.stack(list(flow_preds))
-        loss, metrics = sequence_loss(
-            out, batch["flow"], batch["valid"], gamma=tcfg.gamma,
-            normalization=tcfg.loss_normalization)
-        if tcfg.sparse_lambda > 0:
-            from raft_tpu.losses import sparse_keypoint_loss
-            # key flows are normalized src-dst offsets; the loss
-            # compares in pixels, scaled by (W-1, H-1) like the
-            # reference (train.py:73-82)
-            _, H_, W_, _ = batch["flow"].shape
-            scale = jnp.asarray([W_ - 1, H_ - 1], jnp.float32)
-            sparse = sparse_keypoint_loss(
-                [(p[0], p[1] * scale) for p in sparse_preds],
-                batch["flow"], batch["valid"])
-            lam = tcfg.sparse_lambda * (step < tcfg.sparse_lambda_steps)
-            loss = loss + lam * sparse
-            metrics["sparse_loss"] = sparse
-            metrics["loss"] = loss
-        return loss, metrics, mutated
-    return loss_fn
-
-
-def _token_init_inputs(tcfg, image_shape):
-    # parameter shapes do not depend on the sequence's length: a short
-    # one keeps the initialising forward off the kernels' tilings
-    dummy = jnp.zeros((1, min(tcfg.seq_len, 8)), jnp.int32)
-    return (dummy, dummy, dummy), {}
-
-
-def _token_loss(tcfg, freeze_bn):
-    """Next-token cross-entropy over the vocabulary held; the routing
-    counters of the expert layers ride the metrics."""
-    def loss_fn(apply_fn, variables, batch, rngs, step):
-        from raft_tpu.losses import token_cross_entropy
-        logits, counters = apply_fn(
-            {"params": variables["params"]}, batch["tokens"],
-            batch["segment_ids"], batch["positions"], train=True)
-        loss, metrics = token_cross_entropy(logits, batch["tokens"],
-                                            batch["segment_ids"])
-        metrics.update(counters)
-        return loss, metrics, {}
-    return loss_fn
-
-
-FAMILIES: Dict[str, Family] = {
-    "raft": Family(_flow_init_inputs, _raft_loss),
-    "keypoint_transformer": Family(_flow_init_inputs, _flow_list_loss),
-    "dual_query": Family(_flow_init_inputs, _flow_corr_loss),
-    "full_transformer": Family(_flow_init_inputs, _flow_corr_loss),
-    "sparse": Family(_flow_init_inputs, _sparse_loss),
-    "two_stage": Family(_flow_init_inputs, _sparse_loss),
-    "lfm2_moe": Family(_token_init_inputs, _token_loss),
-}
-
-def family_of(tcfg: TrainConfig) -> Family:
-    try:
-        return FAMILIES[tcfg.model_family]
-    except KeyError:
-        raise ValueError(f"unknown model_family {tcfg.model_family!r}; "
-                         f"choose from {sorted(FAMILIES)}") from None
 
 
 def make_train_step(tcfg: TrainConfig, freeze_bn: bool = False,
@@ -269,7 +114,7 @@ def make_train_step(tcfg: TrainConfig, freeze_bn: bool = False,
     """
     nan_steps = tuple(active_injector().nan_loss_steps)
 
-    family_loss = family_of(tcfg).make_loss(tcfg, freeze_bn)
+    family_loss = family_of(tcfg.model_family).make_loss(tcfg, freeze_bn)
 
     def step_fn(state: RAFTTrainState, batch: Dict[str, jnp.ndarray], rng):
         noise_rng, dropout_rng = jax.random.split(

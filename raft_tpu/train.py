@@ -35,14 +35,13 @@ import threading
 
 from raft_tpu import checkpoint as ckpt_lib
 from raft_tpu import evaluate
-from raft_tpu.config import (MODEL_FAMILIES, TOKEN_FAMILIES, LMConfig,
-                             RAFTConfig, TrainConfig)
+from raft_tpu.config import LMConfig, RAFTConfig, TrainConfig
+from raft_tpu.families import FAMILIES, family_of
 from raft_tpu.resilience import TrainingDiverged, all_hosts_agree
-from raft_tpu.models.raft import RAFT
 from raft_tpu.optim import make_schedule
 from raft_tpu.parallel import (create_train_state, make_mesh,
                                make_train_step, shard_batch)
-from raft_tpu.serving.metrics import xla_compile_count
+from raft_tpu.utils.compile_count import xla_compile_count
 from raft_tpu.utils.logger import TrainLogger
 from raft_tpu.utils.profiling import host_timer
 
@@ -110,34 +109,9 @@ STEP_COUNTERS = ("tokens", "routed_here", "expert_load_max", "dropped")
 
 
 def build_model(model_family: str, mcfg):
-    """``mcfg``: a ``RAFTConfig`` for the flow families, an ``LMConfig``
-    for the token family."""
-    if model_family == "lfm2_moe":
-        from raft_tpu.models.lfm2 import LFM2
-        return LFM2(mcfg)
-    if model_family == "sparse":
-        from raft_tpu.config import OursConfig, sparse_corr_from_env
-        from raft_tpu.models import SparseRAFT
-        return SparseRAFT(OursConfig(
-            mixed_precision=mcfg.mixed_precision,
-            alternate_corr=sparse_corr_from_env()))
-    if model_family == "keypoint_transformer":
-        from raft_tpu.models import KeypointTransformerRAFT
-        return KeypointTransformerRAFT(
-            mixed_precision=mcfg.mixed_precision)
-    if model_family == "dual_query":
-        from raft_tpu.models import DualQueryRAFT
-        return DualQueryRAFT(mixed_precision=mcfg.mixed_precision)
-    if model_family == "two_stage":
-        from raft_tpu.models import TwoStageKeypointRAFT
-        return TwoStageKeypointRAFT(mixed_precision=mcfg.mixed_precision)
-    if model_family == "full_transformer":
-        from raft_tpu.models import FullTransformerRAFT
-        return FullTransformerRAFT(mixed_precision=mcfg.mixed_precision)
-    if model_family == "raft":
-        return RAFT(mcfg)
-    raise ValueError(f"unknown model_family {model_family!r}; "
-                     f"choose from {MODEL_FAMILIES}")
+    """``mcfg``: a ``RAFTConfig`` for a family of image pairs, an
+    ``LMConfig`` for a token family."""
+    return family_of(model_family).build(mcfg)
 
 
 def _panels_and_validation(tcfg, model, state, batch, panel_fn,
@@ -153,15 +127,9 @@ def _panels_and_validation(tcfg, model, state, batch, panel_fn,
             _eval_variables(state), batch["image1"], batch["image2"]))
         i1, i2, fl = jax.device_get(
             (batch["image1"], batch["image2"], batch["flow"]))
-        if tcfg.model_family == "sparse":
-            flow_preds, sparse_preds = preds
-        elif tcfg.model_family in ("dual_query", "two_stage",
-                                   "full_transformer"):
-            # two-list outputs; only the sparse family's 4-tuples feed
-            # the keypoint/mask panels
-            flow_preds, sparse_preds = preds[0], None
-        else:
-            flow_preds, sparse_preds = preds, None
+        flow_preds, sparse_preds = (
+            preds if family_of(tcfg.model_family).sparse_preds
+            else (preds, None))
         logger.write_images(i1, i2, fl, flow_preds, sparse_preds,
                             step=step)
     if validation:
@@ -189,17 +157,17 @@ def train(tcfg: TrainConfig, mcfg, *,
     ``dataloader`` may be injected (tests); by default it is built from
     ``tcfg.stage`` (reference ``datasets.fetch_dataloader``).
     ``spatial_shards`` > 1 splits image rows over that many mesh columns
-    (sequence parallelism; canonical family only — the 2-D data x
-    spatial step is what ``dryrun_multichip`` validates).
+    (sequence parallelism, where the family's row allows it — the 2-D
+    data x spatial step is what ``dryrun_multichip`` validates).
 
-    A token family (``TOKEN_FAMILIES``; ``mcfg`` an ``LMConfig``) goes
+    A token family (``Family.tokens``; ``mcfg`` an ``LMConfig``) goes
     through the same loop, state, optimizer, guard, checkpointer and
     spans; it has no image panels, validation sets, BatchNorm to freeze
     or ``image_size``, its loader yields packed sequences
     (``data_root`` names an optional token file), and its routing
     counters (``STEP_COUNTERS``) ride each ``train.step`` span.
     """
-    tokens = tcfg.model_family in TOKEN_FAMILIES
+    tokens = family_of(tcfg.model_family).tokens
     image_size = None if tokens else tcfg.image_size
     rng = jax.random.PRNGKey(tcfg.seed)
     np.random.seed(tcfg.seed)                 # host-side aug reproducibility
@@ -451,8 +419,8 @@ def resolve_train_corr_engine(model_family, corr_impl, alternate_corr,
     form, so auto keeps the volume there."""
     if alternate_corr:
         return True
-    corr_impl = corr_impl or ("auto" if model_family == "raft"
-                              else "fixed")
+    corr_impl = corr_impl or (
+        "auto" if family_of(model_family).raft_options else "fixed")
     if corr_impl != "auto" or corr_dtype == "bfloat16":
         return False
     import jax as _jax
@@ -491,12 +459,10 @@ def main(argv=None):
     parser.add_argument("--stage", default="chairs",
                         choices=["chairs", "things", "sintel", "kitti"])
     parser.add_argument("--model_family", default="raft",
-                        choices=list(MODEL_FAMILIES),
+                        choices=list(FAMILIES),
                         help="canonical RAFT, the fork's sparse-keypoint "
-                             "(ours) family, a rebuilt experiment "
-                             "snapshot (keypoint_transformer=ours_02, "
-                             "dual_query=ours_04, two_stage=ours_06), or "
-                             "lfm2_moe: the LFM2-MoE language model "
+                             "(ours) family, or lfm2_moe: the LFM2-MoE "
+                             "language model "
                              "(gated short convolutions, grouped-query "
                              "attention, sigmoid-routed experts) on packed "
                              "token sequences; see --lm_config, --seq_len")
@@ -592,21 +558,21 @@ def main(argv=None):
     enable_compile_cache()
 
     evaluate.reject_raft_only_flags(parser, args)   # incl. --iters
-    tokens = args.model_family in TOKEN_FAMILIES
+    family = family_of(args.model_family)
+    tokens = family.tokens
     if not tokens and args.lm_config:
         parser.error("--lm_config applies to the lfm2_moe family only")
     if tokens and (args.validation or args.spatial_shards != 1
                    or args.restore_ckpt):
         parser.error("--validation, --spatial_shards and --restore_ckpt "
                      "apply to the flow families only")
-    # only the keypoint families consume the auxiliary sparse loss
-    if args.sparse_lambda > 0 and args.model_family not in ("sparse",
-                                                            "two_stage"):
+    # only a family with keypoint predictions has something to weigh
+    if args.sparse_lambda > 0 and not family.sparse_preds:
         parser.error("--sparse_lambda requires a keypoint family "
-                     "(sparse or two_stage)")
+                     "(sparse)")
     iters = args.iters if args.iters is not None else 12
 
-    if args.corr_impl == "auto" and args.model_family != "raft":
+    if args.corr_impl == "auto" and not family.raft_options:
         parser.error("--corr_impl auto applies to the canonical RAFT "
                      f"family only (the {args.model_family} family's "
                      "correlation engine has its own config default)")

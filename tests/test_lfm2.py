@@ -123,7 +123,7 @@ def test_forward_logits_match_reference(small):
 
 
 def _program_loss(cfg):
-    from raft_tpu.parallel.train_step import FAMILIES
+    from raft_tpu.families import FAMILIES
     loss_fn = FAMILIES["lfm2_moe"].make_loss(TrainConfig(
         model_family="lfm2_moe"), False)
     model = LFM2(cfg)

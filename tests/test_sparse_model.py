@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from raft_tpu.config import OursConfig
+from raft_tpu.models.ours import SparseRAFT
 from raft_tpu.ops.msda import ms_deform_attn
 
 
@@ -266,3 +267,43 @@ def test_sparse_alternate_corr_matches_materialized(rng):
     leaves = jax.tree_util.tree_leaves(g)
     assert any(float(jnp.max(jnp.abs(l))) > 0 for l in leaves)
     assert all(np.isfinite(np.asarray(l)).all() for l in leaves)
+
+
+# ``OursConfig.encoder_iterations``: the ours_07 encoder stacks
+
+B, H, W = 1, 64, 96
+
+
+@pytest.fixture(scope="module")
+def images():
+    rng = jax.random.PRNGKey(0)
+    img1 = jax.random.uniform(rng, (B, H, W, 3)) * 255.0
+    img2 = jnp.roll(img1, 2, axis=2)
+    return img1, img2
+
+
+class TestOurs07EncoderMode:
+    def test_encoder_stacks_active(self, images):
+        img1, img2 = images
+        cfg = OursConfig(base_channel=16, d_model=32, outer_iterations=2,
+                         num_keypoints=9, n_heads=4, dropout=0.0,
+                         encoder_iterations=2)
+        m = SparseRAFT(cfg)
+        rng = jax.random.PRNGKey(3)
+        v = m.init({"params": rng, "dropout": rng}, img1, img2)
+        names = set(v["params"].keys())
+        assert {"encoder_0", "encoder_1", "context_encoder_0",
+                "context_encoder_1", "encoder_pos_proj"} <= names
+        fp, sp = m.apply(v, img1, img2)
+        assert len(fp) == 2 and fp[-1].shape == (B, H, W, 2)
+        assert bool(jnp.isfinite(fp[-1]).all())
+
+    def test_default_has_no_encoder_params(self, images):
+        img1, img2 = images
+        cfg = OursConfig(base_channel=16, d_model=32, outer_iterations=1,
+                         num_keypoints=9, n_heads=4, dropout=0.0)
+        m = SparseRAFT(cfg)
+        rng = jax.random.PRNGKey(3)
+        v = m.init({"params": rng, "dropout": rng}, img1, img2)
+        assert not any(n.startswith("encoder_")
+                       for n in v["params"].keys())
