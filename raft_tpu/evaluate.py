@@ -16,6 +16,7 @@ validation (reference ``train.py:402-409``).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import os.path as osp
@@ -481,19 +482,25 @@ class FlowPredictor:
                 fn = self._iters_fn(img1.shape, iters, str(img1.dtype))
             return fn(self.variables, img1, img2, None)
 
-    def predict_batch(self, images1: np.ndarray, images2: np.ndarray):
-        """Batched forward: (B, H, W, 3) stacks → ((B, H/8, W/8, 2),
-        (B, H, W, 2)) numpy."""
+    def collect_batch(self, flows):
+        """The blocking half of :meth:`predict_batch`: wait for the
+        ``(flow_low, flow_up)`` device arrays :meth:`dispatch_batch`
+        returned and copy them to numpy. The dataset pass calls the two
+        halves apart, with the next batch's dispatch in between."""
         timer = host_timer()
-        flow_low, flow_up = self.dispatch_batch(images1, images2)
         # the first np.asarray would block as long; waiting here tells
         # the device's time apart from the copy's
         with timer.span("predict.device_wait"):
-            jax.block_until_ready((flow_low, flow_up))
+            jax.block_until_ready(flows)
         with timer.span("predict.d2h") as span:
-            flow_low, flow_up = np.asarray(flow_low), np.asarray(flow_up)
+            flow_low, flow_up = (np.asarray(f) for f in flows)
             span.nbytes = flow_low.nbytes + flow_up.nbytes
         return flow_low, flow_up
+
+    def predict_batch(self, images1: np.ndarray, images2: np.ndarray):
+        """Batched forward: (B, H, W, 3) stacks → ((B, H/8, W/8, 2),
+        (B, H, W, 2)) numpy."""
+        return self.collect_batch(self.dispatch_batch(images1, images2))
 
     # ----- streaming (session) entry points -------------------------------
     # The stateless forward runs fnet twice per pair (twin-image trick).
@@ -802,17 +809,20 @@ class FlowPredictor:
 
 
 class _OpenBatch:
-    """A padded shape's batch while it fills: its ``(idx, sample,
-    padder)`` items and, for a batched predictor, the two ``(bs, H, W,
-    C)`` staging buffers its frames are padded into, with how many of
-    them the arena had to allocate. In the per-sample fallback an item
+    """A padded shape's batch from its first frame to its last yield:
+    its ``(idx, sample, padder)`` items and, for a batched predictor,
+    the two ``(bs, H, W, C)`` staging buffers its frames are padded
+    into, with how many of them the arena had to allocate; from its
+    flush on its root span, and while it is dispatched ahead the
+    predictor's device arrays. In the per-sample fallback an item
     carries its two padded frames instead."""
 
-    __slots__ = ("shape", "items", "buffers", "fresh")
+    __slots__ = ("shape", "items", "buffers", "fresh", "root", "flows")
 
     def __init__(self, shape, buffers=(), fresh=0):
         self.shape, self.items = shape, []
         self.buffers, self.fresh = buffers, fresh
+        self.root = self.flows = None
 
 
 def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
@@ -826,35 +836,91 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
     ``mode``: InputPadder mode, or None when the dataset needs no padding
     (FlyingChairs is already /8).
 
-    Batches are staged through an arena (``predictor.staging``, else one
-    of this pass's own): a bucket's first sample acquires two ``(bs, H,
-    W, C)`` buffers of the sample's dtype, every sample is padded once,
-    straight into its slot, and the pair goes back to the arena when
-    ``predict_batch`` has returned, so from a shape's second batch on
-    the frames land in warm pages and nothing is allocated. The arena
-    holds what the open buckets need plus one idle pair a shape. No
+    **Order.** With a :class:`FlowPredictor` the pass is two batches
+    deep, on this one thread: a full bucket is dispatched at once
+    (``dispatch_batch``: H2D enqueued, the call queued behind the batch
+    on the device), and only then is the batch dispatched before it
+    collected (``collect_batch``: wait, D2H), its flows unpadded and
+    yielded. So the device goes from one batch to the next with no host
+    work in between, and the host fetches, pads and dispatches batch
+    k+1, and unpads and yields batch k, while the device runs. Batches
+    are collected in the order they were flushed: the consumer sees the
+    sequence of the synchronous order, each batch one dispatch later;
+    the last one is collected when the dataset is through. The pass
+    pipelines only where ``predictor.predict_batch`` is
+    ``FlowPredictor``'s own dispatch-then-collect. Any other
+    ``predict_batch`` (a stand-in's, a wrapper set on the instance)
+    promises one blocking call a batch, numpy in and numpy out, and
+    gets that, in the synchronous order: stage, call, yield. If
+    fetching or dispatching a batch raises, the batch before it is
+    collected and yielded first, as the synchronous order had done.
+
+    **Closing.** A consumer that closes the generator at a yield does
+    not wait for the device: the batch dispatched ahead is abandoned
+    (root closed with ``complete`` 0, staging pair dropped, device
+    arrays freed whenever the runtime is done with them).
+
+    **Staging.** Batches are staged through an arena
+    (``predictor.staging``, else one of this pass's own): a bucket's
+    first sample acquires two ``(bs, H, W, C)`` buffers of the sample's
+    dtype, every sample is padded once, straight into its slot, and the
+    pair stays with its batch until that batch's own outputs are ready:
+    only then may a transfer no longer read it, and it goes back to the
+    arena. Pipelined, two pairs a shape are alive (one on the device,
+    one filling), allocated by a shape's first two batches; from the
+    third on the frames land in warm pages and nothing is allocated. A
+    pair whose batch failed or was abandoned is dropped, not pooled. No
     consumer sees arena memory: a yielded ``sample`` is the dataset's
     own and a yielded flow is a view of the predictor's output.
 
-    Every call is one pass in the process host timer
+    **Spans.** Every call is one pass in the process host timer
     (:func:`raft_tpu.utils.profiling.host_timer`): a root span
-    ``pass.batch`` from the first fetch after the previous batch's last
-    yield to this batch's last yield (``unit``: the batch's sequence
-    number in the pass), over ``pass.fetch`` / ``pass.pad`` per sample
+    ``pass.batch`` from the batch's first fetch (the first after the
+    previous flush) to its last yield (``unit``: the batch's sequence
+    number in the pass), so two roots are open at a time, overlapping,
+    closed in ``unit`` order; every child is opened under its own
+    batch's root explicitly: ``pass.fetch`` / ``pass.pad`` per sample
     (``pass.pad``: the one copy of both frames into their slots; a
     bucket's first also takes the buffers from the arena),
     ``pass.stack`` once a batch (what is left of stacking: the tail
     slots' fill and the hand-off), the predictor's ``predict.*`` spans
-    and ``pass.unpad`` per yielded sample; what no child covers is the
-    consumer's. The root reads ``complete`` 1 once all its pairs were
-    yielded, also where the consumer closes the generator at that yield,
-    and ``arena_fresh``: how many of the batch's two buffers had to be
-    allocated (2 on a shape's first batch, 0 after).
+    and ``pass.unpad`` per yielded sample. The root reads ``complete``
+    1 once all its pairs were yielded, also where the consumer closes
+    the generator at that yield; ``arena_fresh``: how many of the
+    batch's two buffers had to be allocated; ``ahead``: 1 where the
+    batch was dispatched while an earlier one's outputs were pending
+    (0 for a pass's first batch and in the synchronous order); and
+    ``compiles`` where a call into the predictor for it compiled.
     """
     timer = host_timer()
     bs = getattr(predictor, "batch_size", 1)
     batched = hasattr(predictor, "predict_batch") and bs > 1
+    pipelined = batched and FlowPredictor.predict_batch is getattr(
+        predictor.predict_batch, "__func__", None)
     arena = getattr(predictor, "staging", None) or StagingArena()
+    units = itertools.count()
+    roots = []             # open, in unit order
+    buckets: Dict = {}
+    pending = None         # dispatched ahead: its flows are device arrays
+
+    def open_root():
+        # detached: the next batch's root opens while this one is open
+        roots.append(timer.span("pass.batch", unit=next(units), complete=0,
+                                ahead=0).detach())
+        return roots[-1]
+
+    @contextlib.contextmanager
+    def calling(root):
+        """A call into the predictor for ``root``'s batch: its spans
+        fall under that root, its compiles are counted there."""
+        before = xla_compile_count()
+        try:
+            with timer.under(root):
+                yield
+        finally:
+            compiles = xla_compile_count() - before
+            if compiles:
+                root.args["compiles"] = root.args.get("compiles", 0) + compiles
 
     def open_bucket(shape, dtype):
         before = arena.allocated
@@ -870,53 +936,32 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
             else:
                 dst[slot] = image
 
-    def flush(batch, root):
+    def launch(batch, root):
+        batch.root = root
         n = len(batch.items)
         root.args.update(pairs=n, padded_to=bs if batched else 1,
                          height=batch.shape[0], width=batch.shape[1])
         if batched:
             i1, i2 = batch.buffers
             root.args["arena_fresh"] = batch.fresh
-            with timer.span("pass.stack", nbytes=i1.nbytes + i2.nbytes):
+            with timer.span("pass.stack", parent=root,
+                            nbytes=i1.nbytes + i2.nbytes):
                 if n < bs:      # every tail slot rewritten: no stale frame
                     i1[n:] = i1[n - 1]
                     i2[n:] = i2[n - 1]
-            _, up = predictor.predict_batch(i1, i2)
-            # Only now may the buffers be written again. jnp.asarray
-            # merely enqueues the H2D copy and reads the host array
-            # until the transfer is done; predict_batch returns after
-            # block_until_ready on the outputs, which the executable
-            # wrote after it had its inputs whole. A batch that raised
-            # never gets here: its buffers are dropped, not pooled.
-            arena.release(i1, i2)
-        for j, (idx, sample, padder, *frames) in enumerate(batch.items):
-            flow = up[j] if batched else predictor(*frames)[1]
-            if padder:
-                with timer.span("pass.unpad"):
-                    flow = padder.unpad(flow)
-            # counted before the yield: a consumer that closes the
-            # generator at the batch's last yield never resumes it
-            root.args["complete"] = int(j == n - 1)
-            yield idx, sample, flow
+        if pipelined:
+            root.args["ahead"] = int(pending is not None)
+            with calling(root):
+                batch.flows = predictor.dispatch_batch(*batch.buffers)
+        return batch
 
-    units = itertools.count()
-
-    def open_batch():
-        return (timer.span("pass.batch", unit=next(units), complete=0),
-                xla_compile_count())
-
-    def close_batch(span, compiles_before):
-        compiles = xla_compile_count() - compiles_before
-        if compiles:
-            span.args["compiles"] = compiles
-        span.close()
-
-    buckets: Dict = {}
-    root = None            # the open batch: (span, compiles before it)
-    try:
+    def launched():
+        """The pass's batches in flush order, each staged and, where
+        the pass pipelines, dispatched."""
+        root = None        # of the batch whose frames are being fetched
         for idx in range(len(dataset)):
-            root = root or open_batch()
-            with timer.span("pass.fetch"):
+            root = root or open_root()
+            with timer.span("pass.fetch", parent=root):
                 sample = dataset[idx]
             images = sample[0], sample[1]
             padder = InputPadder(images[0].shape, mode=mode) if mode else None
@@ -925,7 +970,7 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
             key = shape, images[0].dtype.str
             batch = buckets.get(key)
             if batched:
-                with timer.span("pass.pad"):
+                with timer.span("pass.pad", parent=root):
                     if batch is None:
                         batch = buckets[key] = open_bucket(
                             shape, images[0].dtype)
@@ -935,38 +980,100 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
                 if batch is None:
                     batch = buckets[key] = _OpenBatch(shape)
                 if padder:
-                    with timer.span("pass.pad"):
+                    with timer.span("pass.pad", parent=root):
                         images = padder.pad(*images)
             batch.items.append((idx, sample, padder, *images))
             if len(batch.items) == bs:
-                yield from flush(buckets.pop(key), root[0])
-                root = close_batch(*root)
+                yield launch(buckets.pop(key), root)
+                root = None
         for key in list(buckets):
-            root = root or open_batch()
-            yield from flush(buckets.pop(key), root[0])
-            root = close_batch(*root)
+            yield launch(buckets.pop(key), root or open_root())
+            root = None
+
+    def collect(batch):
+        if batch is None:
+            return
+        root, n = batch.root, len(batch.items)
+        if batched:
+            with calling(root):
+                _, up = (predictor.collect_batch(batch.flows) if pipelined
+                         else predictor.predict_batch(*batch.buffers))
+            # Only now may the pair be written again. jnp.asarray merely
+            # enqueues the H2D copy and reads the host array until the
+            # transfer is done; the outputs are ready, and the
+            # executable wrote them after it had its inputs whole. A
+            # batch that raised never gets here: its pair is dropped,
+            # not pooled. The device arrays go now, not when the next
+            # batch has been staged.
+            arena.release(*batch.buffers)
+            batch.buffers, batch.flows = (), None
+        for j, (idx, sample, padder, *frames) in enumerate(batch.items):
+            if batched:
+                flow = up[j]
+            else:
+                with calling(root):
+                    flow = predictor(*frames)[1]
+            if padder:
+                with timer.span("pass.unpad", parent=root):
+                    flow = padder.unpad(flow)
+            # counted before the yield: a consumer that closes the
+            # generator at the batch's last yield never resumes it
+            root.args["complete"] = int(j == n - 1)
+            yield idx, sample, flow
+        roots.remove(root)
+        root.close()
+
+    def take_pending():
+        nonlocal pending
+        batch, pending = pending, None
+        return batch
+
+    stream = launched()
+    try:
+        while True:
+            try:
+                batch = next(stream, None)
+            except Exception:
+                # the synchronous order had yielded the batch before
+                # this one ahead of touching this one's first pair
+                yield from collect(take_pending())
+                raise
+            if batch is None:
+                yield from collect(take_pending())
+                break
+            if pipelined:   # one behind: what was dispatched before it
+                batch, pending = pending, batch
+            yield from collect(batch)
     finally:
-        if root:
-            close_batch(*root)
-        # a pass given up with buckets still filling: nothing of theirs
-        # is in flight, so their buffers go back too
+        # Given up at a yield, or failed. Nothing waits for the device:
+        # a batch dispatched ahead keeps running, its root closes with
+        # complete 0, and its pair, which a transfer may still read, is
+        # dropped with it; so is the pair of a batch that raised.
+        for root in roots:
+            root.close()
+        # buckets still filling: nothing of theirs is in flight, so
+        # their buffers go back
         for batch in buckets.values():
             arena.release(*batch.buffers)
 
 
 def _reported_pass(predictor, dataset, mode: Optional[str] = None):
     """:func:`_predict_dataset` for the ``validate_*`` entry points:
-    prints where the pass's host time went once it is through, and how
-    many of its batches' staging buffers came from the arena warm."""
+    prints where the pass's host time went once it is through, how many
+    of its batches' staging buffers came from the arena warm, and how
+    many batches were dispatched while the one before was on the
+    device."""
     timer = host_timer()
     before, began = timer.summary(), time.perf_counter_ns()
     yield from _predict_dataset(predictor, dataset, mode)
-    fresh = [s.args["arena_fresh"] for s in timer.spans()
-             if s.name == "pass.batch" and s.start_ns >= began
-             and "arena_fresh" in s.args]
+    roots = [s.args for s in timer.spans()
+             if s.name == "pass.batch" and s.start_ns >= began]
+    fresh = [r["arena_fresh"] for r in roots if "arena_fresh" in r]
     reuse = (f" | arena reuse: {1 - sum(fresh) / (2 * len(fresh)):.0%} of "
              f"{2 * len(fresh)} buffers" if fresh else "")
-    print("host stages:", timer.report(since=before) + reuse)
+    ahead = (f" | dispatched ahead: {sum(r['ahead'] for r in roots)} of "
+             f"{len(roots)} batches" if roots else "")
+    print("host stages:", timer.report(since=before) + reuse + ahead)
 
 
 def _epe_map(flow: np.ndarray, flow_gt: np.ndarray) -> np.ndarray:

@@ -74,27 +74,26 @@ class Span:
     absolute ``time.perf_counter_ns``, the clock the device trace is
     anchored on. ``unit`` (a non-negative integer) is the identifier
     all spans of one batch or one step share, ``parent`` the ``id`` of
-    the span that was open on this thread when this one began (0 for a
-    root); ``args`` holds small integers and may be filled while the
-    span is open."""
+    the span it was opened under: the one handed in, else the innermost
+    span open on this thread when this one began (0 for a root);
+    ``args`` holds small integers and may be filled while the span is
+    open."""
 
     __slots__ = ("_timer", "_stack", "name", "id", "parent", "unit",
                  "start_ns", "dur_ns", "nbytes", "args")
 
-    def __init__(self, timer, name, unit, nbytes, args):
+    def __init__(self, timer, name, unit, nbytes, args, parent=None):
         self._timer = timer
         self.name = name
         self.nbytes = nbytes
         self.args = args
         self.dur_ns = None
-        try:
-            stack = timer._open.stack
-        except AttributeError:
-            stack = timer._open.stack = []
-        self._stack = stack
-        if stack:
-            self.parent = stack[-1].id
-            self.unit = stack[-1].unit if unit is None else unit
+        stack = self._stack = timer._stack()
+        if parent is None and stack:
+            parent = stack[-1]
+        if parent is not None:
+            self.parent = parent.id
+            self.unit = parent.unit if unit is None else unit
         else:
             self.parent = 0
             self.unit = unit
@@ -108,19 +107,26 @@ class Span:
     def __exit__(self, *exc) -> None:
         self.close()
 
+    def detach(self) -> "Span":
+        """Take the open span off its thread's stack: it stays open,
+        but a span opened after it is its child only if it is handed
+        over as ``parent=`` or named by :meth:`HostStageTimer.under`.
+        For a span that outlives the work around it: the dataset pass
+        keeps two batches' roots open at once."""
+        if self in self._stack:
+            self._stack.remove(self)
+        return self
+
     def close(self) -> None:
         """End the span now and record it. Closing twice records once."""
         if self.dur_ns is not None:
             return
         self.dur_ns = time.perf_counter_ns() - self.start_ns
         # Usually the top of its thread's stack of open spans; a
-        # generator's root span can be closed under a consumer's span,
-        # or by the garbage collector on another thread.
-        stack = self._stack
-        if stack and stack[-1] is self:
-            stack.pop()
-        elif self in stack:
-            stack.remove(self)
+        # detached span is not on it, and a generator's span can be
+        # closed under a consumer's span, or by the garbage collector
+        # on another thread.
+        self.detach()
         self._timer._record(self)
 
 
@@ -145,11 +151,14 @@ class HostStageTimer:
 
     ``ring``: how many closed spans to keep (:meth:`spans`, oldest
     first, as :class:`SpanRecord`; :attr:`dropped` counts overwrites).
-    A span's parent is whatever span is open on the same thread, so
-    the predictor's spans fall under the dataset pass's batch that
-    called it. With a ring, each span of a unit is also forwarded to
-    the process :class:`~raft_tpu.observability.Tracer` while one is
-    enabled (as a slice of category ``host``); a span outside any unit
+    A span's parent is the span it was handed (``parent=``), else the
+    innermost span open on the same thread, which a caller can name
+    for the length of a call (:meth:`under`): so the predictor's spans
+    fall under the batch the dataset pass called it for, though the
+    pass has two batches' roots open. With a ring, each span of a unit
+    is also forwarded to the process
+    :class:`~raft_tpu.observability.Tracer` while one is enabled (as a
+    slice of category ``host``); a span outside any unit
     is not (the serving engine's calls into the predictor: its own
     call sites write request-keyed slices, into the tracer the engine
     captured when it was built). ``ring=0`` keeps totals only: the
@@ -180,12 +189,37 @@ class HostStageTimer:
         self._open = threading.local()
 
     def span(self, name: str, unit: Optional[int] = None, nbytes: int = 0,
-             **args: int) -> Span:
+             parent: Optional[Span] = None, **args: int) -> Span:
         """Open a span; close it with ``with`` or :meth:`Span.close`.
-        ``unit`` defaults to the enclosing span's."""
-        return Span(self, name, unit, nbytes, args)
+        ``parent``: the open span it belongs under, where that is not
+        the innermost one open on this thread. ``unit`` defaults to the
+        parent's."""
+        return Span(self, name, unit, nbytes, args, parent)
 
     stage = span
+
+    def _stack(self) -> List[Span]:
+        try:
+            return self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+            return stack
+
+    @contextlib.contextmanager
+    def under(self, span: Span):
+        """For the length of the block ``span`` (open) is the innermost
+        span of this thread: what code called inside opens without a
+        ``parent=`` of its own falls under it. How a caller with
+        several roots open says whose children a callee's spans are."""
+        stack = self._stack()
+        stack.append(span)
+        try:
+            yield span
+        finally:
+            for k in range(len(stack) - 1, -1, -1):
+                if stack[k] is span:
+                    del stack[k]
+                    break
 
     def _record(self, span: Span) -> None:
         with self._lock:

@@ -41,8 +41,10 @@ class StagingArena:
     """
 
     # Per-key cap: pipeline_depth batches in flight + one being staged
-    # covers steady state; beyond that, fall back to allocation rather
-    # than hold unbounded idle buffers.
+    # covers the engine's steady state, and the dataset pass's two pairs
+    # a shape (one batch on the device, one filling) are exactly four;
+    # beyond that, fall back to allocation rather than hold unbounded
+    # idle buffers.
     _MAX_PER_KEY = 4
 
     def __init__(self):

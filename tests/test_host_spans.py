@@ -3,6 +3,9 @@ loop (``raft_tpu.utils.profiling.host_timer``), the benchmark reader
 that turns them into per-batch and per-step milliseconds, and the
 named scopes of the model stages Flax leaves unnamed."""
 
+import contextlib
+import json
+import os
 import re
 import types
 
@@ -102,11 +105,18 @@ def test_pass_spans(predictor, timer, monkeypatch):
             BS * 32 * 48 * 2 * 4 + BS * 4 * 6 * 2 * 4)
     assert len(spans) == len(roots) * len(BATCH_LEVEL) + 3 * 7
     assert timer.dropped == 0
-    # the first batch's two buffers were allocated, the next two
-    # batches (the short one too) ran on the same pair, and it is back
-    assert [r.args["arena_fresh"] for r in roots] == [2, 0, 0]
-    assert predictor.staging.allocated == 2
-    assert predictor.staging.pooled_buffers() == 2
+    # two deep: a batch's root is still open (its flows not yet out)
+    # when the next one's opens, and they close in unit order
+    for a, b in zip(roots, roots[1:]):
+        assert a.start_ns < b.start_ns < a.start_ns + a.dur_ns
+        assert a.start_ns + a.dur_ns <= b.start_ns + b.dur_ns
+    assert [r.args["ahead"] for r in roots] == [0, 1, 1]
+    # the first two batches each had to allocate their pair (the second
+    # was staged while the first was on the device), the third (the
+    # short one) ran on the first's, and both pairs are back
+    assert [r.args["arena_fresh"] for r in roots] == [2, 2, 0]
+    assert predictor.staging.allocated == 4
+    assert predictor.staging.pooled_buffers() == 4
     assert timer.summary()["pass.fetch"]["count"] == 7
 
     # a second call is a new pass: its units start again
@@ -310,19 +320,359 @@ def test_reported_pass_prints_the_reuse_share(timer, capsys):
 
 def test_pass_closed_at_a_yield(predictor, timer):
     """The benchmark closes the generator at a batch's last yield: that
-    batch is complete; one closed a yield earlier is not. Either way no
-    span stays open."""
+    batch is complete; one closed a yield earlier is not. The batch
+    dispatched ahead of it is abandoned either way, and no span stays
+    open."""
     for stop_after, complete in ((BS, 1), (BS - 1, 0)):
         gen = _predict_dataset(predictor, Pairs(), mode="sintel")
         for n, _ in enumerate(gen, 1):
             if n == stop_after:
                 break
         gen.close()
-        root = by_name(timer.spans(), "pass.batch")[-1]
-        assert root.args["complete"] == complete
+        roots = by_name(timer.spans(), "pass.batch")[-2:]
+        assert [(r.unit, r.args["complete"]) for r in roots] == [
+            (0, complete), (1, 0)]
         with timer.span("probe") as probe:
             pass
         assert probe.parent == 0
+
+
+# --------------------------------------------------- two batches deep
+#
+# A FlowPredictor's pass dispatches batch k+1 before it collects batch
+# k. The taps below are set on the instance's dispatch_batch and
+# collect_batch: predict_batch stays the class's own, which is what the
+# pass looks at, so it still pipelines.
+
+class Cycled:
+    """The first ``n`` elements of ``base`` repeated."""
+
+    def __init__(self, base, n):
+        self.base, self.n = base, n
+
+    def __len__(self):
+        return self.n
+
+    def __getitem__(self, i):
+        return self.base[i % len(self.base)]
+
+
+DATASETS = {
+    "mixed_float32": lambda: MixedPairs(np.float32),
+    "mixed_uint8": lambda: MixedPairs(np.uint8),
+    "short_tail": Pairs,
+    "one_batch": lambda: Cycled(Pairs(), BS),
+    "empty": lambda: Cycled(Pairs(), 0),
+}
+
+
+@contextlib.contextmanager
+def wrapped_predict_batch(predictor, log):
+    """``predict_batch`` wrapped on the instance, the way
+    ``benchmark/tests/test_correct.py`` plants its faults."""
+    real = predictor.predict_batch
+
+    def predict_batch(images1, images2):
+        log.append(("call", address(images1)))
+        out = real(images1, images2)
+        log.append(("returned", address(images1)))
+        return out
+
+    predictor.predict_batch = predict_batch
+    try:
+        yield
+    finally:
+        del predictor.predict_batch
+
+
+@contextlib.contextmanager
+def tapped(predictor, log, dispatch_fails_at=None, collect_fails_at=None,
+           gates=None):
+    """Log every ``dispatch_batch`` and ``collect_batch`` with the
+    staging pair it is for, and hold that a pair reads at collection
+    what it read at dispatch. ``gates``: one ``threading.Event`` a
+    batch; its outputs cannot be collected before it is set."""
+    dispatch, collect = predictor.dispatch_batch, predictor.collect_batch
+    inflight = {}
+
+    def dispatch_batch(images1, images2):
+        k = sum(kind == "dispatched" for kind, _ in log)
+        if k == dispatch_fails_at:
+            raise RuntimeError("dispatch refused")
+        flows = dispatch(images1, images2)
+        inflight[id(flows[1])] = (k, images1, images2, images1.copy(),
+                                  images2.copy())
+        log.append(("dispatched", (address(images1), address(images2))))
+        return flows
+
+    def collect_batch(flows):
+        k, images1, images2, was1, was2 = inflight.pop(id(flows[1]))
+        if gates is not None:
+            assert gates[k].wait(timeout=60), f"batch {k} never released"
+        if k == collect_fails_at:
+            raise RuntimeError("device lost")
+        out = collect(flows)
+        assert (images1.tobytes() == was1.tobytes()
+                and images2.tobytes() == was2.tobytes()), (
+            f"batch {k}'s pair was rewritten while it was in flight")
+        log.append(("collected", (address(images1), address(images2))))
+        return out
+
+    predictor.dispatch_batch = dispatch_batch
+    predictor.collect_batch = collect_batch
+    try:
+        yield
+    finally:
+        del predictor.dispatch_batch, predictor.collect_batch
+
+
+class CycleArena(LoggingArena):
+    """Logs what it hands out too."""
+
+    def acquire(self, shape, dtype):
+        buffer = super().acquire(shape, dtype)
+        self.log.append(("acquired", address(buffer)))
+        return buffer
+
+
+@pytest.mark.parametrize("name", list(DATASETS))
+def test_pipelined_pass_yields_what_the_synchronous_order_yields(
+        predictor, timer, name):
+    """Bit for bit and in the same order, whatever the shapes, the tail
+    and the dtype on the wire; the wrapped ``predict_batch`` is called
+    once a batch."""
+    dataset = DATASETS[name]()
+    got = list(_predict_dataset(predictor, dataset, "sintel"))
+    calls = []
+    with wrapped_predict_batch(predictor, calls):
+        want = list(_predict_dataset(predictor, dataset, "sintel"))
+    assert [idx for idx, _, _ in got] == [idx for idx, _, _ in want]
+    assert len(got) == len(dataset)
+    for (_, sample, flow), (_, sample2, flow2) in zip(got, want):
+        assert all(a.tobytes() == b.tobytes()
+                   for a, b in zip(sample, sample2))
+        assert flow.shape == sample[0].shape[:2] + (2,)
+        assert flow.dtype == flow2.dtype == np.float32
+        assert flow.tobytes() == flow2.tobytes()
+    roots = by_name(timer.spans(), "pass.batch")
+    batches = len(roots) // 2
+    assert batches == {"mixed_float32": 6, "mixed_uint8": 6,
+                       "short_tail": 3, "one_batch": 1, "empty": 0}[name]
+    assert [r.args["ahead"] for r in roots] == (
+        [0] + [1] * (batches - 1) if batches else []) + [0] * batches
+    assert [kind for kind, _ in calls] == ["call", "returned"] * batches
+
+
+def test_pipelined_pass_overlaps(predictor, timer):
+    """Batch k+1 is dispatched before batch k's outputs are read, across
+    a change of shape too (the batches go a a b a b a), and every span
+    lies under its own batch's root though two roots are open."""
+    list(_predict_dataset(predictor, MixedPairs(np.float32), "sintel"))
+    spans = timer.spans()
+    roots = by_name(spans, "pass.batch")
+    assert [r.unit for r in roots] == list(range(6))
+    assert [r.args["ahead"] for r in roots] == [0, 1, 1, 1, 1, 1]
+    assert [r.args["height"] for r in roots] == [32, 32, 24, 32, 24, 32]
+    assert all(r.parent == 0 and r.args["complete"] == 1 for r in roots)
+    assert {s.parent for s in spans if s.name != "pass.batch"} == {
+        r.id for r in roots}
+    kids = {r.unit: {s.name: s for s in spans if s.parent == r.id}
+            for r in roots}
+    for r in roots:
+        # (a leftover batch's frames were fetched under earlier roots)
+        assert set(BATCH_LEVEL[1:]) | {"pass.unpad"} <= set(
+            kids[r.unit]) <= set(BATCH_LEVEL[1:]) | {
+                "pass.fetch", "pass.pad", "pass.unpad"}
+        assert all(s.unit == r.unit for s in kids[r.unit].values())
+        n = r.args["pairs"]
+        assert sum(s.parent == r.id and s.name == "pass.unpad"
+                   for s in spans) == n
+    for a, b in zip(roots, roots[1:]):
+        ahead, behind = kids[b.unit], kids[a.unit]
+        assert (ahead["predict.dispatch"].start_ns
+                + ahead["predict.dispatch"].dur_ns
+                <= behind["predict.device_wait"].start_ns
+                < behind["predict.d2h"].start_ns)
+        # and a's flows are out before b's outputs are touched
+        assert (a.start_ns + a.dur_ns
+                <= ahead["predict.device_wait"].start_ns)
+    # the reader of the six pass stages reads them over overlapping
+    # roots: the whole pass, and its last three batches
+    for metric in ("fetch_pad", "stack", "h2d", "dispatch", "device_wait",
+                   "d2h"):
+        args = json.load(open(os.path.join(
+            os.path.dirname(program_spans.__file__), "..", "metrics",
+            metric + "_ms_per_batch.pass.json")))["args"]
+        for n in (6, 3):
+            ids = {r.id for r in roots[-n:]}
+            want = sum(s.dur_ns for s in spans if s.parent in ids
+                       and s.name in args["stages"]) / n / 1e6
+            assert program_spans.per_unit_ms(
+                {"run": {"batches": n}}, args["root"], args["stages"],
+                args["units"]) == pytest.approx(want)
+            assert want > 0
+
+
+def test_a_pair_stays_with_its_batch_until_its_outputs_are_read(
+        predictor, timer, monkeypatch):
+    log = []
+    monkeypatch.setattr(predictor, "staging", CycleArena(log))
+    with tapped(predictor, log):
+        flows = list(_predict_dataset(predictor, Cycled(Pairs(), 5 * BS),
+                                      "sintel"))
+    assert len(flows) == 5 * BS
+    # two pairs, allocated by the first two batches, used in turn
+    arena = predictor.staging
+    assert arena.allocated == 4 == arena.pooled_buffers()
+    assert [r.args["arena_fresh"] for r in by_name(
+        timer.spans(), "pass.batch")] == [2, 2, 0, 0, 0]
+    pairs = [frozenset(p) for kind, p in log if kind == "dispatched"]
+    assert len(set(pairs)) == 2 and all(
+        a != b for a, b in zip(pairs, pairs[1:]))
+    # each buffer's life: handed out, dispatched, its outputs read, back
+    # in the pool, and only then handed out again
+    for buffer in {a for pair in pairs for a in pair}:
+        life = [kind for kind, what in log
+                if what == buffer or (isinstance(what, tuple)
+                                      and buffer in what)]
+        assert life == ["acquired", "dispatched", "collected",
+                        "released"] * (len(life) // 4)
+    # and batch k+1 was dispatched before batch k was collected
+    kinds = [kind for kind, _ in log if kind in ("dispatched", "collected")]
+    assert kinds == (["dispatched"] + ["dispatched", "collected"] * 4
+                     + ["collected"])
+
+
+def test_closing_the_pass_does_not_wait_for_the_batch_in_flight(
+        predictor, timer, monkeypatch):
+    """The benchmark closes the pass at a batch's last yield with the
+    next batch on the device. That batch is abandoned: nobody waits for
+    it, its pair is not pooled, its root closes incomplete; the span
+    reader still reads the completed batches."""
+    import threading
+    import time
+
+    log, gates = [], [threading.Event() for _ in range(4)]
+    monkeypatch.setattr(predictor, "staging", CycleArena(log))
+    gates[0].set()
+    gates[1].set()
+    with tapped(predictor, log, gates=gates):
+        gen = _predict_dataset(predictor, Cycled(Pairs(), 4 * BS), "sintel")
+        got = [next(gen) for _ in range(2 * BS)]
+        began = time.perf_counter()
+        gen.close()          # batch 2 is dispatched; its gate stays shut
+        assert time.perf_counter() - began < 5
+    assert [idx for idx, _, _ in got] == list(range(2 * BS))
+    kinds = [kind for kind, _ in log if kind in ("dispatched", "collected")]
+    assert kinds == ["dispatched", "dispatched", "collected", "dispatched",
+                     "collected"]
+    # batches 0 and 1 ran on two pairs, both back; batch 2 had taken
+    # batch 0's again, and that one is dropped
+    arena = predictor.staging
+    assert arena.allocated == 4 and arena.pooled_buffers() == 2
+    roots = by_name(timer.spans(), "pass.batch")
+    assert [(r.unit, r.args["complete"], r.args["ahead"])
+            for r in roots] == [(0, 1, 0), (1, 1, 1), (2, 0, 1)]
+    assert {s.name for s in timer.spans() if s.parent == roots[2].id} == {
+        "pass.fetch", "pass.pad", "pass.stack", "predict.h2d",
+        "predict.dispatch"}
+    with timer.span("probe") as probe:
+        pass
+    assert probe.parent == 0
+    for stages in (["pass.fetch", "pass.pad"], ["pass.stack"],
+                   ["predict.h2d"], ["predict.dispatch"],
+                   ["predict.device_wait"], ["predict.d2h"]):
+        assert program_spans.per_unit_ms(
+            {"run": {"batches": 2}}, "pass.batch", stages, "batches") > 0
+    # a later pass on the same predictor: the same flows
+    again = list(_predict_dataset(predictor, Cycled(Pairs(), 2 * BS),
+                                  "sintel"))
+    for (_, _, flow), (_, _, flow2) in zip(got, again):
+        np.testing.assert_array_equal(flow, flow2)
+
+
+@pytest.mark.parametrize("where", ["dispatch", "collect"])
+def test_a_failing_batch_surfaces_after_the_flows_before_it(
+        predictor, timer, monkeypatch, where):
+    """Batch 1 fails, in its dispatch or when its outputs are read:
+    the consumer has batch 0's flows by then, as in the synchronous
+    order, and the failed batch's pair (and that of batch 2, already in
+    flight when batch 1's collection fails) is not pooled."""
+    log = []
+    monkeypatch.setattr(predictor, "staging", CycleArena(log))
+    got = []
+    with tapped(predictor, log, **{where + "_fails_at": 1}):
+        with pytest.raises(RuntimeError, match="refused|lost"):
+            for item in _predict_dataset(predictor, Cycled(Pairs(), 4 * BS),
+                                         "sintel"):
+                got.append(item)
+    assert [idx for idx, _, _ in got] == list(range(BS))
+    roots = by_name(timer.spans(), "pass.batch")
+    if where == "dispatch":
+        assert [(r.unit, r.args["complete"]) for r in roots] == [
+            (0, 1), (1, 0)]
+        assert predictor.staging.pooled_buffers() == 2
+    else:
+        assert [(r.unit, r.args["complete"]) for r in roots] == [
+            (0, 1), (1, 0), (2, 0)]
+        assert [kind for kind, _ in log if kind in (
+            "dispatched", "collected")] == [
+                "dispatched", "dispatched", "collected", "dispatched"]
+        assert predictor.staging.pooled_buffers() == 0
+    with timer.span("probe") as probe:
+        pass
+    assert probe.parent == 0
+    want = list(_predict_dataset(predictor, Cycled(Pairs(), BS), "sintel"))
+    for (_, _, flow), (_, _, flow2) in zip(got, want):
+        np.testing.assert_array_equal(flow, flow2)
+
+
+def test_a_failing_fetch_surfaces_after_the_flows_before_it(predictor,
+                                                            timer):
+    class Torn(Pairs):
+        def __getitem__(self, i):
+            if i == BS + 1:
+                raise OSError("frame unreadable")
+            return super().__getitem__(i)
+
+    got = []
+    with pytest.raises(OSError, match="unreadable"):
+        for item in _predict_dataset(predictor, Torn(), "sintel"):
+            got.append(item)
+    assert [idx for idx, _, _ in got] == list(range(BS))
+    assert [(r.unit, r.args["complete"]) for r in by_name(
+        timer.spans(), "pass.batch")] == [(0, 1), (1, 0)]
+
+
+@pytest.mark.parametrize("kind", ["stand_in", "wrapped"])
+def test_any_other_predict_batch_gets_one_blocking_call_a_batch(
+        predictor, timer, kind):
+    """A stand-in that offers only ``predict_batch``, and a
+    ``FlowPredictor`` whose ``predict_batch`` is wrapped on the
+    instance: stage, call, yield, batch after batch."""
+    log = []
+    dataset = MixedPairs(np.float32)
+    if kind == "stand_in":
+        subject, context = Recorder(), contextlib.nullcontext()
+        subject.staging.log = subject.log = log
+    else:
+        subject, context = predictor, wrapped_predict_batch(predictor, log)
+    with context:
+        for idx, _, _ in _predict_dataset(subject, dataset, "sintel"):
+            log.append(("yield", idx))
+    events = [(kind_, what) for kind_, what in log
+              if kind_ in ("returned", "yield")]
+    _, order = old_batches(dataset, "sintel", BS)
+    want = []
+    for pairs in (3, 3, 3, 3, 1, 1):
+        want += ["returned"] + ["yield"] * pairs
+    assert [kind_ for kind_, _ in events] == want
+    assert [what for kind_, what in events if kind_ == "yield"] == order
+    roots = by_name(timer.spans(), "pass.batch")
+    assert [r.args["ahead"] for r in roots] == [0] * 6
+    assert [r.args["pairs"] for r in roots] == [3, 3, 3, 3, 1, 1]
+    for a, b in zip(roots, roots[1:]):     # one root open at a time
+        assert a.start_ns + a.dur_ns <= b.start_ns
 
 
 def test_reader_reads_the_newest_pass(predictor, timer):
