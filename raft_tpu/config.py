@@ -275,6 +275,83 @@ class LMConfig:
         return self.vocab_size if self.vocab_held is None else self.vocab_held
 
 
+#: The published layer pattern of granite-4.0-h-micro: attention at
+#: layers 5, 15, 25 and 35, Mamba-2 elsewhere (a period of 10, 9:1).
+_GRANITE_LAYER_TYPES = tuple(
+    "attention" if i % 10 == 5 else "mamba" for i in range(40))
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteHybridConfig:
+    """The ``granitemoehybrid`` token family (ibm-granite
+    granite-4.0-h-micro's ``config.json`` keys, defaults as published):
+    Mamba-2 state-space mixers and grouped-query attention without
+    positions, a dense SwiGLU in every layer (``num_local_experts`` is 0
+    in the published model: no routed experts), four scalars on the
+    residual path. ``vocab_held`` rows of the (tied) embedding live
+    here; logits and loss are over them."""
+
+    hidden_size: int = 2048
+    shared_intermediate_size: int = 8192   # the dense SwiGLU
+    num_hidden_layers: int = 40
+    layer_types: Tuple[str, ...] = _GRANITE_LAYER_TYPES
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 12.0
+    attention_multiplier: float = 0.015625
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 8.0
+    rms_norm_eps: float = 1e-5
+    vocab_size: int = 100352
+    # the chip's share (all of it by default)
+    vocab_held: Optional[int] = None
+    # bfloat16 operands with float32 accumulation; parameters, norm
+    # statistics, softmax, dt, the log-decays and the carried state
+    # stay float32
+    mixed_precision: bool = True
+
+    def __post_init__(self):
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"num_hidden_layers is {self.num_hidden_layers}")
+        bad = set(self.layer_types) - {"mamba", "attention"}
+        if bad:
+            raise ValueError(f"unknown layer types {sorted(bad)}")
+        if self.num_attention_heads % self.num_key_value_heads or \
+                self.hidden_size % self.num_attention_heads:
+            raise ValueError("heads must divide hidden_size and be a "
+                             "multiple of num_key_value_heads")
+        if self.mamba_n_heads * self.mamba_d_head != self.d_inner:
+            raise ValueError(
+                f"{self.mamba_n_heads} heads of {self.mamba_d_head} are "
+                f"not mamba_expand x hidden_size = {self.d_inner}")
+        if self.mamba_n_groups != 1:
+            raise ValueError("one group of B and C serves all heads "
+                             "(mamba_n_groups 1) in this family's scan")
+        if not 0 < self.vocab <= self.vocab_size:
+            raise ValueError(f"vocab_held {self.vocab} of {self.vocab_size}")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def vocab(self) -> int:
+        return self.vocab_size if self.vocab_held is None else self.vocab_held
+
+
 @dataclasses.dataclass(frozen=True)
 class TrainConfig:
     """Training hyperparameters (reference ``train.py:431-452`` flags and
@@ -283,15 +360,15 @@ class TrainConfig:
     name: str = "raft"
     stage: str = "chairs"
     # a row of raft_tpu/families.py: "raft" (canonical), "sparse" (the
-    # fork's active "ours" trainer, reference train.py:19 → core/ours.py)
-    # or "lfm2_moe" (packed token sequences)
+    # fork's active "ours" trainer, reference train.py:19 → core/ours.py),
+    # "lfm2_moe" or "granitemoehybrid" (packed token sequences)
     model_family: str = "raft"
     lr: float = 4e-4
     num_steps: int = 100000
     batch_size: int = 8
     image_size: Tuple[int, int] = (368, 496)
-    # tokens a sequence; read by the token family (``lfm2_moe``) only,
-    # which has no ``image_size``
+    # tokens a sequence; read by the token families only, which have no
+    # ``image_size``
     seq_len: int = 8192
     wdecay: float = 1e-4
     epsilon: float = 1e-8

@@ -12,17 +12,18 @@ builds, not when this module is.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, NamedTuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from raft_tpu.config import GraniteHybridConfig, LMConfig
 from raft_tpu.losses import sequence_loss
 
 
 class Family(NamedTuple):
     #: ``mcfg -> flax module``; ``mcfg`` is a ``RAFTConfig`` for a row of
-    #: image pairs, an ``LMConfig`` for a row of tokens
+    #: image pairs, the row's ``config_cls`` for a row of tokens
     build: Callable
     #: ``(tcfg, image_shape) -> (args, kwargs)`` of ``model.init``
     init_inputs: Callable
@@ -51,6 +52,13 @@ class Family(NamedTuple):
     flow_init: bool = False
     #: image rows may be split over the mesh's ``spatial`` axis
     spatial_shards: bool = False
+    #: a token row's config dataclass (``--lm_config`` fills it from a
+    #: JSON file's ``model`` object)
+    config_cls: Optional[type] = None
+    #: the integer counters the row's step reports beside its loss;
+    #: ``train()`` puts them on the step's span and into the scalar
+    #: stream
+    step_counters: Tuple[str, ...] = ()
 
 
 def _build_raft(mcfg):
@@ -69,6 +77,11 @@ def _build_sparse(mcfg):
 def _build_lfm2(mcfg):
     from raft_tpu.models.lfm2 import LFM2
     return LFM2(mcfg)
+
+
+def _build_granite(mcfg):
+    from raft_tpu.models.granitemoehybrid import GraniteMoeHybrid
+    return GraniteMoeHybrid(mcfg)
 
 
 def _maybe_add_noise(rng, image1, image2):
@@ -150,8 +163,8 @@ def _token_init_inputs(tcfg, image_shape):
 
 
 def _token_loss(tcfg, freeze_bn):
-    """Next-token cross-entropy over the vocabulary held; the routing
-    counters of the expert layers ride the metrics."""
+    """Next-token cross-entropy over the vocabulary held; the model's
+    counters (the row's ``step_counters``) ride the metrics."""
     def loss_fn(apply_fn, variables, batch, rngs, step):
         from raft_tpu.losses import token_cross_entropy
         logits, counters = apply_fn(
@@ -171,7 +184,14 @@ FAMILIES: Dict[str, Family] = {
     "sparse": Family(_build_sparse, _flow_init_inputs, _sparse_loss,
                      sparse_preds=True),
     "lfm2_moe": Family(_build_lfm2, _token_init_inputs, _token_loss,
-                       tokens=True),
+                       tokens=True, config_cls=LMConfig,
+                       step_counters=("tokens", "routed_here",
+                                      "expert_load_max", "dropped")),
+    "granitemoehybrid": Family(_build_granite, _token_init_inputs,
+                               _token_loss, tokens=True,
+                               config_cls=GraniteHybridConfig,
+                               step_counters=("tokens", "ssm_resets",
+                                              "ssd_chunks")),
 }
 
 #: the rows ``evaluate.py`` and ``demo.py`` offer: image pairs in, flow out

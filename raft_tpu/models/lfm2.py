@@ -30,50 +30,16 @@ import jax
 import jax.numpy as jnp
 
 from raft_tpu.config import LMConfig
+from raft_tpu.models.lm_common import (_INIT, _dense, _dtype,
+                                       _refuse_a_mesh_on_tpu, lm_head,
+                                       rms_norm, swiglu)
 from raft_tpu.ops.attention import causal_attention
 from raft_tpu.ops.gmm import expert_gmm
-
-_INIT = nn.initializers.normal(0.02)
-
-
-def _refuse_a_mesh_on_tpu() -> None:
-    """Neither kernel has a ``shard_map`` wrapper, GSPMD cannot
-    partition a Mosaic kernel, and the jnp twins do not fit a long
-    sequence (8.6 GB of scores at 8192 tokens): traced on TPU over a
-    mesh of more than one device the model refuses, rather than choose
-    a path that can only run out of memory."""
-    if jax.default_backend() != "tpu":
-        return
-    from raft_tpu.parallel.spatial import current_spatial_kernel_mesh
-    mesh = current_spatial_kernel_mesh()
-    if mesh is not None and mesh.size > 1:
-        raise NotImplementedError(
-            f"lfm2_moe is traced over a {dict(mesh.shape)} mesh: "
-            f"expert_gmm and causal_attention have no shard_map wrapper "
-            f"yet; train it on one device")
-
-
-def _dtype(cfg: LMConfig):
-    """The matmul operands' dtype under the configuration's policy."""
-    return jnp.bfloat16 if cfg.mixed_precision else jnp.float32
 
 
 def _no_counters() -> dict:
     zero = jnp.zeros((), jnp.int32)
     return {"routed_here": zero, "expert_load_max": zero, "dropped": zero}
-
-
-def _dense(x, w, dtype):
-    """``x @ w``: operands in ``dtype``, float32 accumulation, result in
-    ``dtype``."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=jnp.float32).astype(dtype)
-
-
-def rms_norm(x, weight, eps: float):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
-        * weight
 
 
 def rope(x, positions, theta: float):
@@ -86,14 +52,6 @@ def rope(x, positions, theta: float):
     sin = jnp.sin(angle)[:, :, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def lm_head(hidden, rows, dtype):
-    """Logits in float32 over the vocabulary rows held (the embedding's,
-    tied)."""
-    with jax.named_scope("lm_head"):
-        return jnp.dot(hidden.astype(dtype), rows.astype(dtype).T,
-                       preferred_element_type=jnp.float32)
 
 
 class ShortConv(nn.Module):
@@ -165,10 +123,7 @@ class DenseFFN(nn.Module):
         w1 = self.param("w1", _INIT, (d, f))
         w3 = self.param("w3", _INIT, (d, f))
         w2 = self.param("w2", _INIT, (f, d))
-        with jax.named_scope("dense_ffn"):
-            gate = _dense(x, w1, dtype).astype(jnp.float32)
-            up = _dense(x, w3, dtype).astype(jnp.float32)
-            return _dense(jax.nn.silu(gate) * up, w2, dtype)
+        return swiglu(x, w1, w3, w2, dtype)
 
 
 @jax.custom_vjp

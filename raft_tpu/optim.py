@@ -115,6 +115,13 @@ def make_schedule(cfg: TrainConfig) -> optax.Schedule:
     raise ValueError(f"unknown scheduler {cfg.scheduler!r}")
 
 
+#: leaves AdamW's decay leaves alone, by name
+_NO_DECAY = frozenset({
+    "expert_bias",                                  # models/lfm2.py
+    "A_log", "D", "dt_bias", "norm",                # models/granitemoehybrid.py
+    "input_layernorm", "post_attention_layernorm"})
+
+
 def _decay_mask(params):
     """True where AdamW weight decay applies.
 
@@ -125,14 +132,17 @@ def _decay_mask(params):
     that semantics. A frozen-BN subtree is recognized by its
     ``running_mean``/``running_var`` keys. The expert router's selection
     bias (``expert_bias``, ``models/lfm2.py``) is a buffer in the same
-    sense: no gradient reaches it, and decay must not shrink it.
+    sense: no gradient reaches it, and decay must not shrink it. The
+    state-space mixer's ``A_log``, ``D`` and ``dt_bias`` and the norm
+    weights of ``models/granitemoehybrid.py`` take none either, as in
+    the published recipes of that family.
     """
     def mask_tree(tree, name=None):
         if isinstance(tree, dict):
             if "running_mean" in tree and "running_var" in tree:
                 return {k: False for k in tree}
             return {k: mask_tree(v, k) for k, v in tree.items()}
-        return name != "expert_bias"
+        return name not in _NO_DECAY
 
     # unwrap FrozenDict-likes into plain dicts for optax
     plain = jax.tree_util.tree_map(lambda x: x, params)

@@ -35,7 +35,7 @@ import threading
 
 from raft_tpu import checkpoint as ckpt_lib
 from raft_tpu import evaluate
-from raft_tpu.config import LMConfig, RAFTConfig, TrainConfig
+from raft_tpu.config import RAFTConfig, TrainConfig
 from raft_tpu.families import FAMILIES, family_of
 from raft_tpu.resilience import TrainingDiverged, all_hosts_agree
 from raft_tpu.optim import make_schedule
@@ -103,14 +103,9 @@ def _eval_variables(state):
     return {"params": state.params, "batch_stats": state.batch_stats}
 
 
-#: the routing counters a token family's step reports; ``train()`` puts
-#: them on the step's span as integers and into the scalar stream
-STEP_COUNTERS = ("tokens", "routed_here", "expert_load_max", "dropped")
-
-
 def build_model(model_family: str, mcfg):
-    """``mcfg``: a ``RAFTConfig`` for a family of image pairs, an
-    ``LMConfig`` for a token family."""
+    """``mcfg``: a ``RAFTConfig`` for a family of image pairs, the
+    row's ``config_cls`` for a token family."""
     return family_of(model_family).build(mcfg)
 
 
@@ -160,14 +155,16 @@ def train(tcfg: TrainConfig, mcfg, *,
     (sequence parallelism, where the family's row allows it — the 2-D
     data x spatial step is what ``dryrun_multichip`` validates).
 
-    A token family (``Family.tokens``; ``mcfg`` an ``LMConfig``) goes
-    through the same loop, state, optimizer, guard, checkpointer and
-    spans; it has no image panels, validation sets, BatchNorm to freeze
-    or ``image_size``, its loader yields packed sequences
-    (``data_root`` names an optional token file), and its routing
-    counters (``STEP_COUNTERS``) ride each ``train.step`` span.
+    A token family (``Family.tokens``; ``mcfg`` the row's
+    ``config_cls``) goes through the same loop, state, optimizer, guard,
+    checkpointer and spans; it has no image panels, validation sets,
+    BatchNorm to freeze or ``image_size``, its loader yields packed
+    sequences (``data_root`` names an optional token file), and the
+    counters its step reports (the row's ``step_counters``) ride each
+    ``train.step`` span.
     """
-    tokens = family_of(tcfg.model_family).tokens
+    family = family_of(tcfg.model_family)
+    tokens, step_counters = family.tokens, family.step_counters
     image_size = None if tokens else tcfg.image_size
     rng = jax.random.PRNGKey(tcfg.seed)
     np.random.seed(tcfg.seed)                 # host-side aug reproducibility
@@ -327,7 +324,7 @@ def train(tcfg: TrainConfig, mcfg, *,
                         with timer.span("train.metrics_fetch",
                                         leaves=len(metrics)):
                             host_metrics = jax.device_get(metrics)
-                        for key in STEP_COUNTERS:
+                        for key in step_counters:
                             if key in host_metrics:
                                 step_span.args[key] = int(host_metrics[key])
                         with timer.span("train.log"):
@@ -437,18 +434,21 @@ def resolve_train_corr_engine(model_family, corr_impl, alternate_corr,
                                         spatial_shards=spatial_shards))
 
 
-def lm_config_from_json(path: Optional[str]) -> LMConfig:
-    """``LMConfig`` from a JSON file's ``model`` object (or its top
-    level); ``None`` gives the published model whole."""
+def lm_config_from_json(path: Optional[str],
+                        model_family: str = "lfm2_moe"):
+    """A token family's config (the row's ``config_cls``) from a JSON
+    file's ``model`` object (or its top level); ``None`` gives the
+    published model whole."""
+    config_cls = family_of(model_family).config_cls
     if path is None:
-        return LMConfig()
+        return config_cls()
     import json
     with open(path) as f:
         keys = json.load(f)
     keys = dict(keys.get("model", keys))
     if "layer_types" in keys:
         keys["layer_types"] = tuple(keys["layer_types"])
-    return LMConfig(**keys)
+    return config_cls(**keys)
 
 
 def main(argv=None):
@@ -461,20 +461,25 @@ def main(argv=None):
     parser.add_argument("--model_family", default="raft",
                         choices=list(FAMILIES),
                         help="canonical RAFT, the fork's sparse-keypoint "
-                             "(ours) family, or lfm2_moe: the LFM2-MoE "
-                             "language model "
-                             "(gated short convolutions, grouped-query "
-                             "attention, sigmoid-routed experts) on packed "
-                             "token sequences; see --lm_config, --seq_len")
+                             "(ours) family, or a language model on packed "
+                             "token sequences: lfm2_moe (LFM2-MoE: gated "
+                             "short convolutions, grouped-query "
+                             "attention, sigmoid-routed experts) or "
+                             "granitemoehybrid (Granite-4.0-H: Mamba-2 "
+                             "state-space layers 9:1 with attention "
+                             "without positions, scaled residual path); "
+                             "see --lm_config, --seq_len")
     parser.add_argument("--lm_config", default=None,
-                        help="lfm2_moe only: a JSON file whose `model` "
-                             "object (or top level) holds LMConfig keys: "
-                             "the published sizes and this chip's share "
-                             "(experts_held, expert_offset, vocab_held), "
-                             "e.g. benchmark/configs/lfm2_24b_a2b.json; "
+                        help="token families only: a JSON file whose "
+                             "`model` object (or top level) holds the "
+                             "family's config keys: the published sizes "
+                             "and this chip's share (vocab_held; lfm2_moe: "
+                             "experts_held, expert_offset), e.g. "
+                             "benchmark/configs/lfm2_24b_a2b.json, "
+                             "benchmark/configs/granite_4_0_h_micro.json; "
                              "default: the published 40-layer model whole")
     parser.add_argument("--seq_len", type=int, default=8192,
-                        help="lfm2_moe only: tokens a packed sequence "
+                        help="token families only: tokens a packed sequence "
                              "(--batch_size counts sequences; --data_root "
                              "names an optional .npz token file with "
                              "`tokens` and document `offsets`, else the "
@@ -561,7 +566,8 @@ def main(argv=None):
     family = family_of(args.model_family)
     tokens = family.tokens
     if not tokens and args.lm_config:
-        parser.error("--lm_config applies to the lfm2_moe family only")
+        parser.error("--lm_config applies to the token families only "
+                     "(lfm2_moe, granitemoehybrid)")
     if tokens and (args.validation or args.spatial_shards != 1
                    or args.restore_ckpt):
         parser.error("--validation, --spatial_shards and --restore_ckpt "
@@ -593,7 +599,7 @@ def main(argv=None):
         val_freq=args.val_freq, scheduler=args.scheduler, seed=args.seed,
         async_checkpointing=args.async_ckpt)
     if tokens:
-        mcfg = lm_config_from_json(args.lm_config)
+        mcfg = lm_config_from_json(args.lm_config, args.model_family)
     else:
         mcfg = RAFTConfig(
             small=args.small, dropout=args.dropout, iters=iters,

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from raft_tpu.config import LMConfig, OursConfig, RAFTConfig, TrainConfig
+from raft_tpu.config import (GraniteHybridConfig, LMConfig, OursConfig,
+                             RAFTConfig, TrainConfig)
 from raft_tpu.families import FAMILIES, FLOW_FAMILIES, family_of
 from raft_tpu.parallel import create_train_state, make_train_step
 
@@ -103,7 +104,8 @@ def test_the_family_tuples_are_computed_from_the_table():
     import raft_tpu.config as config
     assert FLOW_FAMILIES == tuple(
         name for name, row in FAMILIES.items() if not row.tokens)
-    assert set(FAMILIES) == {"raft", "sparse", "lfm2_moe"}
+    assert set(FAMILIES) == {"raft", "sparse", "lfm2_moe",
+                             "granitemoehybrid"}
     for gone in ("MODEL_FAMILIES", "TOKEN_FAMILIES", "FLOW_FAMILIES"):
         assert not hasattr(config, gone)
     with pytest.raises(ValueError, match="unknown model_family"):
@@ -127,10 +129,18 @@ _TINY_LM = LMConfig(
     expert_offset=2, vocab_held=64, mixed_precision=False)
 
 
+_TINY_SSM = GraniteHybridConfig(
+    hidden_size=64, shared_intermediate_size=96, num_hidden_layers=2,
+    layer_types=("mamba", "attention"), num_attention_heads=4,
+    num_key_value_heads=2, mamba_n_heads=8, mamba_d_head=16,
+    mamba_d_state=16, mamba_chunk_size=16, vocab_size=256, vocab_held=64)
+
+
 @pytest.mark.parametrize("name,mcfg,expects", [
     ("raft", RAFTConfig(small=True), "fnet"),
     ("sparse", RAFTConfig(), "query_embed"),
     ("lfm2_moe", _TINY_LM, "embed_tokens"),
+    ("granitemoehybrid", _TINY_SSM, "embed_tokens"),
 ])
 def test_a_row_builds_its_model_and_its_init_inputs_fit(name, mcfg,
                                                         expects):
@@ -147,6 +157,22 @@ def test_a_row_builds_its_model_and_its_init_inputs_fit(name, mcfg,
                            **kwargs))
     assert expects in shapes["params"]
     assert args[0].ndim == (2 if row.tokens else 4)
+    # a token row names its config dataclass and the counters its step
+    # reports; an image row has neither
+    assert (row.config_cls is type(mcfg)) == row.tokens
+    assert bool(row.step_counters) == row.tokens
+
+
+def test_the_steps_counters_are_the_rows():
+    """``train()`` puts on a step's span what the family's row lists;
+    no module holds one tuple for every token family."""
+    import raft_tpu.train as train
+    assert not hasattr(train, "STEP_COUNTERS")
+    assert family_of("lfm2_moe").step_counters == (
+        "tokens", "routed_here", "expert_load_max", "dropped")
+    assert family_of("granitemoehybrid").step_counters == (
+        "tokens", "ssm_resets", "ssd_chunks")
+    assert family_of("raft").step_counters == ()
 
 
 @pytest.mark.parametrize("cli,argv,offers", [
