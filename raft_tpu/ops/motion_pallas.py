@@ -18,11 +18,15 @@ Design (the gru_pallas playbook, full-2-D edition)
 * **2-D convs as shifted MXU matmuls.** On the flattened ``(rows, C)``
   tile a ``(K, K)`` conv is, per tap ``(dy, dx)``, one
   ``(rows, Cin) @ (Cin, Cout)`` matmul of the input shifted by
-  ``dy*W + dx`` flattened rows — 9 taps for the 3x3s, 49 for the 7x7 —
+  ``dy*W + dx`` flattened rows — 9 taps for the 3x3s —
   masked by the *combined* column validity (``col + dx ∈ [0, W)``) and
   global-row validity (``row + dy ∈ [0, H)``), exactly reproducing the
   convs' zero padding. ``convc1`` is 1x1: a single unshifted, unmasked
-  matmul.
+  matmul. The 7x7 on the 2-channel flow is ONE matmul too: its 49
+  shifted, masked copies of the flow are laid side by side on the lane
+  axis (``flow_patches``) and contracted with the tap-major weights at
+  once — a tap of two channels would otherwise stream the whole span
+  through the MXU for two rows of weights, 49 times.
 * **Both output concats killed by weight packing.** The fusing ``conv``
   reads ``concat([cor, flo])``; its kernel is pre-split into ``cor``-
   and ``flo``-input row slices (``pack_weights`` — ``_concat_conv`` in
@@ -103,6 +107,10 @@ _ROW_LADDER = (16, 8, 4)
 # lanes; a wider window is uncalibrated and keeps the conv path.
 _MAX_CORR_CHANNELS = 384
 
+# Lanes of convf1's patch operand: its 49 taps x 2 flow channels, padded
+# to one MXU contraction slice.
+_PATCH_LANES = 128
+
 
 # ---------------------------------------------------------------------------
 # Weight packing (the _concat_conv weight-merge idea, kernel-shaped)
@@ -160,6 +168,98 @@ def pack_weights(convc1, convc2, convf1, convf2, conv):
 
 
 # ---------------------------------------------------------------------------
+# Conv helpers shared with the fused step kernel (ops/step_pallas.py)
+# ---------------------------------------------------------------------------
+
+def tap_valid(col, grow, w: int, h_img: int, dy: int, dx: int):
+    """Whether the source of tap ``(dy, dx)`` lies inside the image, per
+    flattened row: ``col + dx in [0, W)`` and ``grow + dy in [0, H)`` —
+    the convs' zero padding, and what neutralizes clamped halo rows."""
+    cd = col + dx
+    gr = grow + dy
+    return (cd >= 0) & (cd < w) & (gr >= 0) & (gr < h_img)
+
+
+def conv_taps(valid, ops, b_ref, ksize: int, w: int):
+    """One spatial conv on the flattened ``(rows, C)`` span: the sum over
+    ``(dy, dx)`` taps of shifted-masked MXU matmuls, summed across the
+    input operands (the fusing conv has two — its concat killed by the
+    weight split); f32 accumulation, compute-dtype bias add (the flax
+    Conv contract). ``valid(dy, dx)`` is the tap's ``tap_valid``."""
+    r = ksize // 2
+    cdt = b_ref.dtype
+    acc = jnp.zeros((ops[0][0].shape[0], b_ref.shape[1]), jnp.float32)
+    t = 0
+    for dy in range(-r, r + 1):
+        for dx in range(-r, r + 1):
+            mk = valid(dy, dx).astype(cdt)
+            for v, w_ref in ops:
+                cin = v.shape[1]
+                acc += jax.lax.dot_general(
+                    _shift_rows(v, dy * w + dx) * mk,
+                    w_ref[t * cin:(t + 1) * cin, :],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            t += 1
+    return acc.astype(cdt) + b_ref[...]
+
+
+def kernel_mats(mats):
+    """``pack_weights`` output as the kernels take it: ``wf1 (49*2, F1)``
+    zero-padded to one whole 128-row contraction slice, the right-hand
+    side of ``flow_conv7``; the rest as packed."""
+    mats = list(mats)
+    mats[4] = jnp.pad(mats[4],
+                      ((0, _PATCH_LANES - mats[4].shape[0]), (0, 0)))
+    return mats
+
+
+def flow_patches(fac, col, grow, w: int, h_img: int):
+    """The left-hand side of ``flow_conv7``: a ``(rows, 128)`` operand
+    whose lane ``2*t + c`` holds channel ``c`` of the flow shifted by tap
+    ``t``'s ``dy*W + dx`` rows, zero where ``tap_valid`` fails — the 49
+    shifted, masked copies side by side (lanes 98.. are zero, against
+    ``kernel_mats``' zero rows). Built separably: rows outside the image
+    are zeroed at the source (a tap with a valid column reads the row
+    ``dy`` below its own, so that is the tap's row mask), the flow is
+    broadcast over the lanes by channel parity, each lane takes its own
+    ``dx`` shift under that shift's column mask (7 row shifts), then its
+    own ``dy`` shift by whole image rows (7 more). Every value is exactly
+    the per-tap ``_shift_rows(fac, dy*W + dx) * mask``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _PATCH_LANES), 1)
+    tap = lane // 2
+    ldy = tap // 7 - 3          # lanes past tap 48 read 4: no dy matches
+    ldx = tap - (tap // 7) * 7 - 3
+    # Assembled in 32 bits (exact for a compute-dtype flow; Mosaic has no
+    # relayout for a row mask and a lane mask meeting on packed bf16).
+    src = jnp.where((grow >= 0) & (grow < h_img),
+                    fac.astype(jnp.float32), 0.0)
+    ft = jnp.where(lane - tap * 2 == 0, src[:, 0:1], src[:, 1:2])
+    zero = jnp.zeros_like(ft)
+    gx = zero
+    for dx in range(-3, 4):
+        cd = col + dx
+        gx = jnp.where((cd >= 0) & (cd < w) & (ldx == dx),
+                       _shift_rows(ft, dx), gx)
+    patches = zero
+    for dy in range(-3, 4):
+        patches = jnp.where(ldy == dy, _shift_rows(gx, dy * w), patches)
+    return patches.astype(fac.dtype)
+
+
+def flow_conv7(fac, wf1p_ref, b_ref, col, grow, w: int, h_img: int):
+    """``convf1`` (7x7 on the 2-channel flow) as ONE contraction over its
+    98 tap-channels instead of 49 products with K = 2, each of which
+    streams the whole span through the MXU for two rows of weights.
+    Against the per-tap sum only the order of the 98 f32 partial sums
+    changes (inside one product instead of across 49)."""
+    return jax.lax.dot_general(
+        flow_patches(fac, col, grow, w, h_img), wf1p_ref[...],
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(fac.dtype) + b_ref[...]
+
+
+# ---------------------------------------------------------------------------
 # Kernel
 # ---------------------------------------------------------------------------
 
@@ -199,33 +299,11 @@ def _motion_kernel(*refs, w: int, h_img: int, th: int):
     col = ri - (ri // w) * w
     grow = ti * th - _HALO + ri // w
 
-    def mask(dy, dx):
-        cd = col + dx
-        gr = grow + dy
-        return ((cd >= 0) & (cd < w)
-                & (gr >= 0) & (gr < h_img)).astype(cdt)
+    def valid(dy, dx):
+        return tap_valid(col, grow, w, h_img, dy, dx)
 
     def conv2d(ops, b_ref, ksize):
-        """One spatial conv: Σ over (dy, dx) taps of shifted-masked
-        matmuls, summed across the input operands (the fusing conv has
-        two — its concat killed by the weight split); f32 accumulation,
-        compute-dtype bias add (the flax Conv contract)."""
-        r = ksize // 2
-        nout = b_ref.shape[1]
-        acc = jnp.zeros((rows, nout), jnp.float32)
-        t = 0
-        for dy in range(-r, r + 1):
-            for dx in range(-r, r + 1):
-                mk = mask(dy, dx)
-                for v, w_ref in ops:
-                    cin = v.shape[1]
-                    acc += jax.lax.dot_general(
-                        _shift_rows(v, dy * w + dx) * mk,
-                        w_ref[t * cin:(t + 1) * cin, :],
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                t += 1
-        return acc.astype(cdt) + b_ref[...]
+        return conv_taps(valid, ops, b_ref, ksize, w)
 
     # Corr branch: 1x1 is one unshifted matmul (no padding geometry);
     # garbage on out-of-image assembly rows is masked by convc2's taps.
@@ -237,7 +315,8 @@ def _motion_kernel(*refs, w: int, h_img: int, th: int):
     # Flow branch: convs read the compute-dtype cast; the passthrough
     # below reads fa uncast (the conv path leaves flow untouched).
     fac = fa.astype(cdt)
-    flo = jax.nn.relu(conv2d([(fac, wf1_ref)], bf1_ref, 7))
+    flo = jax.nn.relu(flow_conv7(fac, wf1_ref, bf1_ref, col, grow, w,
+                                 h_img))
     flo = jax.nn.relu(conv2d([(flo, wf2_ref)], bf2_ref, 3))
 
     # Fusing conv over [cor ‖ flo] without the concat, then the direct
@@ -268,6 +347,7 @@ def _pallas_motion(static, flow2d, corr2d, mats):
 
     kernel = functools.partial(_motion_kernel, w=w, h_img=h_img, th=th)
     nb = -(-_HALO // th)
+    mats = kernel_mats(mats)
 
     def neighbor_specs(channels):
         return [pl.BlockSpec(
@@ -277,8 +357,7 @@ def _pallas_motion(static, flow2d, corr2d, mats):
 
     in_specs = (neighbor_specs(cc) + neighbor_specs(cf)
                 + [_full_spec(m) for m in mats])
-    operands = ([corr2d] * (2 * nb + 1) + [flow2d] * (2 * nb + 1)
-                + list(mats))
+    operands = [corr2d] * (2 * nb + 1) + [flow2d] * (2 * nb + 1) + mats
     # Layout-contract invariant 6: the [out ‖ flow] emission is the
     # GRU's packed x part, declared as a handoff.
     out_specs, out_shape = klayout.handoff_tiled_out(b, n, co + cf, g,
@@ -397,7 +476,7 @@ def choose_rows(h_img: int, w: int, cc: int,
     fits the admission budget and whose flattened tile is
     sublane-aligned (vmem.choose_rows). None → no admissible tile
     (auto falls back to the conv path). At Sintel eval shapes (H=55,
-    W=128, Ccorr=324) bf16 admits th=16 and f32 th=8 under the explicit
+    W=128, Ccorr=324) both dtypes admit th=16 under the explicit
     100 MiB limit — asserted in tests/test_motion_pallas.py and compiled
     for the chip in tests/test_chip_compile.py."""
     return vmem.choose_rows(

@@ -23,8 +23,17 @@ Two fusion depths, chosen by admission (``plan_fusion``):
   whose ``_concat_conv`` stays on the XLA side, and whenever the flow
   head pushes the estimate over budget.
 * ``'mgf'`` — + the flow head's two 3x3 convs, emitting ``(h2, delta)``
-  as two outputs. Admissible at smaller shapes; at Sintel bf16 the
-  ladder honestly rejects it and falls to ``'mg'``.
+  as two outputs. Admitted wherever ``'mg'`` is at bf16 (Sintel, chairs
+  and KITTI feature maps); at f32 it costs a rung or the depth.
+
+No tap of a two-channel conv gets an MXU pass of its own: ``convf1``
+(7x7 on the 2-channel flow) is one contraction over its 98 tap-channels
+(``motion_pallas.flow_conv7``, shared with the stand-alone motion
+kernel) and the flow head's last conv (3x3, 256 -> 2) is one product
+with its nine taps on the output axis (``folded_head_conv``). As 49
+products with K = 2 and 9 with N = 2 they were a quarter of the passes
+the kernel streams and, by their per-tap temporaries, a third of its
+VMEM.
 
 Halos compose across the chain: the GRU's SepConv pair needs ±4 rows
 of valid *x* (and the flow head another ±2 of valid ``h2``), and the
@@ -43,10 +52,9 @@ VMEM admission is ``step_vmem_parts`` — Mosaic's calibrated per-row
 figure over the motion span, which is this kernel's peak
 (``vmem.scan_rows_parts``) — under the shared ``vmem.choose_rows``
 ladder ``(16, 8, 4)`` and the explicit 100 MiB limit; at Sintel shapes
-bf16 admits TH=8 at both depths and f32 admits ``'mg'`` at TH=4 only. A
-shape the ladder rejects
-(e.g. W=240 at 1080p, either depth) falls back, loudly logged, to the
-two-launch chain, never silently.
+bf16 admits TH=16 at both depths and f32 TH=8 at both. A shape the
+ladder rejects (e.g. W=240 at 1080p, either depth) falls back, loudly
+logged, to the two-launch chain, never silently.
 
 The custom VJP recomputes through the identical-math jnp twin
 (``reference_motion`` → ``reference_gru`` → flow-head taps); a fused
@@ -75,8 +83,9 @@ from raft_tpu.ops.gru_pallas import (_TAPS, _bshift, _flatten_mats,
                                      gate_sigmoid, halo_assemble,
                                      split_x_weights)
 from raft_tpu.ops.gru_pallas import reference_gru
-from raft_tpu.ops.motion_pallas import (_MAX_CORR_CHANNELS,
-                                        reference_motion)
+from raft_tpu.ops.motion_pallas import (_MAX_CORR_CHANNELS, conv_taps,
+                                        flow_conv7, kernel_mats,
+                                        reference_motion, tap_valid)
 from raft_tpu.utils.envflags import STEP_FLAG, resolve_step_pallas
 
 # Per-stage receptive-field depths (rows each side). The GRU needs its
@@ -88,7 +97,7 @@ _HALO_GRU = 4
 _HALO_FLOW_HEAD = 2
 
 # Row-tile ladder for real launches (same rungs as the component
-# kernels; at Sintel bf16 only the TH=4 rung admits the fused step).
+# kernels; Sintel and chairs bf16 ride the top rung at both depths).
 _ROW_LADDER = (16, 8, 4)
 
 
@@ -121,6 +130,36 @@ def pack_flow_head(conv1, conv2):
     cin, fh = k1.shape[2], k1.shape[3]
     return (k1.reshape(9 * cin, fh), b1.reshape(1, fh),
             k2.reshape(9 * fh, 2), b2.reshape(1, 2))
+
+
+def fold_head_taps(wfh2):
+    """The flow head's second conv with its taps on the output axis:
+    tap-major ``(9*Fh, 2)`` rows as ``(Fh, 18)`` columns
+    ``[W_0 | ... | W_8]`` — the right-hand side of ``folded_head_conv``."""
+    fhid = wfh2.shape[0] // 9
+    return wfh2.reshape(9, fhid, 2).transpose(1, 0, 2).reshape(fhid, 18)
+
+
+def folded_head_conv(valid, v, wcat_ref, b_ref, w: int):
+    """The 3x3 ``Fh -> 2`` conv as ONE product ``v @ [W_0 | ... | W_8]``
+    and nine shifted, masked adds of two float32 columns each, instead
+    of nine products with N = 2 (each a whole pass of the span through
+    the MXU for two output columns). ``delta[p] = sum_t mask_t[p] *
+    (v[p + s_t] @ W_t)`` is the per-tap sum with the shift and mask
+    applied to the product's rows instead of the operand's: the same
+    float32 terms added in the same order, then the same cast and
+    compute-dtype bias add."""
+    taps = jax.lax.dot_general(
+        v, wcat_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    acc = jnp.zeros((v.shape[0], 2), jnp.float32)
+    t = 0
+    for dy in (-1, 0, 1):
+        for dx in (-1, 0, 1):
+            acc += (_shift_rows(taps[:, 2 * t:2 * t + 2], dy * w + dx)
+                    * valid(dy, dx).astype(jnp.float32))
+            t += 1
+    return acc.astype(v.dtype) + b_ref[...]
 
 
 # ---------------------------------------------------------------------------
@@ -173,43 +212,21 @@ def _step_kernel(*refs, w: int, h_img: int, th: int, fh: bool):
     colm = rim - (rim // w) * w
     growm = ti * th - hm + rim // w
 
-    def conv2d(mask, ops, b_ref, ksize):
-        """One spatial conv as shifted-masked MXU matmuls (the
-        motion/flow-head taps); f32 accumulation, compute-dtype bias
-        add — the flax Conv contract, identical to the component
-        kernels tap for tap."""
-        r = ksize // 2
-        nrows = ops[0][0].shape[0]
-        nout = b_ref.shape[1]
-        acc = jnp.zeros((nrows, nout), jnp.float32)
-        t = 0
-        for dy in range(-r, r + 1):
-            for dx in range(-r, r + 1):
-                mk = mask(dy, dx)
-                for v, w_ref in ops:
-                    cin = v.shape[1]
-                    acc += jax.lax.dot_general(
-                        _shift_rows(v, dy * w + dx) * mk,
-                        w_ref[t * cin:(t + 1) * cin, :],
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                t += 1
-        return acc.astype(cdt) + b_ref[...]
+    def mvalid(dy, dx):
+        return tap_valid(colm, growm, w, h_img, dy, dx)
 
-    def mmask(dy, dx):
-        cd = colm + dx
-        gr = growm + dy
-        return ((cd >= 0) & (cd < w)
-                & (gr >= 0) & (gr < h_img)).astype(cdt)
+    def conv2d(valid, ops, b_ref, ksize):
+        return conv_taps(valid, ops, b_ref, ksize, w)
 
     cor = jax.nn.relu(jax.lax.dot_general(
         ca, wc1_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(cdt) + bc1_ref[...])
-    cor = jax.nn.relu(conv2d(mmask, [(cor, wc2_ref)], bc2_ref, 3))
+    cor = jax.nn.relu(conv2d(mvalid, [(cor, wc2_ref)], bc2_ref, 3))
     fac = fa.astype(cdt)
-    flo = jax.nn.relu(conv2d(mmask, [(fac, wf1_ref)], bf1_ref, 7))
-    flo = jax.nn.relu(conv2d(mmask, [(flo, wf2_ref)], bf2_ref, 3))
-    out_m = jax.nn.relu(conv2d(mmask, [(cor, woc_ref), (flo, wof_ref)],
+    flo = jax.nn.relu(flow_conv7(fac, wf1_ref, bf1_ref, colm, growm, w,
+                                 h_img))
+    flo = jax.nn.relu(conv2d(mvalid, [(flo, wf2_ref)], bf2_ref, 3))
+    out_m = jax.nn.relu(conv2d(mvalid, [(cor, woc_ref), (flo, wof_ref)],
                                bo_ref, 3))
     # The handoff, fused away: [motion ‖ flow] sliced from the deep span
     # to the GRU (±hg) span — valid on every slice row by the masks
@@ -276,14 +293,11 @@ def _step_kernel(*refs, w: int, h_img: int, th: int, fh: bool):
     if fh:
         wfh1, bfh1, wfh2, bfh2 = fh_refs
 
-        def gmask(dy, dx):
-            cd = colg + dx
-            gr = growg + dy
-            return ((cd >= 0) & (cd < w)
-                    & (gr >= 0) & (gr < h_img)).astype(cdt)
+        def gvalid(dy, dx):
+            return tap_valid(colg, growg, w, h_img, dy, dx)
 
-        fh1 = jax.nn.relu(conv2d(gmask, [(h2, wfh1)], bfh1, 3))
-        delta = conv2d(gmask, [(fh1, wfh2)], bfh2, 3)
+        fh1 = jax.nn.relu(conv2d(gvalid, [(h2, wfh1)], bfh1, 3))
+        delta = folded_head_conv(gvalid, fh1, wfh2, bfh2, w)
         klayout.boundary_store(out_refs[1], delta[hw_g:hw_g + g])
 
 
@@ -313,8 +327,10 @@ def _pallas_step(static, net2d, inp2d, flow2d, corr2d, mmats, gmats,
                 (1, g, chn),
                 lambda bi, ti, k=k: (bi, jnp.clip(ti + k, 0, last), 0)))
             operands.append(arr)
-    flat_mats = (list(mmats) + list(_flatten_mats(gmats))
-                 + (list(fmats) if fh else []))
+    flat_mats = kernel_mats(mmats) + list(_flatten_mats(gmats))
+    if fh:
+        wfh1, bfh1, wfh2, bfh2 = fmats
+        flat_mats += [wfh1, bfh1, fold_head_taps(wfh2), bfh2]
     in_specs += [_full_spec(m) for m in flat_mats]
 
     spec_h, shape_h = klayout.query_tiled_out(b, n, c, g, net2d.dtype)
@@ -436,7 +452,7 @@ def choose_rows(h_img: int, w: int, cc: int, dtype_bytes: int, *,
     """Largest admissible row tile for one fused launch under the
     shared (16, 8, 4) ladder and ``step_vmem_parts``; None → this
     fusion depth doesn't fit (the caller steps down mgf → mg →
-    two-launch chain). At Sintel eval shapes bf16 admits TH=8 at both
+    two-launch chain). At Sintel eval shapes bf16 admits TH=16 at both
     depths; at 1080p (W=240) neither admits any — asserted in
     tests/test_step_pallas.py."""
     return vmem.choose_rows(

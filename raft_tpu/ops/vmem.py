@@ -62,21 +62,29 @@ SCAN_LIMIT_BYTES = 100 * 2 ** 20
 #: (``(TH + 2*halo) * W`` rows), by kernel and compute-dtype width, at the
 #: canonical RAFT-large widths (hidden 128, x 256, 324 corr channels).
 #: Read from ``used_scoped_memory_configs`` in the compiled text (jax
-#: 0.9.0 / libtpu 0.0.34, v5e) of compiles under a 1 GiB limit, batch 1-8,
+#: 0.9.0 / libtpu 0.0.34, v5e) of compiles under a 1 GiB limit, batch 2,
 #: TH in {4, 8, 16}, at 55x128 (Sintel), 46x62 (chairs), 48x156 (KITTI)
 #: and 135x240 (1080p). Mosaic's footprint is elastic — under a tighter
 #: limit the same kernel compiles into less, by an amount that varies
 #: with batch and surroundings — so the unlimited figure is the upper
 #: bound and the only safe thing to admit against. It is linear in the
 #: row span with no constant term, and bf16 costs most of what f32 does
-#: (the v5e VPU computes the elementwise tail in f32 either way). Largest
-#: observed, KiB/row: GRU 13.4 (bf16) / 19.3 (f32); motion 24.3 / 27.5;
-#: fused step, either depth, over its motion span 25.0 / 33.8 — rounded
-#: up, further where a width (W=62 in f32) was not probed.
+#: (the v5e VPU computes the elementwise tail in f32 either way). The
+#: motion and step figures were re-read when ``convf1`` and the flow
+#: head's last conv stopped taking an MXU pass a tap: the 49 + 9 per-tap
+#: temporaries had been a third of both (motion 24.3 / 27.5 and step
+#: 25.0 / 33.8 KiB/row before). Largest observed, KiB/row: GRU 13.4
+#: (bf16) / 19.3 (f32); motion 16.0 / 21.5; fused step, either depth,
+#: over its motion span 18.0 / 25.3 (both at W=62; 15.9 / 22.0 at
+#: Sintel, 13.8-15.4 at W=240) — rounded up, the bf16 step by a tenth:
+#: the narrowest probed width reads highest, and at W=240 the figure
+#: refuses a TH=4 'mg' tile that Mosaic fits in 71 MiB, where a tile
+#: keeps 4 rows of the 22 it computes and the two-launch chain is the
+#: better program anyway.
 _ROW_BYTES = {
     "gru": {2: 14 * 1024, 4: 24 * 1024},
-    "motion": {2: 26 * 1024, 4: 31 * 1024},
-    "step": {2: 26 * 1024, 4: 35 * 1024},
+    "motion": {2: 17 * 1024, 4: 23 * 1024},
+    "step": {2: 20 * 1024, 4: 26 * 1024},
 }
 
 

@@ -258,15 +258,15 @@ class TestEligibility:
     def test_sintel_admission_table(self):
         """The pinned envelope at Sintel-eval feature shapes (H=55,
         W=128, Ccorr=4*81=324) under the explicit 100 MiB scoped limit
-        and the Mosaic-calibrated estimate: bf16 rides the TH=16 rung
-        (Mosaic takes up to 73 MiB), f32 TH=8 (89 MiB at TH=16 is too
-        close); a 1080p-wide map steps down to TH=4; a 4K-wide map fits
-        no tile and falls back loudly (see the fallback-log test); a
-        corr window wider than the calibrated 384 lanes keeps the conv
-        path."""
+        and the Mosaic-calibrated estimate: both dtypes ride the TH=16
+        rung (Mosaic takes up to 52 / 66 MiB; 73 / 89 when convf1 was
+        49 products); a 1080p-wide map steps down to TH=8 (60 MiB; 96
+        at TH=16); a 4K-wide map fits no tile and falls back loudly
+        (see the fallback-log test); a corr window wider than the
+        calibrated 384 lanes keeps the conv path."""
         assert motion_pallas.choose_rows(55, 128, 324, 2) == 16
-        assert motion_pallas.choose_rows(55, 128, 324, 4) == 8
-        assert motion_pallas.choose_rows(135, 240, 324, 2) == 4
+        assert motion_pallas.choose_rows(55, 128, 324, 4) == 16
+        assert motion_pallas.choose_rows(135, 240, 324, 2) == 8
         assert motion_pallas.choose_rows(55, 512, 324, 4) is None
         assert motion_pallas.motion_eligible(55, 128, 324, jnp.bfloat16,
                                              False)
@@ -278,16 +278,17 @@ class TestEligibility:
                                                  jnp.bfloat16, False)
 
     @pytest.mark.parametrize("th,dtype_bytes,mosaic_mib", [
-        (16, 2, 72.98), (8, 2, 47.49), (4, 2, 28.33), (8, 4, 40.59)])
+        (16, 2, 51.96), (8, 2, 33.22), (4, 2, 26.34), (8, 4, 41.70),
+        (16, 4, 65.70)])
     def test_estimate_covers_what_mosaic_reported(self, th, dtype_bytes,
                                                   mosaic_mib):
         """The phase-peak estimate admitted the Sintel bf16 TH=16 tile
         at 11.7 MiB under a 13 MiB budget; Mosaic put 53.8 MiB on its
-        stack and refused it under the 16 MiB default (and takes 73 MiB
-        when nothing limits it). The calibrated estimate is at least
-        what the compiler reported at every probed tile, and the tile
-        is now admitted only because the launch carries the explicit
-        limit."""
+        stack and refused it under the 16 MiB default. The calibrated
+        estimate is at least what the compiler reported (for the
+        present body, compiled for a described v5e under a 1 GiB limit)
+        at every probed tile, and the tile is admitted only because the
+        launch carries the explicit limit."""
         est = vmem.total_bytes(
             motion_pallas.motion_vmem_parts(55, 128, 324, th, dtype_bytes))
         assert mosaic_mib * 2**20 <= est <= vmem.SCAN_LIMIT_BYTES

@@ -15,8 +15,11 @@ three levels:
 * **dispatch contract** (``RAFT_STEP_PALLAS``): '0' byte-identical,
   '1' forced (raises on TPU when inadmissible), auto fuses only on TPU
   with a LOUD logged fallback; plus the pinned VMEM admission table at
-  the Sintel-eval operating point (phase-peak liveness model —
-  bf16 admits TH=4 for 'mg' only; f32 admits nothing).
+  the Sintel-eval operating point (Mosaic's own figure for the body
+  without two-channel taps: bf16 admits TH=16 and f32 TH=8, at both
+  depths).
+* **the pass census**: from the traced program, that no product with a
+  contraction or an output of 2 is left, and what a span row streams.
 """
 
 import logging
@@ -76,6 +79,56 @@ def update_setup():
     return model, vs, net, inp, corr, flow
 
 
+def _tile_span(rng, th, ti, halo, chans, dtype=jnp.float32):
+    """A kernel tile's working span as ``halo_assemble`` leaves it at
+    image shape (H, W): ``th + 2*halo`` rows whose rows outside the image
+    hold garbage (what a clamped neighbour block brings), with the
+    span's ``col`` and global-row vectors. ``ti`` picks the tile, so the
+    first, an inner and the last one put every edge of the masks to
+    work."""
+    rows = (th + 2 * halo) * W
+    ri = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    col = ri % W
+    grow = ti * th - halo + ri // W
+    v = jnp.asarray(3.0 * rng.standard_normal((rows, chans)), dtype)
+    return v, col, grow
+
+
+def _dot_generals(jaxpr):
+    """Every ``dot_general`` under ``jaxpr`` (kernel bodies included),
+    as ``(rows, K, N)``."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (lc, rc), _ = eqn.params["dimension_numbers"]
+            lhs, rhs = (v.aval.shape for v in eqn.invars)
+            assert len(lhs) == len(rhs) == 2 and lc == (1,) and rc == (0,)
+            out.append((lhs[0], lhs[1], rhs[1]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _dot_generals(sub)
+    return out
+
+
+def _real_width_mats():
+    """The three packers' outputs at RAFT-large widths (324 corr
+    channels), as shapes only."""
+    def z(*shape):
+        return jnp.zeros(shape, jnp.float32)
+
+    def build():
+        mm = motion_pallas.pack_weights(
+            (z(1, 1, 324, 256), z(256)), (z(3, 3, 256, 192), z(192)),
+            (z(7, 7, 2, 128), z(128)), (z(3, 3, 128, 64), z(64)),
+            (z(3, 3, 256, CO), z(CO)))
+        gm = gru_pallas.pack_weights(
+            tuple((z(1, 5, 3 * C, C), z(C)) for _ in range(3)),
+            tuple((z(5, 1, 3 * C, C), z(C)) for _ in range(3)), C)
+        fm = step_pallas.pack_flow_head((z(3, 3, C, 256), z(256)),
+                                        (z(3, 3, 256, 2), z(2)))
+        return mm, gm, fm
+    return jax.eval_shape(build)
+
+
 class TestForwardParity:
     @pytest.mark.parametrize("th", [4, 5, 8])
     @pytest.mark.parametrize("fh", [False, True])
@@ -97,6 +150,63 @@ class TestForwardParity:
         got_h2 = out[0] if fh else out
         np.testing.assert_array_equal(np.asarray(got_h2),
                                       np.asarray(want_h2))
+
+    @pytest.mark.parametrize("th,ti", [(4, 0), (4, 1), (4, 2), (5, 1),
+                                       (8, 0), (8, 1)])
+    def test_packed_convf1_matches_the_49_tap_sum(self, th, ti):
+        """``convf1`` as one contraction over its 98 tap-channels vs the
+        49 shifted-masked K = 2 products it replaces, at float32 on one
+        tile's span (odd W, H not a tile multiple, first / inner / last
+        tile, out-of-image rows holding garbage). The patch operand is
+        the 49 masked copies side by side, value for value; the product
+        differs only by the order of 98 float32 partial sums (measured
+        over these six cases: at most 6.7e-6 on outputs of magnitude up
+        to 18, four parts in ten million; asserted at three times
+        that)."""
+        rng = np.random.default_rng(10 * th + ti)
+        hm = step_pallas.halos(True)[1]
+        fac, col, grow = _tile_span(rng, th, ti, hm, 2)
+        wf1 = jnp.asarray(rng.standard_normal((98, 128)) / 7.0,
+                          jnp.float32)
+        bf1 = jnp.asarray(rng.standard_normal((1, 128)), jnp.float32)
+
+        def valid(dy, dx):
+            return motion_pallas.tap_valid(col, grow, W, H, dy, dx)
+
+        patches = motion_pallas.flow_patches(fac, col, grow, W, H)
+        copies = [motion_pallas._shift_rows(fac, dy * W + dx)
+                  * valid(dy, dx).astype(jnp.float32)
+                  for dy in range(-3, 4) for dx in range(-3, 4)]
+        np.testing.assert_array_equal(
+            np.asarray(patches),
+            np.asarray(jnp.concatenate(
+                copies + [jnp.zeros((fac.shape[0], 30))], axis=1)))
+        want = motion_pallas.conv_taps(valid, [(fac, wf1)], bf1, 7, W)
+        got = motion_pallas.flow_conv7(
+            fac, jnp.pad(wf1, ((0, 30), (0, 0))), bf1, col, grow, W, H)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=0)
+
+    @pytest.mark.parametrize("th,ti", [(4, 0), (4, 1), (4, 2), (8, 1)])
+    def test_folded_head_conv_is_the_9_tap_sum(self, th, ti):
+        """The flow head's 3x3 ``256 -> 2`` conv as one product and nine
+        shifted, masked adds of its float32 columns vs the nine N = 2
+        products it replaces: the same terms in the same order, so not a
+        bit moves."""
+        rng = np.random.default_rng(20 * th + ti)
+        hg = step_pallas.halos(True)[0]
+        fh1, col, grow = _tile_span(rng, th, ti, hg, 256)
+        wfh2 = jnp.asarray(rng.standard_normal((9 * 256, 2)) / 48.0,
+                           jnp.float32)
+        bfh2 = jnp.asarray(rng.standard_normal((1, 2)), jnp.float32)
+
+        def valid(dy, dx):
+            return motion_pallas.tap_valid(col, grow, W, H, dy, dx)
+
+        want = motion_pallas.conv_taps(valid, [(fh1, wfh2)], bfh2, 3, W)
+        got = step_pallas.folded_head_conv(
+            valid, fh1, step_pallas.fold_head_taps(wfh2), bfh2, W)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_mgf_delta_matches_conv_flow_head(self, update_setup):
         """The in-kernel flow head vs the flax FlowHead on the SAME h2
@@ -228,6 +338,58 @@ class TestGradParity:
                                        atol=2e-4, rtol=0)
 
 
+class TestPassCensus:
+    """The mechanism's counter, read from the program itself: what the
+    kernel streams through the MXU, in pass units of (span row) x
+    (128-wide slice of the contraction) x (128-wide slice of the
+    output), at RAFT-large widths."""
+
+    MOTION_UNITS = 79     # 6 + 36 + 1 (convf1: 49 before) + 9 + 27
+    GRU_UNITS = 90
+    HEAD_UNITS = 20       # 18 + 2 (conv2: 18 before)
+
+    @staticmethod
+    def _units(dots, rows):
+        return sum(-(-k // 128) * -(-n // 128)
+                   for r, k, n in dots if r == rows)
+
+    @pytest.mark.parametrize("th", [4, 8])
+    @pytest.mark.parametrize("fh", [False, True])
+    def test_no_two_channel_tap_gets_a_pass_of_its_own(self, fh, th):
+        mm, gm, fm = _real_width_mats()
+
+        def sds(c):
+            return jax.ShapeDtypeStruct((1, H, W, c), jnp.bfloat16)
+
+        jaxpr = jax.make_jaxpr(
+            lambda n, i, c, f, mm, gm, fm: step_pallas.fused_step(
+                n, i, c, f, mm, gm, fm if fh else None,
+                dtype=jnp.bfloat16, interpret=True, th=th))(
+            sds(C), sds(C), sds(324), sds(2), mm, gm, fm)
+        dots = _dot_generals(jaxpr.jaxpr)
+        assert all(k > 2 and n > 2 for _, k, n in dots), dots
+        # 38 motion + 60 GRU (+ 10 flow head); 86 + 60 (+ 18) before
+        assert len(dots) == (108 if fh else 98)
+        hg, hm = step_pallas.halos(fh)
+        rows_m, rows_g = (th + 2 * hm) * W, (th + 2 * hg) * W
+        assert {r for r, _, _ in dots} == {rows_m, rows_g}
+        assert self._units(dots, rows_m) == self.MOTION_UNITS
+        assert self._units(dots, rows_g) == self.GRU_UNITS + (
+            self.HEAD_UNITS if fh else 0)
+
+    def test_chained_motion_kernel_shares_the_packing(self):
+        mm, _, _ = _real_width_mats()
+        jaxpr = jax.make_jaxpr(
+            lambda f, c, mm: motion_pallas.motion_encoder(
+                f, c, mm, dtype=jnp.bfloat16, interpret=True, th=4))(
+            jax.ShapeDtypeStruct((1, H, W, 2), jnp.float32),
+            jax.ShapeDtypeStruct((1, H, W, 324), jnp.bfloat16), mm)
+        dots = _dot_generals(jaxpr.jaxpr)
+        assert all(k > 2 and n > 2 for _, k, n in dots), dots
+        assert len(dots) == 38
+        assert self._units(dots, (4 + 10) * W) == self.MOTION_UNITS
+
+
 class TestDispatch:
     def test_flag_off_is_bitexact(self, update_setup, monkeypatch):
         """RAFT_STEP_PALLAS=0 and unset-on-CPU (auto) both take the
@@ -256,20 +418,23 @@ class TestDispatch:
         assert plan(net, inp, corr, flow, True) is None
 
     def test_auto_on_tpu_steps_down_mgf_to_mg(self, monkeypatch):
-        """Sintel-eval f32 on a (faked) TPU backend: the flow-head
-        depth doesn't fit, so auto honestly steps down to 'mg' instead
-        of rejecting fusion outright; Sintel bf16 admits 'mgf'."""
+        """KITTI f32 (48x156 features) on a (faked) TPU backend: the
+        flow-head depth fits no tile, so auto honestly steps down to
+        'mg' instead of rejecting fusion outright; Sintel admits 'mgf'
+        in either dtype."""
         monkeypatch.delenv("RAFT_STEP_PALLAS", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-        def sds(c, dtype):
-            return jax.ShapeDtypeStruct((1, 55, 128, c), dtype)
+        def sds(hw, dtype):
+            return tuple(jax.ShapeDtypeStruct((1, *hw, c), dtype)
+                         for c in (C, C, 324, 2))
 
-        args = tuple(sds(c, jnp.float32) for c in (C, C, 324, 2))
-        assert step_pallas.plan_fusion(*args, True) == "mg"
-        assert step_pallas.plan_fusion(*args, False) == "mg"
-        half = tuple(sds(c, jnp.bfloat16) for c in (C, C, 324, 2))
-        assert step_pallas.plan_fusion(*half, True) == "mgf"
+        kitti = sds((48, 156), jnp.float32)
+        assert step_pallas.plan_fusion(*kitti, True) == "mg"
+        assert step_pallas.plan_fusion(*kitti, False) == "mg"
+        for dtype in (jnp.float32, jnp.bfloat16):
+            assert step_pallas.plan_fusion(*sds((55, 128), dtype),
+                                           True) == "mgf"
 
     def test_forced_bad_shape_raises(self, update_setup):
         _, _, net, inp, corr, _ = update_setup
@@ -376,31 +541,37 @@ class TestEligibility:
     def test_sintel_admission_table(self):
         """The pinned envelope at Sintel-eval feature shapes (H=55,
         W=128, Ccorr=4*81=324) under the explicit 100 MiB scoped limit
-        and the Mosaic-calibrated estimate: bf16 admits TH=8 at both
-        depths (Mosaic takes up to 72.5 / 82.8 MiB there, 95 / 106 MiB
-        at TH=16); f32 admits 'mg' at TH=4 only; at 1080p (W=240)
-        nothing admits — auto steps down / falls back (logged) rather
-        than OOM Mosaic."""
-        assert step_pallas.choose_rows(55, 128, 324, 2) == 8
+        and the Mosaic-calibrated estimate: bf16 admits TH=16 at both
+        depths (Mosaic takes up to 67.7 / 75.2 MiB there; with the 49
+        two-channel taps it took 95 / 106 and TH=8 was the rung); f32
+        admits TH=8 at both (71.6 / 79.4 MiB; 93.6 / 102.4 at TH=16);
+        KITTI bf16 (48x156) TH=8 at both; at 1080p (W=240) nothing
+        admits — auto steps down / falls back (logged) rather than OOM
+        Mosaic."""
+        assert step_pallas.choose_rows(55, 128, 324, 2) == 16
         assert step_pallas.choose_rows(55, 128, 324, 2,
-                                       flow_head=True) == 8
-        assert step_pallas.choose_rows(55, 128, 324, 4) == 4
+                                       flow_head=True) == 16
+        assert step_pallas.choose_rows(55, 128, 324, 4) == 8
         assert step_pallas.choose_rows(55, 128, 324, 4,
-                                       flow_head=True) is None
+                                       flow_head=True) == 8
+        assert step_pallas.choose_rows(46, 62, 324, 2) == 16
+        assert step_pallas.choose_rows(48, 156, 324, 2,
+                                       flow_head=True) == 8
         assert step_pallas.choose_rows(135, 240, 324, 2) is None
 
     @pytest.mark.parametrize("th,dtype_bytes,flow_head,mosaic_mib", [
-        (4, 2, False, 45.40), (8, 2, False, 72.51),
-        (8, 2, True, 82.75), (16, 2, True, 106.01),
-        (4, 4, False, 83.47), (16, 4, False, 111.47)])
+        (4, 2, False, 40.18), (8, 2, True, 57.50),
+        (16, 2, False, 67.74), (16, 2, True, 75.23),
+        (8, 4, True, 79.39), (16, 4, True, 102.37)])
     def test_estimate_covers_what_mosaic_reported(self, th, dtype_bytes,
                                                   flow_head, mosaic_mib):
         """The phase-peak estimate admitted Sintel bf16 'mg' TH=4 at
-        12.8 MiB under a 13 MiB budget; Mosaic needs 45.4 MiB. The
-        calibrated estimate is at least what the compiler reported at
-        every probed tile, and a tile Mosaic takes more than the limit
-        for (bf16 'mgf' TH=16 at 106 MiB, f32 'mg' TH=16 at 111 MiB) is
-        no longer admitted."""
+        12.8 MiB under a 13 MiB budget where Mosaic needed 45.4 MiB.
+        The calibrated estimate is at least what the compiler reported
+        (for the present body, compiled for a described v5e under a
+        1 GiB limit) at every probed tile; the tiles the cells ride
+        (bf16 TH=8 and TH=16) are admitted, and a tile Mosaic takes more
+        than the limit for (f32 'mgf' TH=16 at 102.4 MiB) is not."""
         est = vmem.total_bytes(step_pallas.step_vmem_parts(
             128, th, dtype_bytes, flow_head=flow_head))
         assert est >= mosaic_mib * 2**20
@@ -415,17 +586,18 @@ class TestEligibility:
 
     def test_fused_step_preflights_real_launches(self, update_setup):
         """fused_step(interpret=False) trips the itemized VMEM
-        preflight before any pallas_call for an over-budget shape."""
+        preflight before any pallas_call for an over-budget shape (a
+        1080p-wide map: no rung fits)."""
         _, vs, *_ = update_setup
         mmats, gmats, fmats = _packers(vs["params"])
         rng = np.random.default_rng(2)
-        net = jnp.asarray(rng.standard_normal((1, 55, 128, C)),
+        net = jnp.asarray(rng.standard_normal((1, 8, 240, C)),
                           jnp.float32)
-        inp = jnp.asarray(rng.standard_normal((1, 55, 128, C)),
+        inp = jnp.asarray(rng.standard_normal((1, 8, 240, C)),
                           jnp.float32)
-        corr = jnp.asarray(rng.standard_normal((1, 55, 128, CC)),
+        corr = jnp.asarray(rng.standard_normal((1, 8, 240, CC)),
                            jnp.float32)
-        flow = jnp.asarray(rng.standard_normal((1, 55, 128, 2)),
+        flow = jnp.asarray(rng.standard_normal((1, 8, 240, 2)),
                            jnp.float32)
         with pytest.raises(ValueError, match="VMEM"):
             step_pallas.fused_step(net, inp, corr, flow, mmats, gmats,
