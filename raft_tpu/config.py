@@ -352,6 +352,87 @@ class GraniteHybridConfig:
         return self.vocab_size if self.vocab_held is None else self.vocab_held
 
 
+#: The published layer pattern of Trinity-Mini: a global layer after
+#: every three sliding-window ones (``global_attn_every_n_layers`` 4).
+_AFMOE_LAYER_TYPES = tuple(
+    "full_attention" if i % 4 == 3 else "sliding_attention"
+    for i in range(32))
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeConfig:
+    """The ``afmoe`` token family (arcee-ai Trinity-Mini's
+    ``config.json`` keys, defaults as published) plus this chip's share
+    of an expert- and vocabulary-parallel deployment: sliding-window
+    (rotary) and full (position-free) attention layers with a sigmoid
+    gate on the heads' output, a dense SwiGLU in the leading layers,
+    then ``num_experts`` routed experts beside ``num_shared_experts``
+    shared ones. The router always scores all ``num_experts``;
+    ``experts_held`` of them, from ``expert_offset`` on, live here and
+    only their terms are added up (the shared expert's whole);
+    ``vocab_held`` rows of the embedding and of the untied head live
+    here and the logits and the loss are over them."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 6144         # dense SwiGLU (leading layers)
+    moe_intermediate_size: int = 1024     # each expert's SwiGLU
+    num_hidden_layers: int = 32
+    layer_types: Tuple[str, ...] = _AFMOE_LAYER_TYPES
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 2048
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    route_norm: bool = True
+    route_scale: float = 2.826
+    mup_enabled: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    vocab_size: int = 200192
+    # the chip's share (all of it by default)
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    vocab_held: Optional[int] = None
+    # bfloat16 operands with float32 accumulation; parameters, router
+    # scores, norm statistics, softmax, the attention gate's sigmoid,
+    # residual stream, logits and loss stay float32
+    mixed_precision: bool = True
+
+    def __post_init__(self):
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"num_hidden_layers is {self.num_hidden_layers}")
+        bad = set(self.layer_types) - {"sliding_attention",
+                                       "full_attention"}
+        if bad:
+            raise ValueError(f"unknown layer types {sorted(bad)}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("query heads must be a multiple of "
+                             "num_key_value_heads")
+        if self.num_shared_experts not in (0, 1):
+            raise ValueError("one shared expert, or none")
+        if not 0 < self.held <= self.num_experts - self.expert_offset:
+            raise ValueError(
+                f"experts {self.expert_offset}.."
+                f"{self.expert_offset + self.held} are not among the "
+                f"router's {self.num_experts}")
+        if not 0 < self.vocab <= self.vocab_size:
+            raise ValueError(f"vocab_held {self.vocab} of {self.vocab_size}")
+
+    @property
+    def held(self) -> int:
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held)
+
+    @property
+    def vocab(self) -> int:
+        return self.vocab_size if self.vocab_held is None else self.vocab_held
+
+
 @dataclasses.dataclass(frozen=True)
 class TrainConfig:
     """Training hyperparameters (reference ``train.py:431-452`` flags and
@@ -361,7 +442,7 @@ class TrainConfig:
     stage: str = "chairs"
     # a row of raft_tpu/families.py: "raft" (canonical), "sparse" (the
     # fork's active "ours" trainer, reference train.py:19 → core/ours.py),
-    # "lfm2_moe" or "granitemoehybrid" (packed token sequences)
+    # "lfm2_moe", "granitemoehybrid" or "afmoe" (packed token sequences)
     model_family: str = "raft"
     lr: float = 4e-4
     num_steps: int = 100000

@@ -17,7 +17,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from raft_tpu.config import GraniteHybridConfig, LMConfig
+from raft_tpu.config import AfmoeConfig, GraniteHybridConfig, LMConfig
 from raft_tpu.losses import sequence_loss
 
 
@@ -82,6 +82,11 @@ def _build_lfm2(mcfg):
 def _build_granite(mcfg):
     from raft_tpu.models.granitemoehybrid import GraniteMoeHybrid
     return GraniteMoeHybrid(mcfg)
+
+
+def _build_afmoe(mcfg):
+    from raft_tpu.models.afmoe import Afmoe
+    return Afmoe(mcfg)
 
 
 def _maybe_add_noise(rng, image1, image2):
@@ -177,6 +182,20 @@ def _token_loss(tcfg, freeze_bn):
     return loss_fn
 
 
+def _blocked_token_loss(tcfg, freeze_bn):
+    """:func:`_token_loss` for a model that runs its own head and loss
+    in blocks of positions (``blocked_loss=True``): the step never holds
+    the whole step's logits."""
+    def loss_fn(apply_fn, variables, batch, rngs, step):
+        (loss, metrics), counters = apply_fn(
+            {"params": variables["params"]}, batch["tokens"],
+            batch["segment_ids"], batch["positions"], train=True,
+            blocked_loss=True)
+        metrics.update(counters)
+        return loss, metrics, {}
+    return loss_fn
+
+
 FAMILIES: Dict[str, Family] = {
     "raft": Family(_build_raft, _flow_init_inputs, _raft_loss,
                    raft_options=True, torch_weights=True, flow_init=True,
@@ -192,6 +211,11 @@ FAMILIES: Dict[str, Family] = {
                                config_cls=GraniteHybridConfig,
                                step_counters=("tokens", "ssm_resets",
                                               "ssd_chunks")),
+    "afmoe": Family(_build_afmoe, _token_init_inputs, _blocked_token_loss,
+                    tokens=True, config_cls=AfmoeConfig,
+                    step_counters=("tokens", "routed_here",
+                                   "expert_load_max", "dropped",
+                                   "window_pairs", "causal_pairs")),
 }
 
 #: the rows ``evaluate.py`` and ``demo.py`` offer: image pairs in, flow out

@@ -169,3 +169,52 @@ def token_cross_entropy(logits, tokens, segment_ids):
         loss = jnp.where(counted, logz - picked, 0.0).sum() \
             / jnp.maximum(n, 1)
     return loss, {"loss": loss, "tokens": n.astype(jnp.int32)}
+
+
+#: positions whose logits exist at once in the blocked loss (float32
+#: logits of 16384 positions over 25024 rows are 1.6 GB; of 2048, 0.2)
+LOSS_BLOCK = 2048
+
+
+def blocked_token_cross_entropy(hidden, head, tokens, segment_ids, *,
+                                dtype, block: int = LOSS_BLOCK):
+    """:func:`token_cross_entropy` of ``hidden @ head`` without the
+    logits of the whole step ever existing at once.
+
+    ``hidden`` (B, S, d) float32 after the last norm, ``head`` (d, V)
+    over the vocabulary held; the positions go through in blocks of
+    ``block`` (the whole where it does not divide them): a block's
+    logits (operands in ``dtype``, float32 accumulation) are made, used
+    and, in the backward pass, made again, so neither pass holds more
+    than one block of them. Same value and gradient as the whole loss,
+    to the order of float32 sums.
+    """
+    b, s, d = hidden.shape
+    rows = b * s
+    if rows % block:
+        block = rows
+    targets = jnp.roll(tokens, -1, axis=1)
+    counted = (jnp.roll(segment_ids, -1, axis=1) == segment_ids) \
+        & (jnp.arange(s) < s - 1)
+    n = counted.sum()
+
+    @jax.checkpoint
+    def nll_of(total, rows_of):
+        hidden_b, target_b, counted_b = rows_of
+        with jax.named_scope("lm_head"):
+            logits = jnp.dot(hidden_b.astype(dtype), head.astype(dtype),
+                             preferred_element_type=jnp.float32)
+        with jax.named_scope("token_loss"):
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, target_b[:, None],
+                                         axis=-1)[:, 0]
+            return total + jnp.where(counted_b, logz - picked,
+                                     0.0).sum(), None
+
+    blocked = jax.tree.map(
+        lambda a: a.reshape(rows // block, block, *a.shape[2:]),
+        (hidden, targets, counted))
+    total, _ = jax.lax.scan(nll_of, jnp.zeros((), jnp.float32), blocked)
+    with jax.named_scope("token_loss"):
+        loss = total / jnp.maximum(n, 1)
+    return loss, {"loss": loss, "tokens": n.astype(jnp.int32)}

@@ -32,26 +32,14 @@ import jax.numpy as jnp
 from raft_tpu.config import LMConfig
 from raft_tpu.models.lm_common import (_INIT, _dense, _dtype,
                                        _refuse_a_mesh_on_tpu, lm_head,
-                                       rms_norm, swiglu)
+                                       rms_norm, rope, routed_experts,
+                                       swiglu)
 from raft_tpu.ops.attention import causal_attention
-from raft_tpu.ops.gmm import expert_gmm
 
 
 def _no_counters() -> dict:
     zero = jnp.zeros((), jnp.int32)
     return {"routed_here": zero, "expert_load_max": zero, "dropped": zero}
-
-
-def rope(x, positions, theta: float):
-    """Half-rotation RoPE on ``x`` (B, S, H, D) at ``positions`` (B, S):
-    the pair of dimension ``i`` is ``i + D/2``."""
-    half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[..., None] * inv_freq
-    cos = jnp.cos(angle)[:, :, None, :]
-    sin = jnp.sin(angle)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 class ShortConv(nn.Module):
@@ -126,26 +114,6 @@ class DenseFFN(nn.Module):
         return swiglu(x, w1, w3, w2, dtype)
 
 
-@jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation whose inverse is known: the
-    transpose is a gather too, not a scatter."""
-    del inverse
-    return x[perm]
-
-
-def _permute_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
-
-
-def _permute_bwd(res, g):
-    perm, inverse = res
-    return g[inverse], None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
-
-
 class ExpertFFN(nn.Module):
     """``s = sigmoid(W_g x)`` over all experts in float32;
     ``sel = topk(s + b)``; ``w = s[sel] / (sum(s[sel]) + 1e-6)`` times
@@ -165,43 +133,11 @@ class ExpertFFN(nn.Module):
         w1 = self.param("w1", _INIT, (held, d, f))
         w3 = self.param("w3", _INIT, (held, d, f))
         w2 = self.param("w2", _INIT, (held, f, d))
-        shape = x.shape
-        x = x.reshape(-1, d)
-        t = x.shape[0]
-        with jax.named_scope("moe_router"):
-            scores = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), w_g,
-                precision=jax.lax.Precision.HIGHEST))
-            ranked = scores
-            if cfg.use_expert_bias:
-                # a selection bias only: no gradient reaches it and its
-                # update rule is not published, so it keeps its values
-                ranked = scores + jax.lax.stop_gradient(bias)
-            _, sel = jax.lax.top_k(ranked, k)
-            weight = jnp.take_along_axis(scores, sel, axis=-1)
-            if cfg.norm_topk_prob:
-                weight = weight / (weight.sum(-1, keepdims=True) + 1e-6)
-            weight = weight * cfg.routed_scaling_factor
-            # every assignment, sorted by expert (stable: by token within)
-            flat = sel.reshape(-1)
-            order = jnp.argsort(flat, stable=True)
-            inverse = jnp.argsort(order)
-            sizes = jnp.zeros((n,), jnp.int32).at[flat].add(1)
-            here = sizes[off:off + held]
-        with jax.named_scope("moe_experts"):
-            rows = _permute(jnp.repeat(x.astype(dtype), k, axis=0), order,
-                            inverse)
-            gmm = lambda a, w: expert_gmm(                # noqa: E731
-                a, w.astype(dtype), sizes, off)
-            gate = gmm(rows, w1).astype(jnp.float32)
-            up = gmm(rows, w3).astype(jnp.float32)
-            out = gmm((jax.nn.silu(gate) * up).astype(dtype), w2)
-            out = _permute(out, inverse, order).reshape(t, k, d)
-            out = (out.astype(jnp.float32) * weight[..., None]).sum(1)
-        counters = {"routed_here": here.sum(),
-                    "expert_load_max": here.max(),
-                    "dropped": t * k - sizes.sum()}
-        return out.astype(dtype).reshape(shape), counters
+        out, counters = routed_experts(
+            x.reshape(-1, d), w_g, bias if cfg.use_expert_bias else None,
+            w1, w3, w2, top_k=k, offset=off, norm_topk=cfg.norm_topk_prob,
+            norm_eps=1e-6, scale=cfg.routed_scaling_factor, dtype=dtype)
+        return out.astype(dtype).reshape(x.shape), counters
 
 
 class DecoderLayer(nn.Module):

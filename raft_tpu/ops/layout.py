@@ -68,6 +68,8 @@ that handoff into VMEM entirely.
 
 from __future__ import annotations
 
+import re
+
 import jax
 from jax.experimental import pallas as pl
 
@@ -75,26 +77,52 @@ from jax.experimental import pallas as pl
 #: compiled program's text (in the ``op_name`` of its
 #: ``tpu_custom_call``) and in profiler traces, so tools can tell which
 #: kernels a program really lowered (``kernel_census``) without parsing
-#: kernel bodies.
+#: kernel bodies. A census takes the first name an ``op_name`` holds, so
+#: ``attn_window`` stands before ``attn``, which its name begins with.
 KERNEL_NAMES = {key: f"raft_{key}" for key in (
     "corr_fwd", "corr_bwd", "gru", "motion", "step", "msda_fwd", "msda_bwd",
-    "expert_gmm", "attn")}
+    "expert_gmm", "attn_window", "attn")}
 
 
-def kernel_census(compiled_text: str) -> dict:
-    """Count the Mosaic kernels in a compiled program's text
-    (``compiled.as_text()``): ``{kernel: n}`` over ``KERNEL_NAMES`` keys,
-    plus ``"unnamed"`` for any other ``tpu_custom_call``. Kernels that
-    did not lower are absent — an interpret-mode or XLA-path program
-    returns ``{}``."""
-    counts: dict = {}
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = ")
+
+
+def hlo_instructions(compiled_text: str):
+    """The lines of a compiled program's text, an instruction whole on
+    each: a kernel that hands Mosaic metadata of its own (the shipped
+    block-sparse attention does) is printed over several lines, its
+    ``op_name`` on the last; they are joined until the braces the
+    instruction opened are closed."""
+    held = None
     for line in compiled_text.splitlines():
+        if held is not None:
+            held += " " + line.strip()
+        elif _INSTRUCTION.match(line):
+            held = line
+        else:
+            yield line
+            continue
+        if held.count("{") <= held.count("}"):
+            yield held
+            held = None
+    if held is not None:
+        yield held
+
+
+def kernel_census(compiled_text: str, names: dict = KERNEL_NAMES) -> dict:
+    """Count the Mosaic kernels in a compiled program's text
+    (``compiled.as_text()``): ``{kernel: n}`` over the keys of ``names``
+    (``KERNEL_NAMES``), plus ``"unnamed"`` for any other
+    ``tpu_custom_call``. Kernels that did not lower are absent — an
+    interpret-mode or XLA-path program returns ``{}``."""
+    counts: dict = {}
+    for line in hlo_instructions(compiled_text):
         if 'custom_call_target="tpu_custom_call"' not in line:
             continue
         # op_name is ".../<name>/pallas_call" in a forward program and
         # ".../transpose(jvp(<name>))/pallas_call" in a backward one.
         op_name = line.partition('op_name="')[2].partition('"')[0]
-        key = next((k for k, name in KERNEL_NAMES.items()
+        key = next((k for k, name in names.items()
                     if name in op_name), "unnamed")
         counts[key] = counts.get(key, 0) + 1
     return counts

@@ -119,7 +119,9 @@ def make_schedule(cfg: TrainConfig) -> optax.Schedule:
 _NO_DECAY = frozenset({
     "expert_bias",                                  # models/lfm2.py
     "A_log", "D", "dt_bias", "norm",                # models/granitemoehybrid.py
-    "input_layernorm", "post_attention_layernorm"})
+    "input_layernorm", "post_attention_layernorm",
+    "pre_mlp_layernorm", "post_mlp_layernorm",      # models/afmoe.py
+    "q_norm", "k_norm"})
 
 
 def _decay_mask(params):
@@ -135,7 +137,8 @@ def _decay_mask(params):
     sense: no gradient reaches it, and decay must not shrink it. The
     state-space mixer's ``A_log``, ``D`` and ``dt_bias`` and the norm
     weights of ``models/granitemoehybrid.py`` take none either, as in
-    the published recipes of that family.
+    the published recipes of that family; nor do the norm weights of
+    ``models/afmoe.py`` (four a layer, two a head, the last one).
     """
     def mask_tree(tree, name=None):
         if isinstance(tree, dict):

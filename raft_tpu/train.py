@@ -23,6 +23,8 @@ Improvements over the reference, kept explicit:
 from __future__ import annotations
 
 import argparse
+import contextlib
+import gc
 import os
 import time
 from typing import Optional, Sequence
@@ -274,7 +276,10 @@ def train(tcfg: TrainConfig, mcfg, *,
         loader_snap = (dataloader.state().to_dict()
                        if hasattr(dataloader, "state") else None)
         timer = host_timer()
-        with guard:
+        with guard, contextlib.ExitStack() as leaving:
+            # what a compiling step freezes (below) is the cyclic
+            # collector's again once the loop is left, however it is
+            leaving.callback(gc.unfreeze)
             # the while-condition check also escapes a pathological spin
             # over an exhausted one-shot dataloader (local flag only; no
             # collectives run in an empty pass)
@@ -349,6 +354,16 @@ def train(tcfg: TrainConfig, mcfg, *,
                         compiles = xla_compile_count() - compiles0
                         if compiles:
                             step_span.args["compiles"] = compiles
+                            # What tracing and compiling left (jaxprs,
+                            # the executables' Python side: ~1 M
+                            # container objects for a token model's
+                            # step) lives as long as the run. Out of
+                            # the cyclic collector's reach, a full pass
+                            # no longer walks it: on the chip such a
+                            # pass held one 0.8 s step in ~25 for
+                            # 1.2-1.8 s (PERF.md, Findings of PR 35).
+                            gc.collect()
+                            gc.freeze()
                         step_span.close()
                     if tcfg.max_consecutive_skips and consecutive_skips \
                             >= tcfg.max_consecutive_skips:
@@ -464,20 +479,24 @@ def main(argv=None):
                              "(ours) family, or a language model on packed "
                              "token sequences: lfm2_moe (LFM2-MoE: gated "
                              "short convolutions, grouped-query "
-                             "attention, sigmoid-routed experts) or "
+                             "attention, sigmoid-routed experts), "
                              "granitemoehybrid (Granite-4.0-H: Mamba-2 "
                              "state-space layers 9:1 with attention "
-                             "without positions, scaled residual path); "
-                             "see --lm_config, --seq_len")
+                             "without positions, scaled residual path) or "
+                             "afmoe (Trinity: sliding-window and full "
+                             "attention 3:1 with gated heads, routed "
+                             "experts beside a shared one, head and loss "
+                             "in blocks); see --lm_config, --seq_len")
     parser.add_argument("--lm_config", default=None,
                         help="token families only: a JSON file whose "
                              "`model` object (or top level) holds the "
                              "family's config keys: the published sizes "
-                             "and this chip's share (vocab_held; lfm2_moe: "
-                             "experts_held, expert_offset), e.g. "
-                             "benchmark/configs/lfm2_24b_a2b.json, "
-                             "benchmark/configs/granite_4_0_h_micro.json; "
-                             "default: the published 40-layer model whole")
+                             "and this chip's share (vocab_held; lfm2_moe "
+                             "and afmoe: experts_held, expert_offset), "
+                             "e.g. benchmark/configs/lfm2_24b_a2b.json, "
+                             "benchmark/configs/granite_4_0_h_micro.json, "
+                             "benchmark/configs/trinity_mini.json; "
+                             "default: the published model whole")
     parser.add_argument("--seq_len", type=int, default=8192,
                         help="token families only: tokens a packed sequence "
                              "(--batch_size counts sequences; --data_root "
@@ -567,7 +586,7 @@ def main(argv=None):
     tokens = family.tokens
     if not tokens and args.lm_config:
         parser.error("--lm_config applies to the token families only "
-                     "(lfm2_moe, granitemoehybrid)")
+                     "(lfm2_moe, granitemoehybrid, afmoe)")
     if tokens and (args.validation or args.spatial_shards != 1
                    or args.restore_ckpt):
         parser.error("--validation, --spatial_shards and --restore_ckpt "

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from raft_tpu.config import (GraniteHybridConfig, LMConfig, OursConfig,
-                             RAFTConfig, TrainConfig)
+from raft_tpu.config import (AfmoeConfig, GraniteHybridConfig, LMConfig,
+                             OursConfig, RAFTConfig, TrainConfig)
 from raft_tpu.families import FAMILIES, FLOW_FAMILIES, family_of
 from raft_tpu.parallel import create_train_state, make_train_step
 
@@ -105,7 +105,7 @@ def test_the_family_tuples_are_computed_from_the_table():
     assert FLOW_FAMILIES == tuple(
         name for name, row in FAMILIES.items() if not row.tokens)
     assert set(FAMILIES) == {"raft", "sparse", "lfm2_moe",
-                             "granitemoehybrid"}
+                             "granitemoehybrid", "afmoe"}
     for gone in ("MODEL_FAMILIES", "TOKEN_FAMILIES", "FLOW_FAMILIES"):
         assert not hasattr(config, gone)
     with pytest.raises(ValueError, match="unknown model_family"):
@@ -136,11 +136,21 @@ _TINY_SSM = GraniteHybridConfig(
     mamba_d_state=16, mamba_chunk_size=16, vocab_size=256, vocab_held=64)
 
 
+_TINY_SWA = AfmoeConfig(
+    hidden_size=64, intermediate_size=160, moe_intermediate_size=48,
+    num_hidden_layers=2, layer_types=("sliding_attention",
+                                      "full_attention"),
+    num_dense_layers=1, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, sliding_window=8, num_experts=8, num_experts_per_tok=2,
+    vocab_size=256, experts_held=2, expert_offset=2, vocab_held=64)
+
+
 @pytest.mark.parametrize("name,mcfg,expects", [
     ("raft", RAFTConfig(small=True), "fnet"),
     ("sparse", RAFTConfig(), "query_embed"),
     ("lfm2_moe", _TINY_LM, "embed_tokens"),
     ("granitemoehybrid", _TINY_SSM, "embed_tokens"),
+    ("afmoe", _TINY_SWA, "lm_head"),
 ])
 def test_a_row_builds_its_model_and_its_init_inputs_fit(name, mcfg,
                                                         expects):
@@ -172,7 +182,40 @@ def test_the_steps_counters_are_the_rows():
         "tokens", "routed_here", "expert_load_max", "dropped")
     assert family_of("granitemoehybrid").step_counters == (
         "tokens", "ssm_resets", "ssd_chunks")
+    assert family_of("afmoe").step_counters == (
+        "tokens", "routed_here", "expert_load_max", "dropped",
+        "window_pairs", "causal_pairs")
     assert family_of("raft").step_counters == ()
+
+
+@pytest.mark.parametrize("name,blocked", [
+    ("lfm2_moe", False), ("granitemoehybrid", False), ("afmoe", True)])
+def test_a_token_rows_loss_is_whole_or_blocked(name, blocked):
+    """Two paths are kept: the two older token rows make the whole
+    step's logits and hand them to ``token_cross_entropy`` (their
+    compiled steps are the parent's); ``afmoe``'s row asks its model for
+    the loss in blocks of positions. Either way the loss function
+    returns ``(loss, metrics with the row's counters, {})``."""
+    row = family_of(name)
+    seen = {}
+
+    def apply_fn(variables, tokens, segment_ids, positions, **kwargs):
+        seen.update(kwargs)
+        counters = {k: jnp.zeros((), jnp.int32)
+                    for k in row.step_counters if k != "tokens"}
+        if kwargs.get("blocked_loss"):
+            zero = jnp.zeros(())
+            return (zero, {"loss": zero,
+                           "tokens": jnp.zeros((), jnp.int32)}), counters
+        return jnp.zeros(tokens.shape + (16,)), counters
+
+    ids = jnp.zeros((2, 8), jnp.int32)
+    loss, metrics, mutated = row.make_loss(TrainConfig(model_family=name),
+                                           False)(
+        apply_fn, {"params": {}}, {"tokens": ids, "segment_ids": ids,
+                                   "positions": ids}, {}, 0)
+    assert bool(seen.get("blocked_loss")) == blocked
+    assert set(metrics) == {"loss", *row.step_counters} and mutated == {}
 
 
 @pytest.mark.parametrize("cli,argv,offers", [
