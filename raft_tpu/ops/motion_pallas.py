@@ -214,7 +214,7 @@ def kernel_mats(mats):
     return mats
 
 
-def flow_patches(fac, col, grow, w: int, h_img: int):
+def flow_patches(fac, col, grow, w: int, h_img: int, stride: int = 0):
     """The left-hand side of ``flow_conv7``: a ``(rows, 128)`` operand
     whose lane ``2*t + c`` holds channel ``c`` of the flow shifted by tap
     ``t``'s ``dy*W + dx`` rows, zero where ``tap_valid`` fails — the 49
@@ -225,7 +225,9 @@ def flow_patches(fac, col, grow, w: int, h_img: int):
     broadcast over the lanes by channel parity, each lane takes its own
     ``dx`` shift under that shift's column mask (7 row shifts), then its
     own ``dy`` shift by whole image rows (7 more). Every value is exactly
-    the per-tap ``_shift_rows(fac, dy*W + dx) * mask``."""
+    the per-tap ``_shift_rows(fac, dy*W + dx) * mask``. ``stride`` is the
+    flattened rows an image row takes where that is more than its ``w``
+    columns (the fused step kernel pads a row to whole sublane tiles)."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, _PATCH_LANES), 1)
     tap = lane // 2
     ldy = tap // 7 - 3          # lanes past tap 48 read 4: no dy matches
@@ -243,7 +245,8 @@ def flow_patches(fac, col, grow, w: int, h_img: int):
                        _shift_rows(ft, dx), gx)
     patches = zero
     for dy in range(-3, 4):
-        patches = jnp.where(ldy == dy, _shift_rows(gx, dy * w), patches)
+        patches = jnp.where(ldy == dy, _shift_rows(gx, dy * (stride or w)),
+                            patches)
     return patches.astype(fac.dtype)
 
 

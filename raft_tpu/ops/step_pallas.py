@@ -16,45 +16,60 @@ flow head) never leaves VMEM. Because PR 15's contbatch ``step``
 executable IS this scan body, the fusion speeds batched, streaming,
 brownout, and continuous serving at once.
 
-Two fusion depths, chosen by admission (``plan_fusion``):
+Two fusion depths, by what the iteration needs (``plan_fusion``):
 
 * ``'mg'`` — motion encoder + GRU, emitting the new hidden state. Used
   on iterations that also need the mask head (``compute_mask=True``),
-  whose ``_concat_conv`` stays on the XLA side, and whenever the flow
-  head pushes the estimate over budget.
+  whose ``_concat_conv`` stays on the XLA side.
 * ``'mgf'`` — + the flow head's two 3x3 convs, emitting ``(h2, delta)``
-  as two outputs. Admitted wherever ``'mg'`` is at bf16 (Sintel, chairs
-  and KITTI feature maps); at f32 it costs a rung or the depth.
+  as two outputs. Admitted wherever ``'mg'`` is.
 
 No tap of a two-channel conv gets an MXU pass of its own: ``convf1``
 (7x7 on the 2-channel flow) is one contraction over its 98 tap-channels
-(``motion_pallas.flow_conv7``, shared with the stand-alone motion
+(``motion_pallas.flow_patches``, shared with the stand-alone motion
 kernel) and the flow head's last conv (3x3, 256 -> 2) is one product
 with its nine taps on the output axis (``folded_head_conv``). As 49
 products with K = 2 and 9 with N = 2 they were a quarter of the passes
 the kernel streams and, by their per-tap temporaries, a third of its
 VMEM.
 
-Halos compose across the chain: the GRU's SepConv pair needs ±4 rows
-of valid *x* (and the flow head another ±2 of valid ``h2``), and the
-motion chain needs ±5 beyond wherever its output must be valid — so
-the corr/flow windows carry ``hm = hg + 5`` halo rows (9 for ``mg``,
-11 for ``mgf``) assembled from ``ceil(hm/th)`` neighbor blocks per
-side (``gru_pallas.halo_assemble``), while net/inp carry ``hg``. The
-motion chain is computed over its full span and sliced down to the GRU
-span; every row of the slice is exact by the same masks the
-stand-alone kernels use, so the fused result is the *identical*
-shifted-matmul arithmetic — parity with the two-launch chain is
-near-bit-exact at f32, and ≤2e-4 vs the conv path
-(``tests/test_step_pallas.py``).
+Every row of every stage is computed once. The receptive fields
+compose across the chain — an output row of ``mg`` reads corr and flow
+9 rows each side, one of ``mgf`` 11 (``halos``) — and a tile that
+stood alone would have to recompute that many rows of the motion chain
+and of the GRU beside every ``th`` it keeps (22 and 12 for 16 at
+``mgf``). Instead the grid walks the row tiles of one image in order
+and each stage produces its next ``th`` rows a grid step into a span
+in VMEM scratch that holds (the last rows the stage produced in the
+previous steps) + (its new rows), which its readers slice: 2 rows kept
+for a 3x3, 6 of the flow for the 7x7, 4 for the vertical gate convs,
+more where a later stage reads the same rows further behind
+(``_carry_rows``); at the end of a step a span moves up by ``th``
+rows. A stage with vertical taps runs that many rows behind its
+source, so the outputs run ``hm`` rows behind the inputs: an image
+takes ``ceil(hm / th)`` closing grid steps after its last tile, the
+output block of a step is completed from a kept part and a new part,
+and every input block is fetched once. Rows outside the image are
+zeroed where they are produced, by global row, which is the convs'
+zero padding; the kept rows are zeroed at an image's first step. An
+image row takes a whole number of sublane tiles of flattened rows (62
+columns are padded to 64 by the wrapper, beside the rows' pad to whole
+tiles), so every slice of a span starts on a tile boundary: on the
+chip, spans sliced off the boundary read wrong. Per output row the
+arithmetic is the stand-alone kernels': the same products with the
+same operands summed in the same order, only the rows that share a
+product differ — parity with the two-launch chain is bit-exact at f32,
+and ≤2e-4 vs the conv path (``tests/test_step_pallas.py``).
 
-VMEM admission is ``step_vmem_parts`` — Mosaic's calibrated per-row
-figure over the motion span, which is this kernel's peak
-(``vmem.scan_rows_parts``) — under the shared ``vmem.choose_rows``
-ladder ``(16, 8, 4)`` and the explicit 100 MiB limit; at Sintel shapes
-bf16 admits TH=16 at both depths and f32 TH=8 at both. A shape the
-ladder rejects (e.g. W=240 at 1080p, either depth) falls back, loudly
-logged, to the two-launch chain, never silently.
+VMEM admission is ``step_vmem_parts`` — Mosaic's own figures for this
+body, a part by the tile's rows and a part by the width for the
+rows kept, beside the weights — under the ladder ``(16, 8)`` and the
+explicit 100 MiB limit. Every admitted rung streams ``(tiles + closing
+steps) * th`` rows a stage for the image's ``H``, so ``choose_rows``
+takes the admitted rung that streams fewest (the taller on a tie): TH
+8 for a 55-row Sintel map (72 rows against 80 at TH 16), TH 16 for
+chairs' 46. A shape the ladder rejects falls back, loudly logged, to
+the two-launch chain, never silently.
 
 The custom VJP recomputes through the identical-math jnp twin
 (``reference_motion`` → ``reference_gru`` → flow-head taps); a fused
@@ -75,37 +90,59 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from raft_tpu.ops import layout as klayout
 from raft_tpu.ops import vmem
 from raft_tpu.ops.gru_pallas import (_TAPS, _bshift, _flatten_mats,
                                      _full_spec, _round_up, _shift_rows,
-                                     gate_sigmoid, halo_assemble,
-                                     split_x_weights)
+                                     gate_sigmoid, split_x_weights)
 from raft_tpu.ops.gru_pallas import reference_gru
-from raft_tpu.ops.motion_pallas import (_MAX_CORR_CHANNELS, conv_taps,
-                                        flow_conv7, kernel_mats,
-                                        reference_motion, tap_valid)
+from raft_tpu.ops.motion_pallas import (_MAX_CORR_CHANNELS, flow_patches,
+                                        kernel_mats, reference_motion)
 from raft_tpu.utils.envflags import STEP_FLAG, resolve_step_pallas
 
-# Per-stage receptive-field depths (rows each side). The GRU needs its
-# x/net assembly valid ±_HALO_GRU rows around the tile; the flow head
-# needs h2 valid another ±_HALO_FLOW_HEAD; the motion chain needs its
-# inputs ±_HALO_MOTION beyond wherever its output must be valid.
-_HALO_MOTION = 5
-_HALO_GRU = 4
-_HALO_FLOW_HEAD = 2
+# Rows each stage runs behind the input blocks of its grid step: a stage
+# with vertical taps of reach r reads its source to r rows below its own
+# row, so it runs r rows behind that source. ``convc1`` (1x1) runs with
+# the input; the GRU's horizontal half has no vertical taps and runs
+# with ``[motion | flow]``.
+_LAG_FLO1 = 3                    # convf1, 7x7 on the flow
+_LAG_MID = _LAG_FLO1 + 1         # convc2 and convf2
+_LAG_MOT = _LAG_MID + 1          # the motion conv; zr1, q1, h1
+_LAG_ZR2 = _LAG_MOT + 2          # the vertical gates
+_LAG_H2 = _LAG_ZR2 + 2           # q2, h2: the 'mg' output
+_LAG_FH1 = _LAG_H2 + 1           # the flow head's first conv
+_LAG_DELTA = _LAG_FH1 + 1        # the 'mgf' output
 
-# Row-tile ladder for real launches (same rungs as the component
-# kernels; Sintel and chairs bf16 ride the top rung at both depths).
-_ROW_LADDER = (16, 8, 4)
+# Row-tile ladder for real launches. The component kernels' TH = 4 rung
+# is left out: the streamed body reads wrong there on the chip (v5e,
+# PR 36: a third of the outputs off at Sintel and chairs, both depths,
+# where TH 8 and 16 equal the self-contained tiles bit for bit; equal
+# in interpret mode too, cause not found), and every shape that fits a
+# rung fits TH 8.
+_ROW_LADDER = (16, 8)
+
+# Flattened rows an image row is padded to a multiple of: a packed
+# bfloat16 sublane tile (two float32 ones).
+_ROW_ALIGN = 16
 
 
 def halos(flow_head: bool) -> tuple[int, int]:
-    """``(hg, hm)``: halo rows each side for the net/inp (GRU-span) and
-    corr/flow (motion-span) windows of one fused launch."""
-    hg = _HALO_GRU + (_HALO_FLOW_HEAD if flow_head else 0)
-    return hg, hg + _HALO_MOTION
+    """``(hg, hm)``: rows each side of an output row that the chain
+    reads of net/inp (GRU ±4, flow head ±2 more) and of corr/flow (the
+    motion encoder's ±5 beyond that). No tile assembles them: ``hm`` is
+    what the streamed body's outputs run behind its input blocks, and
+    ``ceil(hm / th)`` the closing grid steps of an image."""
+    hm = _LAG_DELTA if flow_head else _LAG_H2
+    return hm - _LAG_MOT, hm
+
+
+def grid_steps(h_img: int, th: int, flow_head: bool) -> int:
+    """Grid steps an image takes at tile height ``th``: its row tiles,
+    then the closing steps its outputs lag by. Every stage computes
+    ``th`` rows in each."""
+    return -(-h_img // th) + -(-halos(flow_head)[1] // th)
 
 
 # ---------------------------------------------------------------------------
@@ -140,217 +177,380 @@ def fold_head_taps(wfh2):
     return wfh2.reshape(9, fhid, 2).transpose(1, 0, 2).reshape(fhid, 18)
 
 
-def folded_head_conv(valid, v, wcat_ref, b_ref, w: int):
-    """The 3x3 ``Fh -> 2`` conv as ONE product ``v @ [W_0 | ... | W_8]``
-    and nine shifted, masked adds of two float32 columns each, instead
-    of nine products with N = 2 (each a whole pass of the span through
-    the MXU for two output columns). ``delta[p] = sum_t mask_t[p] *
-    (v[p + s_t] @ W_t)`` is the per-tap sum with the shift and mask
-    applied to the product's rows instead of the operand's: the same
-    float32 terms added in the same order, then the same cast and
-    compute-dtype bias add."""
-    taps = jax.lax.dot_general(
-        v, wcat_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc = jnp.zeros((v.shape[0], 2), jnp.float32)
+def folded_head_conv(taps, b_ref, w: int, w_img: int, g: int, dtype):
+    """The 3x3 ``Fh -> 2`` conv from its product ``fh1 @ [W_0 | ... |
+    W_8]`` (``taps``: float32, 18 columns, over the ``g`` output rows
+    and one image row each side) as nine shifted adds of two columns
+    each, instead of nine products with N = 2 (each a whole pass of the
+    rows through the MXU for two output columns). ``delta[p] = sum_t
+    (fh1[p + s_t] @ W_t)`` is the per-tap sum with the shift applied to
+    the product's rows instead of the operand's: the same float32 terms
+    added in the same order, then the same cast and compute-dtype bias
+    add. Rows of ``fh1`` outside the image are zero at the source (so
+    are their products); a tap that leaves the image sideways is zeroed
+    here (``w_img`` columns of the ``w`` an image row takes)."""
+    col = _col(taps.shape[0], w)
+    shifted = {0: taps}
+    for dx in (-1, 1):
+        shifted[dx] = (_shift_rows(taps, dx)
+                       * _col_valid(col, dx, w_img).astype(jnp.float32))
+    acc = jnp.zeros((g, 2), jnp.float32)
     t = 0
     for dy in (-1, 0, 1):
         for dx in (-1, 0, 1):
-            acc += (_shift_rows(taps[:, 2 * t:2 * t + 2], dy * w + dx)
-                    * valid(dy, dx).astype(jnp.float32))
+            acc += shifted[dx][(1 + dy) * w:(1 + dy) * w + g,
+                               2 * t:2 * t + 2]
             t += 1
-    return acc.astype(v.dtype) + b_ref[...]
+    return acc.astype(dtype) + b_ref[...]
 
 
 # ---------------------------------------------------------------------------
 # Kernel
 # ---------------------------------------------------------------------------
 
-def _step_kernel(*refs, w: int, h_img: int, th: int, fh: bool):
-    """One whole refine-scan iteration for a TH-row tile.
+def _dot(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    ``refs`` is ``(<2nm+1 corr>, <2nm+1 flow>, <2ng+1 net>, <2ng+1 inp>,
-    <11 motion mats>, <16 GRU mats>, [4 flow-head mats,] h2_out
-    [, delta_out])`` — neighbor refs are the SAME flattened arrays
-    under clamped block index maps. The motion chain runs over the
-    deep (±hm) span; its ``[out ‖ flow]`` is sliced to the GRU (±hg)
-    span and consumed as the second x part without ever being stored;
-    with ``fh`` the flow head consumes ``h2`` in the same launch.
+
+def _col(rows: int, w: int):
+    """Image column of each of ``rows`` flattened rows, as ``(rows, 1)``."""
+    ri = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    return ri - (ri // w) * w
+
+
+def _col_valid(col, dx: int, w_img: int):
+    cd = col + dx
+    return (cd >= 0) & (cd < w_img)
+
+
+def conv3_rows(ops, b_ref, w: int, w_img: int, g: int):
+    """A 3x3 conv's ``g`` output rows from operands that span them and
+    one image row each side: per tap ``(dy, dx)`` one MXU product of
+    exactly ``g`` rows, summed over the input operands in
+    ``motion_pallas.conv_taps``' order (float32 accumulation,
+    compute-dtype bias add). The sideways shift and its column mask are
+    applied once a ``dx`` to the whole span, and a tap's ``dy`` is a
+    slice of it by whole image rows; rows outside the image are zero at
+    the source, which is the conv's zero padding. Value for value the
+    operands ``conv_taps`` builds with a shift and a mask a tap. An
+    image row takes ``w`` flattened rows, ``w_img`` of them columns."""
+    cdt = b_ref.dtype
+    col = _col(ops[0][0].shape[0], w)
+    shifted = {0: [v for v, _ in ops]}
+    for dx in (-1, 1):
+        mk = _col_valid(col, dx, w_img).astype(cdt)
+        shifted[dx] = [_shift_rows(v, dx) * mk for v, _ in ops]
+    acc = jnp.zeros((g, b_ref.shape[1]), jnp.float32)
+    t = 0
+    for dy in (-1, 0, 1):
+        for dx in (-1, 0, 1):
+            for (v, w_ref), sv in zip(ops, shifted[dx]):
+                cin = v.shape[1]
+                acc += _dot(sv[(1 + dy) * w:(1 + dy) * w + g],
+                            w_ref[t * cin:(t + 1) * cin, :])
+            t += 1
+    return acc.astype(cdt) + b_ref[...]
+
+
+def _carry_rows(th: int, fh: bool) -> dict:
+    """Image rows each stage keeps in VMEM for the next grid step: what
+    its consumers read above this step's ``th`` new rows (a source at
+    lag ``a`` read by a stage at lag ``b`` with reach ``r`` keeps ``b +
+    r - a`` rows; the deepest reader decides), and for the outputs the
+    rows that complete the next output block."""
+    out_lag = -(-halos(fh)[1] // th) * th    # the out blocks', in rows
+    rows = {
+        "cor1": _LAG_MID + 1,            # convc1 runs with the input
+        "flow": _LAG_FLO1 + 3,
+        "flo1": 2, "cor2": 2, "flo2": 2,
+        "net": _LAG_MOT,
+        "inp": _LAG_H2 + 2,              # q2 reads x two rows above h2
+        "mot": _LAG_H2 + 2 - _LAG_MOT,
+        "h1": _LAG_H2 - _LAG_MOT,        # zr2's taps, then h2's blend
+        "rh": _LAG_H2 + 2 - _LAG_ZR2,
+        "z2": _LAG_H2 - _LAG_ZR2,
+        "h2": max(out_lag - _LAG_H2, 2 if fh else 0),
+    }
+    if fh:
+        rows["taps"] = 2
+        rows["delta"] = out_lag - _LAG_DELTA
+    return rows
+
+
+def _step_kernel(*refs, w: int, w_img: int, h_img: int, th: int, fh: bool,
+                 stages):
+    """One grid step of the streamed refine iteration: every stage of
+    the chain produces its next ``th`` rows of the image.
+
+    ``refs`` is ``(corr, flow, net, inp, <11 motion mats>, <16 GRU
+    mats>, [4 flow-head mats,] h2_out [, delta_out], <one VMEM span a
+    name of stages>)``. An image row takes ``w`` flattened rows, of
+    which ``w_img`` are image columns. Grid step ``ti`` brings input
+    rows ``[ti*th, (ti+1)*th)``; a stage at lag ``a`` produces rows
+    ``[ti*th - a, (ti+1)*th - a)`` into its span — (the trailing rows it
+    kept from the last step) + (its new rows) — which its readers slice,
+    and no row of any stage is computed twice. The outputs run ``hm``
+    rows behind, so the out block of step ``ti`` is block ``ti -
+    ceil(hm/th)``, completed from a kept part and a new part, and an
+    image takes ``ceil(hm/th)`` closing steps whose input blocks are the
+    last block again (their rows lie below the image and are masked like
+    any other).
+
+    Rows outside the image are zeroed where they are produced (the
+    global row of a stage's row ``i`` is ``ti*th - lag + i // w``), so a
+    reader's vertical taps read the convs' zero padding with no mask of
+    their own; sideways taps keep their column masks.
     """
     nouts = 2 if fh else 1
+    span = dict(zip(stages, refs[len(refs) - len(stages):]))
+    refs = refs[:len(refs) - len(stages)]
     out_refs = refs[-nouts:]
-    refs = refs[:-nouts]
-    hg, hm = halos(fh)
-    nm = -(-hm // th)
-    ng = -(-hg // th)
-    ncorr = 2 * nm + 1
-    nnet = 2 * ng + 1
-    i = 0
-    corr_refs = refs[i:i + ncorr]; i += ncorr
-    flow_refs = refs[i:i + ncorr]; i += ncorr
-    net_refs = refs[i:i + nnet]; i += nnet
-    inp_refs = refs[i:i + nnet]; i += nnet
+    corr_ref, flow_ref, net_ref, inp_ref = refs[:4]
     (wc1_ref, bc1_ref, wc2_ref, bc2_ref, wf1_ref, bf1_ref,
-     wf2_ref, bf2_ref, woc_ref, wof_ref, bo_ref) = refs[i:i + 11]
-    i += 11
+     wf2_ref, bf2_ref, woc_ref, wof_ref, bo_ref) = refs[4:15]
     (wzr1h, wzr1xa, wzr1xb, wq1h, wq1xa, wq1xb, bzr1, bq1,
-     wzr2h, wzr2xa, wzr2xb, wq2h, wq2xa, wq2xb, bzr2, bq2) = refs[i:i + 16]
-    i += 16
-    fh_refs = refs[i:i + 4] if fh else None
+     wzr2h, wzr2xa, wzr2xb, wq2h, wq2xa, wq2xb, bzr2, bq2) = refs[15:31]
 
     g = th * w
     c = out_refs[0].shape[-1]
-    cdt = net_refs[ng].dtype
+    cdt = net_ref.dtype
     ti = pl.program_id(1)
 
-    # ---- motion chain over the deep (±hm) span ------------------------
-    rows_m = (th + 2 * hm) * w
-    ca = halo_assemble([r[0] for r in corr_refs], g, hm * w)
-    fa = halo_assemble([r[0] for r in flow_refs], g, hm * w)
+    def kept(ref):
+        """Image rows of a span above this step's ``th`` new ones."""
+        return ref.shape[0] // w - th
 
-    rim = jax.lax.broadcasted_iota(jnp.int32, (rows_m, 1), 0)
-    colm = rim - (rim // w) * w
-    growm = ti * th - hm + rim // w
+    # A new image: the kept rows are the last one's tail (or, at the very
+    # first step, whatever the memory held), and a row mask applied by
+    # multiplication must never meet a stale Inf or NaN.
+    @pl.when(ti == 0)
+    def _():
+        for ref in span.values():
+            if kept(ref):
+                ref[:kept(ref) * w, :] = jnp.zeros(
+                    (kept(ref) * w, ref.shape[1]), ref.dtype)
 
-    def mvalid(dy, dx):
-        return tap_valid(colm, growm, w, h_img, dy, dx)
+    def put(name, new):
+        """This step's rows of stage ``name``, below the rows it kept."""
+        ref = span[name]
+        ref[kept(ref) * w:, :] = new
+        return ref
 
-    def conv2d(valid, ops, b_ref, ksize):
-        return conv_taps(valid, ops, b_ref, ksize, w)
+    def rows_of(ref, first, n=th):
+        return ref[first * w:(first + n) * w, :]
 
-    cor = jax.nn.relu(jax.lax.dot_general(
-        ca, wc1_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(cdt) + bc1_ref[...])
-    cor = jax.nn.relu(conv2d(mvalid, [(cor, wc2_ref)], bc2_ref, 3))
-    fac = fa.astype(cdt)
-    flo = jax.nn.relu(flow_conv7(fac, wf1_ref, bf1_ref, colm, growm, w,
-                                 h_img))
-    flo = jax.nn.relu(conv2d(mvalid, [(flo, wf2_ref)], bf2_ref, 3))
-    out_m = jax.nn.relu(conv2d(mvalid, [(cor, woc_ref), (flo, wof_ref)],
-                               bo_ref, 3))
-    # The handoff, fused away: [motion ‖ flow] sliced from the deep span
-    # to the GRU (±hg) span — valid on every slice row by the masks
-    # above — and consumed in-register as the GRU's second x part.
-    off = (hm - hg) * w
-    rows_g = (th + 2 * hg) * w
-    mot = jnp.concatenate([out_m, fac], axis=1)[off:off + rows_g]
+    def at(ref, src_lag, lag, up=0):
+        """``(ref, its image row that lies ``up`` rows above the first
+        row of a stage at ``lag``)``, for a span whose new rows are at
+        ``src_lag``."""
+        return ref, kept(ref) + src_lag - lag - up
 
-    # ---- SepConvGRU over the (±hg) span -------------------------------
-    ha = halo_assemble([r[0] for r in net_refs], g, hg * w)
-    xia = halo_assemble([r[0] for r in inp_refs], g, hg * w)
-    xas = (xia, mot)
+    row = ti * th + jax.lax.broadcasted_iota(jnp.int32, (g, 1), 0) // w
 
-    rig = jax.lax.broadcasted_iota(jnp.int32, (rows_g, 1), 0)
-    colg = rig - (rig // w) * w
-    growg = ti * th - hg + rig // w
-
-    def hmask(d):
-        cd = colg + d
-        return ((cd >= 0) & (cd < w)).astype(cdt)
-
-    def vmask(d):
-        gr = growg + d
+    def inside(lag):
+        gr = row - lag
         return ((gr >= 0) & (gr < h_img)).astype(cdt)
 
-    def sepconv(vh, vxs, wh_ref, wx_refs, b_ref, shift_mul, mask):
-        ch = vh.shape[1]
-        nout = b_ref.shape[1]
-        acc = jnp.zeros((rows_g, nout), jnp.float32)
+    def conv3(ops, b_ref):
+        return conv3_rows(ops, b_ref, w, w_img, g)
+
+    # ---- motion encoder ------------------------------------------------
+    cor1 = put("cor1", jax.nn.relu(
+        _dot(corr_ref[0], wc1_ref[...]).astype(cdt) + bc1_ref[...])
+        * inside(0))
+    cor2 = put("cor2", jax.nn.relu(conv3(
+        [(rows_of(*at(cor1, 0, _LAG_MID, 1), th + 2), wc2_ref)], bc2_ref))
+        * inside(_LAG_MID))
+    # The flow keeps its clamped or padded rows here: ``flow_patches``
+    # zeroes a source row outside the image itself, and the passthrough
+    # is masked with the motion features below.
+    flow = put("flow", flow_ref[0].astype(cdt))
+    nfl = flow.shape[0]
+    rif = jax.lax.broadcasted_iota(jnp.int32, (nfl, 1), 0)
+    patches = flow_patches(flow[...], _col(nfl, w),
+                           ti * th - kept(flow) + rif // w, w_img, h_img,
+                           stride=w)
+    first = kept(flow) - _LAG_FLO1
+    flo1 = put("flo1", jax.nn.relu(
+        _dot(patches[first * w:first * w + g], wf1_ref[...]).astype(cdt)
+        + bf1_ref[...]) * inside(_LAG_FLO1))
+    flo2 = put("flo2", jax.nn.relu(conv3([(flo1[...], wf2_ref)], bf2_ref))
+               * inside(_LAG_MID))
+    out_m = jax.nn.relu(conv3([(cor2[...], woc_ref), (flo2[...], wof_ref)],
+                              bo_ref))
+    # The handoff, fused away: [motion | flow] is the GRU's second x part
+    # and never leaves VMEM.
+    mot = put("mot", jnp.concatenate(
+        [out_m, rows_of(*at(flow, 0, _LAG_MOT))], axis=1)
+        * inside(_LAG_MOT))
+
+    # ---- SepConvGRU ----------------------------------------------------
+    net = put("net", net_ref[0])
+    inp = put("inp", inp_ref[0] * inside(0))
+
+    col = _col(g, w)
+    hmask = [_col_valid(col, k - 2, w_img).astype(cdt)
+             for k in range(_TAPS)]
+
+    def hconv(vh, vxs, wh_ref, wx_refs, b_ref):
+        """A (1, 5) gate conv on this step's rows: per tap a shifted,
+        column-masked product of the h part, then of each x part."""
+        acc = jnp.zeros((g, b_ref.shape[1]), jnp.float32)
         for k in range(_TAPS):
-            d = k - 2
-            mk = mask(d)
-            acc += jax.lax.dot_general(
-                _shift_rows(vh, d * shift_mul) * mk,
-                wh_ref[k * ch:(k + 1) * ch, :],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            for vx, wx_ref in zip(vxs, wx_refs):
-                chx = vx.shape[1]
-                acc += jax.lax.dot_general(
-                    _shift_rows(vx, d * shift_mul) * mk,
-                    wx_ref[k * chx:(k + 1) * chx, :],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+            for v, wm_ref in zip((vh, *vxs), (wh_ref, *wx_refs)):
+                ch = v.shape[1]
+                acc += _dot(_shift_rows(v, k - 2) * hmask[k],
+                            wm_ref[k * ch:(k + 1) * ch, :])
         return acc.astype(cdt) + b_ref[...]
 
-    zr1 = gate_sigmoid(sepconv(ha, xas, wzr1h, (wzr1xa, wzr1xb),
-                               bzr1, 1, hmask))
-    z1, r1 = zr1[:, :c], zr1[:, c:]
-    q1 = jnp.tanh(sepconv(r1 * ha, xas, wq1h, (wq1xa, wq1xb),
-                          bq1, 1, hmask))
-    h1 = (1 - z1) * ha + z1 * q1
-    zr2 = gate_sigmoid(sepconv(h1, xas, wzr2h, (wzr2xa, wzr2xb),
-                               bzr2, w, vmask))
-    z2, r2 = zr2[:, :c], zr2[:, c:]
-    q2 = jnp.tanh(sepconv(r2 * h1, xas, wq2h, (wq2xa, wq2xb),
-                          bq2, w, vmask))
-    h2 = (1 - z2) * h1 + z2 * q2
+    def vconv(vh, vxs, wh_ref, wx_refs, b_ref):
+        """A (5, 1) gate conv: ``vh`` and each of ``vxs`` is ``(span,
+        image row of the span that tap -2 of the first output row
+        reads)``; a tap is a slice by whole image rows, and rows outside
+        the image are already zero."""
+        acc = jnp.zeros((g, b_ref.shape[1]), jnp.float32)
+        for k in range(_TAPS):
+            for (v, first), wm_ref in zip((vh, *vxs), (wh_ref, *wx_refs)):
+                ch = v.shape[1]
+                acc += _dot(rows_of(v, first + k),
+                            wm_ref[k * ch:(k + 1) * ch, :])
+        return acc.astype(cdt) + b_ref[...]
 
-    hw_g = hg * w
-    klayout.boundary_store(out_refs[0], h2[hw_g:hw_g + g])
+    ha = rows_of(*at(net, 0, _LAG_MOT))
+    xas = (rows_of(*at(inp, 0, _LAG_MOT)),
+           rows_of(*at(mot, _LAG_MOT, _LAG_MOT)))
+    zr1 = gate_sigmoid(hconv(ha, xas, wzr1h, (wzr1xa, wzr1xb), bzr1))
+    z1, r1 = zr1[:, :c], zr1[:, c:]
+    q1 = jnp.tanh(hconv(r1 * ha, xas, wq1h, (wq1xa, wq1xb), bq1))
+    h1 = put("h1", ((1 - z1) * ha + z1 * q1) * inside(_LAG_MOT))
+
+    zr2 = gate_sigmoid(vconv(
+        at(h1, _LAG_MOT, _LAG_ZR2, 2),
+        (at(inp, 0, _LAG_ZR2, 2), at(mot, _LAG_MOT, _LAG_ZR2, 2)),
+        wzr2h, (wzr2xa, wzr2xb), bzr2))
+    z2, r2 = put("z2", zr2[:, :c]), zr2[:, c:]
+    rh = put("rh", r2 * rows_of(*at(h1, _LAG_MOT, _LAG_ZR2)))
+    q2 = jnp.tanh(vconv(
+        at(rh, _LAG_ZR2, _LAG_H2, 2),
+        (at(inp, 0, _LAG_H2, 2), at(mot, _LAG_MOT, _LAG_H2, 2)),
+        wq2h, (wq2xa, wq2xb), bq2))
+    z2 = rows_of(*at(z2, _LAG_ZR2, _LAG_H2))
+    h2 = (1 - z2) * rows_of(*at(h1, _LAG_MOT, _LAG_H2)) + z2 * q2
+    h2 = put("h2", h2 * inside(_LAG_H2) if fh else h2)
+    # The out blocks run ``closing * th`` rows behind the input's.
+    out_lag = -(-halos(fh)[1] // th) * th
+    klayout.boundary_store(out_refs[0],
+                           rows_of(*at(h2, _LAG_H2, out_lag)))
 
     # ---- flow head (mgf): two more 3x3s on the SAME resident h2 -------
     if fh:
-        wfh1, bfh1, wfh2, bfh2 = fh_refs
+        wfh1, bfh1, wfh2, bfh2 = refs[31:35]
+        fh1 = jax.nn.relu(conv3(
+            [(rows_of(*at(h2, _LAG_H2, _LAG_FH1, 1), th + 2), wfh1)],
+            bfh1)) * inside(_LAG_FH1)
+        taps = put("taps", _dot(fh1, wfh2[...]))
+        delta = put("delta", folded_head_conv(taps[...], bfh2, w, w_img, g,
+                                              cdt))
+        klayout.boundary_store(out_refs[1],
+                               rows_of(*at(delta, _LAG_DELTA, out_lag)))
 
-        def gvalid(dy, dx):
-            return tap_valid(colg, growg, w, h_img, dy, dx)
-
-        fh1 = jax.nn.relu(conv2d(gvalid, [(h2, wfh1)], bfh1, 3))
-        delta = folded_head_conv(gvalid, fh1, wfh2, bfh2, w)
-        klayout.boundary_store(out_refs[1], delta[hw_g:hw_g + g])
+    # Keep each stage's trailing rows for the next step, after every
+    # reader of this step's spans: the span moves up by ``th`` rows, at
+    # most ``th`` rows at a time, so that no copy reads rows it writes.
+    for ref in span.values():
+        for r0 in range(0, kept(ref), th):
+            n = min(th, kept(ref) - r0)
+            ref[r0 * w:(r0 + n) * w, :] = ref[(r0 + th) * w:
+                                              (r0 + th + n) * w, :]
 
 
 def _pallas_step(static, net2d, inp2d, flow2d, corr2d, mmats, gmats,
                  fmats):
-    """net2d/inp2d: (B, Hpad*W, C/Cinp); flow2d: (B, Hpad*W, 2);
-    corr2d: (B, Hpad*W, Cc) — all already in the compute dtype; mats
-    pre-packed and cast. Returns (B, Hpad*W, C) or a (h2, delta)
-    pair."""
-    w, h_img, th, interpret, fh = static
-    b, n, c = net2d.shape
+    """net2d/inp2d: (B, H*W, C/Cinp); flow2d: (B, H*W, 2); corr2d:
+    (B, H*W, Cc) — all already in the compute dtype; mats pre-packed and
+    cast. Pads the rows to whole tiles and an image row to whole
+    sublane tiles (every slice the kernel takes of a span then starts on
+    a tile boundary), runs the launch and slices back. Returns
+    (B, H*W, C) or a (h2, delta) pair."""
+    w_img, h_img, th, interpret, fh = static
+    b, _, c = net2d.shape
+    w = _round_up(w_img, _ROW_ALIGN)
+    tiles = -(-h_img // th)
+    closing = -(-halos(fh)[1] // th)
     g = th * w
-    grid = (b, n // g)
-    last = grid[1] - 1
-    hg, hm = halos(fh)
-    nm = -(-hm // th)
-    ng = -(-hg // th)
+    n = tiles * g
+    dtype = net2d.dtype
 
-    kernel = functools.partial(_step_kernel, w=w, h_img=h_img, th=th,
-                               fh=fh)
+    def padded(a2):
+        if w == w_img:      # whole rows only: cheaper on the flat array
+            return jnp.pad(a2, ((0, 0), (0, n - h_img * w), (0, 0)))
+        a4 = a2.reshape(b, h_img, w_img, a2.shape[-1])
+        a4 = jnp.pad(a4, ((0, 0), (0, tiles * th - h_img),
+                          (0, w - w_img), (0, 0)))
+        return a4.reshape(b, n, a2.shape[-1])
 
-    in_specs, operands = [], []
-    for arr, nb in ((corr2d, nm), (flow2d, nm), (net2d, ng), (inp2d, ng)):
-        chn = arr.shape[-1]
-        for k in range(-nb, nb + 1):
-            in_specs.append(pl.BlockSpec(
-                (1, g, chn),
-                lambda bi, ti, k=k: (bi, jnp.clip(ti + k, 0, last), 0)))
-            operands.append(arr)
+    def unpadded(a2):
+        if w == w_img:
+            return a2[:, :h_img * w]
+        a4 = a2.reshape(b, tiles * th, w, a2.shape[-1])
+        return a4[:, :h_img, :w_img].reshape(b, h_img * w_img,
+                                             a2.shape[-1])
+
+    widths = {"cor1": mmats[0].shape[1], "flow": 2,
+              "flo1": mmats[4].shape[1], "cor2": mmats[2].shape[1],
+              "flo2": mmats[6].shape[1], "net": c,
+              "inp": inp2d.shape[-1], "mot": mmats[-1].shape[1] + 2,
+              "h1": c, "rh": c, "z2": c, "h2": c, "taps": 18, "delta": 2}
+    stages = _carry_rows(th, fh)
+    kernel = functools.partial(_step_kernel, w=w, w_img=w_img, h_img=h_img,
+                               th=th, fh=fh, stages=tuple(stages))
+
+    # Every input block is fetched once, in order (the closing steps
+    # name the last block again, which is no new fetch); the output
+    # blocks run ``closing`` steps behind, and the steps before the
+    # first of them leave block 0 in VMEM until it is whole.
+    operands = [padded(a) for a in (corr2d, flow2d, net2d, inp2d)]
+    in_specs = [pl.BlockSpec(
+        (1, g, a.shape[-1]),
+        lambda bi, ti: (bi, jnp.minimum(ti, tiles - 1), 0))
+        for a in operands]
     flat_mats = kernel_mats(mmats) + list(_flatten_mats(gmats))
     if fh:
         wfh1, bfh1, wfh2, bfh2 = fmats
         flat_mats += [wfh1, bfh1, fold_head_taps(wfh2), bfh2]
     in_specs += [_full_spec(m) for m in flat_mats]
 
-    spec_h, shape_h = klayout.query_tiled_out(b, n, c, g, net2d.dtype)
+    def lagged_out(feat):
+        return (pl.BlockSpec(
+            (1, g, feat),
+            lambda bi, ti: (bi, jnp.maximum(ti - closing, 0), 0)),
+            jax.ShapeDtypeStruct((b, n, feat), dtype))
+
+    spec_h, shape_h = lagged_out(c)
     if fh:
-        spec_d, shape_d = klayout.query_tiled_out(b, n, 2, g,
-                                                  net2d.dtype)
+        spec_d, shape_d = lagged_out(2)
         out_specs, out_shape = [spec_h, spec_d], [shape_h, shape_d]
     else:
         out_specs, out_shape = spec_h, shape_h
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, tiles + closing),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(
+            ((rows + th) * w, widths[name]),
+            jnp.float32 if name == "taps" else dtype)
+            for name, rows in stages.items()],
         interpret=interpret,
-        compiler_params=vmem.scan_compiler_params(),
+        # Images are independent; an image's row tiles are walked in
+        # order, each step reading what the last one kept.
+        compiler_params=vmem.scan_compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
         name=klayout.KERNEL_NAMES["step"],
     )(*operands, *flat_mats)
-    return tuple(out) if fh else out
+    return tuple(map(unpadded, out)) if fh else unpadded(out)
 
 
 # ---------------------------------------------------------------------------
@@ -438,27 +638,38 @@ _step.defvjp(_step_fwd, _step_bwd)
 
 def step_vmem_parts(w: int, th: int, dtype_bytes: int, *,
                     flow_head: bool = False) -> dict:
-    """Named scoped-VMEM estimate for one fused launch: Mosaic's
-    calibrated per-row figure over the deep (motion) span of
-    ``TH + 2*hm`` rows — 9 halo rows a side for ``mg``, 11 for ``mgf``.
-    Holds for the canonical widths (C = Cinp = 128, at most 384 corr
-    channels), which ``plan_fusion`` enforces."""
-    _, hm = halos(flow_head)
-    return vmem.scan_rows_parts("step", (th + 2 * hm) * w, dtype_bytes)
+    """Named scoped-VMEM estimate for one fused launch, from Mosaic's
+    own reports for the streamed body (``vmem.STEP_BYTES``): the
+    weights, the rows the stages keep for the next grid step (by the
+    padded width alone: they are image rows, however tall the tile) and
+    what is live of a grid step's ``th`` rows. Either depth is held to
+    the 'mgf' figures. Holds for
+    the canonical widths (C = Cinp = 128, at most 384 corr channels),
+    which ``plan_fusion`` enforces."""
+    weights, per_column, per_row = vmem.STEP_BYTES[dtype_bytes]
+    w = _round_up(w, _ROW_ALIGN)
+    return {"weights": weights, "rows_kept": per_column * w,
+            "tile_rows_live": per_row * th * w}
 
 
 def choose_rows(h_img: int, w: int, cc: int, dtype_bytes: int, *,
                 flow_head: bool = False) -> int | None:
-    """Largest admissible row tile for one fused launch under the
-    shared (16, 8, 4) ladder and ``step_vmem_parts``; None → this
-    fusion depth doesn't fit (the caller steps down mgf → mg →
-    two-launch chain). At Sintel eval shapes bf16 admits TH=16 at both
-    depths; at 1080p (W=240) neither admits any — asserted in
-    tests/test_step_pallas.py."""
-    return vmem.choose_rows(
-        _ROW_LADDER, w,
-        lambda th: step_vmem_parts(w, th, dtype_bytes,
-                                   flow_head=flow_head))
+    """The row tile of one fused launch, from the shape alone: of the
+    rungs of the ladder that fit ``step_vmem_parts``, the one whose grid
+    streams least — every stage computes ``grid_steps * th`` rows for
+    the image's ``h_img``, and a grid step costs about half a row more
+    (Sintel 'mgf' on the chip, batch 128: 80 rows in 5 steps at TH 16
+    took 63.6 ms with the wrapper's pad to 64 rows, 72 in 9 at TH 8
+    53.0 ms); the taller rung on a tie. None → no rung fits (the
+    caller falls back to the two-launch chain)."""
+    admitted = [th for th in _ROW_LADDER
+                if vmem.fits(
+                    step_vmem_parts(w, th, dtype_bytes,
+                                    flow_head=flow_head),
+                    vmem.SCAN_LIMIT_BYTES)]
+    return min(admitted, default=None,
+               key=lambda th: (grid_steps(h_img, th, flow_head)
+                               * (2 * th + 1), -th))
 
 
 def resolve_mode() -> str:
@@ -474,11 +685,11 @@ def plan_fusion(net, inp, corr, flow, want_flow_head: bool,
     ``'mg'`` or ``'mgf'``.
 
     '0' → None always (byte-identical to today). '1' → force: off-TPU
-    runs the interpreter (parity tooling); on TPU raises if even 'mg'
-    fits no tile. 'auto' → fuse only on a real TPU backend, preferring
-    'mgf' where wanted and admissible, stepping down to 'mg', and
-    falling back to None with a LOUD ``vmem.log_fallback`` when the
-    ladder rejects the shape entirely.
+    runs the interpreter (parity tooling); on TPU raises if the shape
+    fits no tile. 'auto' → fuse only on a real TPU backend, at the depth
+    that is wanted (the streamed body's working rows do not grow with
+    the depth, so no shape admits 'mg' alone), falling back to None with
+    a LOUD ``vmem.log_fallback`` when the ladder rejects the shape.
     """
     if mode is None:
         mode = resolve_mode()
@@ -508,19 +719,16 @@ def plan_fusion(net, inp, corr, flow, want_flow_head: bool,
     cinp = inp.shape[-1]
     cc = corr.shape[-1]
     d = jnp.dtype(net.dtype).itemsize
-    # The widths the VMEM figure was calibrated at (vmem._ROW_BYTES).
+    # The widths the VMEM figures were read at (vmem.STEP_BYTES).
     widths_ok = c == 128 and cinp == 128 and cc <= _MAX_CORR_CHANNELS
-    if widths_ok and want_flow_head and choose_rows(
-            hh, ww, cc, d, flow_head=True):
-        return "mgf"
-    if widths_ok and choose_rows(hh, ww, cc, d, flow_head=False):
-        return "mg"
+    if widths_ok and choose_rows(hh, ww, cc, d,
+                                 flow_head=want_flow_head):
+        return "mgf" if want_flow_head else "mg"
     if mode == "1":
         raise ValueError(
             f"{STEP_FLAG}=1 but shape (H={hh}, W={ww}, C={c}, "
             f"Ccorr={cc}, dtype={jnp.dtype(net.dtype).name}) admits no "
-            f"row tile even for the 'mg' fusion; use auto to fall back "
-            f"to the two-launch chain")
+            f"row tile; use auto to fall back to the two-launch chain")
     vmem.log_fallback(
         STEP_FLAG,
         f"(H={hh}, W={ww}, C={c}, Ccorr={cc}, "
@@ -546,7 +754,8 @@ def fused_step(net, inp, corr, flow, mmats, gmats, fmats=None, *,
       dtype: compute dtype (the flax module's); default ``net.dtype``.
       interpret: force Pallas interpret mode (defaults to True
         off-TPU).
-      th: row-tile override for tests; default = largest admissible.
+      th: row-tile override for tests (a real launch takes only a rung
+        of the ladder); default = ``choose_rows``.
 
     Returns ``(B, H, W, C)`` h2 in ``net.dtype`` — or, with ``fmats``,
     an ``(h2, delta_flow)`` pair with ``delta_flow (B, H, W, 2)`` in
@@ -569,18 +778,17 @@ def fused_step(net, inp, corr, flow, mmats, gmats, fmats=None, *,
             th = choose_rows(hh, ww, cc, cdt.itemsize,
                              flow_head=fh) or _ROW_LADDER[-1]
     if not interpret:
+        if th not in _ROW_LADDER:
+            raise ValueError(
+                f"fused step kernel: th={th} is no rung of {_ROW_LADDER}, "
+                f"the tiles the kernel is held to on the chip")
         vmem.preflight(
             step_vmem_parts(ww, th, cdt.itemsize, flow_head=fh),
             f"fused step kernel (th={th}, w={ww}, flow_head={fh})",
             vmem.SCAN_LIMIT_BYTES)
 
-    hpad = _round_up(hh, th)
-
     def to2d(a):
-        a2 = a.astype(cdt).reshape(b, hh * ww, a.shape[-1])
-        if hpad != hh:
-            a2 = jnp.pad(a2, ((0, 0), (0, (hpad - hh) * ww), (0, 0)))
-        return a2
+        return a.astype(cdt).reshape(b, hh * ww, a.shape[-1])
 
     net2d, inp2d, flow2d, corr2d = map(to2d, (net, inp, flow, corr))
     mmats = tuple(m.astype(cdt) for m in mmats)
@@ -595,6 +803,6 @@ def fused_step(net, inp, corr, flow, mmats, gmats, fmats=None, *,
                 fmats)
     if fh:
         h2, delta = out
-        return (h2[:, :hh * ww].reshape(b, hh, ww, c).astype(out_dt),
-                delta[:, :hh * ww].reshape(b, hh, ww, 2))
-    return out[:, :hh * ww].reshape(b, hh, ww, c).astype(out_dt)
+        return (h2.reshape(b, hh, ww, c).astype(out_dt),
+                delta.reshape(b, hh, ww, 2))
+    return out.reshape(b, hh, ww, c).astype(out_dt)

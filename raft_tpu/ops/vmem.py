@@ -72,27 +72,42 @@ SCAN_LIMIT_BYTES = 100 * 2 ** 20
 #: (the v5e VPU computes the elementwise tail in f32 either way). The
 #: motion and step figures were re-read when ``convf1`` and the flow
 #: head's last conv stopped taking an MXU pass a tap: the 49 + 9 per-tap
-#: temporaries had been a third of both (motion 24.3 / 27.5 and step
-#: 25.0 / 33.8 KiB/row before). Largest observed, KiB/row: GRU 13.4
-#: (bf16) / 19.3 (f32); motion 16.0 / 21.5; fused step, either depth,
-#: over its motion span 18.0 / 25.3 (both at W=62; 15.9 / 22.0 at
-#: Sintel, 13.8-15.4 at W=240) — rounded up, the bf16 step by a tenth:
-#: the narrowest probed width reads highest, and at W=240 the figure
-#: refuses a TH=4 'mg' tile that Mosaic fits in 71 MiB, where a tile
-#: keeps 4 rows of the 22 it computes and the two-launch chain is the
-#: better program anyway.
+#: temporaries had been a third of it (motion 24.3 / 27.5 before).
+#: Largest observed, KiB/row: GRU 13.4 (bf16) / 19.3 (f32); motion
+#: 16.0 / 21.5 (at W=62) — rounded up.
 _ROW_BYTES = {
     "gru": {2: 14 * 1024, 4: 24 * 1024},
     "motion": {2: 17 * 1024, 4: 23 * 1024},
-    "step": {2: 20 * 1024, 4: 26 * 1024},
+}
+
+#: The fused step kernel's scoped VMEM by compute-dtype width:
+#: ``(weights, bytes a column of the padded W for the rows the stages
+#: keep, bytes a flattened row of the tile's TH x W)``. Its body streams:
+#: a stage computes a grid step's TH rows into a VMEM span that also
+#: holds the image rows its readers need above them, however tall the
+#: tile, so the footprint is no longer proportional to one span. Read
+#: the same way as ``_ROW_BYTES`` (1 GiB limit, batch 2, 'mgf'; 'mg'
+#: reads 3-5 MiB less) at 55x128, 46x62, 48x156, 135x240 and 30x64, TH
+#: in {4, 8, 16}: bf16 16.2 / 24.3 / 40.3 MiB at Sintel (75.2 MiB at TH
+#: 16 when every tile recomputed its halo), 25.4 / 40.8 / 70.8 at
+#: 1080p; f32 49.1 / 81.6 at Sintel TH 8 / 16, 82.4 at 1080p TH 8.
+#: Fitted: bf16 5.6 MiB + 22.5 KiB x W + 16 KiB x TH x W, f32 11 MiB +
+#: 45 KiB x W + 32.5 KiB x TH x W — each rounded up by about a tenth;
+#: the estimate covers every probe by 4-25 %.
+STEP_BYTES = {
+    2: (6 * 2 ** 20, 24 * 1024, 18 * 1024),
+    4: (12 * 2 ** 20, 48 * 1024, 36 * 1024),
 }
 
 
-def scan_compiler_params():
+def scan_compiler_params(dimension_semantics=None):
     """The TPU compiler params every scan-body ``pallas_call`` passes:
-    the explicit scoped-VMEM limit their admission is sized from."""
+    the explicit scoped-VMEM limit their admission is sized from, and
+    for a kernel whose grid steps depend on each other which axes may
+    run in any order and which in order."""
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(vmem_limit_bytes=SCAN_LIMIT_BYTES)
+    return pltpu.CompilerParams(vmem_limit_bytes=SCAN_LIMIT_BYTES,
+                                dimension_semantics=dimension_semantics)
 
 
 def total_bytes(parts: Mapping[str, int]) -> int:

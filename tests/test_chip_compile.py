@@ -227,8 +227,9 @@ CASES = {
     "motion_bf16_sintel": (lambda: _motion(BF16, SINTEL), "motion"),
     "step_mg_bf16_chairs": (lambda: _step(BF16, CHAIRS, False), "step"),
     "step_mgf_bf16_sintel": (lambda: _step(BF16, SINTEL, True), "step"),
-    "step_mgf_bf16_th8_sintel": (lambda: _step(BF16, SINTEL, True, th=8),
-                                 "step"),
+    "step_mgf_bf16_th16_sintel": (lambda: _step(BF16, SINTEL, True, th=16),
+                                  "step"),
+    "step_mgf_f32_sintel": (lambda: _step(F32, SINTEL, True), "step"),
     "msda_dense_352x480": (_msda_dense, "msda_fwd"),
     "expert_gmm_bf16_131072_rows": (lambda: _expert_gmm(131072),
                                     "expert_gmm"),
@@ -237,16 +238,16 @@ CASES = {
     "refuse_expert_gmm_tiles_of_2048": (
         lambda: _expert_gmm(131072, (2048, 2048, 1536)), ValueError),
     "refuse_attn_blocks_of_2048": (lambda: _attn(8192, 2048), ValueError),
-    # Refused by the static admission rule, before Mosaic: the fused step
-    # at a 1080p feature map (no tile fits), and tiles Mosaic was seen to
-    # take more than the limit for (f32 GRU TH=16 at 1080p: 104.7 MiB;
-    # f32 'mgf' TH=16 at Sintel: 102.4 MiB).
-    "refuse_step_mgf_bf16_1080p": (lambda: _step(BF16, HD1080, True),
-                                   ValueError),
+    # Refused by the static admission rule, before Mosaic: tiles Mosaic
+    # was seen to take more than the limit for (f32 GRU TH=16 at 1080p:
+    # 104.7 MiB) or could not place at all (the fused step's f32 'mgf'
+    # TH=16 at 1080p: RESOURCE_EXHAUSTED under a 1 GiB limit. Its
+    # streamed body fits both rungs at Sintel and, in bf16, at 1080p:
+    # 40.8 / 70.8 MiB at TH 8 / 16).
     "refuse_gru_f32_th16_1080p": (lambda: _gru(F32, HD1080, th=16),
                                   ValueError),
-    "refuse_step_mgf_f32_th16_sintel": (
-        lambda: _step(F32, SINTEL, True, th=16), ValueError),
+    "refuse_step_mgf_f32_th16_1080p": (
+        lambda: _step(F32, HD1080, True, th=16), ValueError),
 }
 
 

@@ -7,19 +7,21 @@ three levels:
 * **vs the two-launch chain** (``motion_pallas.motion_encoder`` →
   ``gru_pallas.sepconv_gru``): BIT-exact at every row tile, both
   fusion depths. Same shifted-matmul taps, same masks, same cast
-  points — fusing the handoff must not move a single bit.
+  points — fusing the handoff, and walking an image's row tiles in
+  order with every stage's trailing rows carried in VMEM, must not
+  move a single bit.
 * **vs the conv path** (``BasicUpdateBlock`` with all kernels off):
   within the ISSUE acceptance bounds (f32 forward ≤1e-5, grads ≤2e-4),
   forward and gradients, through the custom VJP and all three weight
   packers.
 * **dispatch contract** (``RAFT_STEP_PALLAS``): '0' byte-identical,
   '1' forced (raises on TPU when inadmissible), auto fuses only on TPU
-  with a LOUD logged fallback; plus the pinned VMEM admission table at
-  the Sintel-eval operating point (Mosaic's own figure for the body
-  without two-channel taps: bf16 admits TH=16 and f32 TH=8, at both
-  depths).
+  with a LOUD logged fallback; plus the pinned VMEM admission table
+  (Mosaic's own figures for the streamed body: every rung fits at
+  Sintel in either dtype, and the rung is the one that streams least).
 * **the pass census**: from the traced program, that no product with a
-  contraction or an output of 2 is left, and what a span row streams.
+  contraction or an output of 2 is left, that every product streams
+  exactly a grid step's ``th`` rows, and what a Sintel image streams.
 """
 
 import logging
@@ -60,21 +62,27 @@ def _packers(params):
     return mmats, gmats, fmats
 
 
+def _inputs(w=W, seed=1):
+    """(net, inp, corr, flow) for a batch of B images of H x ``w``."""
+    rng = np.random.default_rng(seed)
+    net = jnp.asarray(np.tanh(rng.standard_normal((B, H, w, C))),
+                      jnp.float32)
+    inp = jnp.asarray(rng.standard_normal((B, H, w, C)), jnp.float32)
+    corr = jnp.asarray(rng.standard_normal((B, H, w, CC)), jnp.float32)
+    flow = jnp.asarray(3.0 * rng.standard_normal((B, H, w, 2)),
+                       jnp.float32)
+    return net, inp, corr, flow
+
+
 @pytest.fixture(scope="module")
 def update_setup():
     """Full BasicUpdateBlock + inputs at a deliberately awkward shape
-    (odd W, H not a row-tile multiple, so every halo direction and the
+    (odd W, H not a row-tile multiple, so every carry and the
     padded-row masks are live through the 9/11-row receptive field)."""
     from raft_tpu.models.update import BasicUpdateBlock
 
     model = BasicUpdateBlock()
-    rng = np.random.default_rng(1)
-    net = jnp.asarray(np.tanh(rng.standard_normal((B, H, W, C))),
-                      jnp.float32)
-    inp = jnp.asarray(rng.standard_normal((B, H, W, C)), jnp.float32)
-    corr = jnp.asarray(rng.standard_normal((B, H, W, CC)), jnp.float32)
-    flow = jnp.asarray(3.0 * rng.standard_normal((B, H, W, 2)),
-                       jnp.float32)
+    net, inp, corr, flow = _inputs()
     vs = model.init(jax.random.PRNGKey(1), net, inp, corr, flow)
     return model, vs, net, inp, corr, flow
 
@@ -130,29 +138,58 @@ def _real_width_mats():
 
 
 class TestForwardParity:
-    @pytest.mark.parametrize("th", [4, 5, 8])
-    @pytest.mark.parametrize("fh", [False, True])
+    @pytest.mark.parametrize("w,th,fh,foreign", [
+        # H = 9 is no multiple of any of these tiles.
+        (W, 8, False, False), (W, 8, True, False),    # 2 tiles + 2 closing
+        # th under the outputs' lag (9 / 11 rows): 3 tiles and 3 closing
+        # steps, stages that keep more rows than a step computes
+        (W, 4, False, False), (W, 4, True, False),
+        # a one-tile image, of a width that needs no padding
+        (16, 16, True, False),
+        # the image before it in the batch leaves nothing behind
+        (W, 8, True, True), (W, 4, False, True)])
     def test_fused_is_bitexact_vs_chained_kernels(self, update_setup,
-                                                  th, fh):
+                                                  w, th, fh, foreign):
         """The whole point of the fusion: identical arithmetic to the
         two-launch motion→GRU chain, with the handoff buffer gone. h2
-        must not move a bit at ANY row tile (multi-neighbor halos at
-        th=4 assemble ceil(11/4)=3 blocks per side for 'mgf')."""
-        _, vs, net, inp, corr, flow = update_setup
+        must not move a bit at ANY row tile, although the streamed body
+        computes each row of each stage once, in a product of ``th``
+        rows, where the chained kernels compute it with its tile's
+        halo. (An image row takes 16 flattened rows in the fused
+        kernel, so its products have 64 rows or more; under 51 XLA's
+        CPU dot sums a contraction of 256 in another order, for any
+        kernel alike.) ``foreign``: the first image of the
+        batch is filled with 1e4, and the second must come out, bit for
+        bit, as it does alone — the carries are cut at an image's first
+        step."""
+        _, vs, *_ = update_setup
+        net, inp, corr, flow = args = _inputs(w)
         mmats, gmats, fmats = _packers(vs["params"])
+
+        def fused(net, inp, corr, flow):
+            return step_pallas.fused_step(net, inp, corr, flow, mmats,
+                                          gmats, fmats if fh else None,
+                                          interpret=True, th=th)
+
+        if foreign:
+            got = fused(*(a.at[0].set(1e4) for a in args))
+            want = fused(*(a[1:] for a in args))
+            for a, b in zip(got if fh else (got,),
+                            want if fh else (want,)):
+                assert np.isfinite(np.asarray(b)).all()
+                np.testing.assert_array_equal(np.asarray(a[1:]),
+                                              np.asarray(b))
+            return
         mot = motion_pallas.motion_encoder(flow, corr, mmats,
                                            interpret=True, th=th)
         want_h2 = gru_pallas.sepconv_gru(net, (inp, mot), gmats,
                                          interpret=True, th=th)
-        out = step_pallas.fused_step(net, inp, corr, flow, mmats,
-                                     gmats, fmats if fh else None,
-                                     interpret=True, th=th)
+        out = fused(*args)
         got_h2 = out[0] if fh else out
         np.testing.assert_array_equal(np.asarray(got_h2),
                                       np.asarray(want_h2))
 
-    @pytest.mark.parametrize("th,ti", [(4, 0), (4, 1), (4, 2), (5, 1),
-                                       (8, 0), (8, 1)])
+    @pytest.mark.parametrize("th,ti", [(4, 0), (4, 1), (4, 2), (8, 1)])
     def test_packed_convf1_matches_the_49_tap_sum(self, th, ti):
         """``convf1`` as one contraction over its 98 tap-channels vs the
         49 shifted-masked K = 2 products it replaces, at float32 on one
@@ -160,9 +197,9 @@ class TestForwardParity:
         tile, out-of-image rows holding garbage). The patch operand is
         the 49 masked copies side by side, value for value; the product
         differs only by the order of 98 float32 partial sums (measured
-        over these six cases: at most 6.7e-6 on outputs of magnitude up
-        to 18, four parts in ten million; asserted at three times
-        that)."""
+        over these four cases and two more tiles at PR 34: at most
+        6.7e-6 on outputs of magnitude up to 18, four parts in ten
+        million; asserted at three times that)."""
         rng = np.random.default_rng(10 * th + ti)
         hm = step_pallas.halos(True)[1]
         fac, col, grow = _tile_span(rng, th, ti, hm, 2)
@@ -190,9 +227,12 @@ class TestForwardParity:
     @pytest.mark.parametrize("th,ti", [(4, 0), (4, 1), (4, 2), (8, 1)])
     def test_folded_head_conv_is_the_9_tap_sum(self, th, ti):
         """The flow head's 3x3 ``256 -> 2`` conv as one product and nine
-        shifted, masked adds of its float32 columns vs the nine N = 2
-        products it replaces: the same terms in the same order, so not a
-        bit moves."""
+        shifted adds of its float32 columns vs the nine N = 2 products
+        it replaces: the same terms in the same order, so not a bit
+        moves. The streamed body zeroes ``fh1``'s rows outside the image
+        where it produces them and hands the product over the output
+        rows and one image row each side; the nine-tap sum masks each
+        tap on a span whose outside rows hold garbage."""
         rng = np.random.default_rng(20 * th + ti)
         hg = step_pallas.halos(True)[0]
         fh1, col, grow = _tile_span(rng, th, ti, hg, 256)
@@ -204,9 +244,13 @@ class TestForwardParity:
             return motion_pallas.tap_valid(col, grow, W, H, dy, dx)
 
         want = motion_pallas.conv_taps(valid, [(fh1, wfh2)], bfh2, 3, W)
+        taps = jnp.dot(jnp.where((grow >= 0) & (grow < H), fh1, 0.0),
+                       step_pallas.fold_head_taps(wfh2),
+                       preferred_element_type=jnp.float32)
         got = step_pallas.folded_head_conv(
-            valid, fh1, step_pallas.fold_head_taps(wfh2), bfh2, W)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+            taps, bfh2, W, W, fh1.shape[0] - 2 * W, jnp.float32)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want[W:-W]))
 
     def test_mgf_delta_matches_conv_flow_head(self, update_setup):
         """The in-kernel flow head vs the flax FlowHead on the SAME h2
@@ -340,8 +384,8 @@ class TestGradParity:
 
 class TestPassCensus:
     """The mechanism's counter, read from the program itself: what the
-    kernel streams through the MXU, in pass units of (span row) x
-    (128-wide slice of the contraction) x (128-wide slice of the
+    kernel streams through the MXU, in pass units of (row of a product)
+    x (128-wide slice of the contraction) x (128-wide slice of the
     output), at RAFT-large widths."""
 
     MOTION_UNITS = 79     # 6 + 36 + 1 (convf1: 49 before) + 9 + 27
@@ -370,12 +414,29 @@ class TestPassCensus:
         assert all(k > 2 and n > 2 for _, k, n in dots), dots
         # 38 motion + 60 GRU (+ 10 flow head); 86 + 60 (+ 18) before
         assert len(dots) == (108 if fh else 98)
-        hg, hm = step_pallas.halos(fh)
-        rows_m, rows_g = (th + 2 * hm) * W, (th + 2 * hg) * W
-        assert {r for r, _, _ in dots} == {rows_m, rows_g}
-        assert self._units(dots, rows_m) == self.MOTION_UNITS
-        assert self._units(dots, rows_g) == self.GRU_UNITS + (
-            self.HEAD_UNITS if fh else 0)
+        # Every product streams exactly the grid step's th rows (an image
+        # row padded to whole sublane tiles: 7 -> 16 here, 62 -> 64 at
+        # chairs, 128 as it is at Sintel): no stage computes a row for a
+        # neighbouring tile's sake (the motion products ran over
+        # th + 2*hm rows and the GRU's over th + 2*hg).
+        assert {r for r, _, _ in dots} == {th * 16}
+        assert self._units(dots, th * 16) == (
+            self.MOTION_UNITS + self.GRU_UNITS
+            + (self.HEAD_UNITS if fh else 0))
+
+    def test_a_sintel_image_streams_its_rows_and_the_lag(self):
+        """The number ISSUE 36's prediction rests on: what a 55-row
+        Sintel feature map streams at the rung ``choose_rows`` picks.
+        Self-contained tiles streamed 4 x (79 x 38 + 110 x 28) = 24,328
+        units at TH 16 (442 a kept row); a stage now streams the
+        image's tiles and the closing steps its outputs lag by, and
+        189 a kept row is the floor."""
+        th = step_pallas.choose_rows(55, 128, 324, 2, flow_head=True)
+        per_row = self.MOTION_UNITS + self.GRU_UNITS + self.HEAD_UNITS
+        streamed = {t: step_pallas.grid_steps(55, t, True) * t * per_row
+                    for t in (16, 8)}
+        assert streamed == {16: 5 * 16 * 189, 8: 9 * 8 * 189}
+        assert (th, streamed[th]) == (8, 13608)         # 247 a kept row
 
     def test_chained_motion_kernel_shares_the_packing(self):
         mm, _, _ = _real_width_mats()
@@ -417,11 +478,12 @@ class TestDispatch:
         monkeypatch.delenv("RAFT_STEP_PALLAS", raising=False)
         assert plan(net, inp, corr, flow, True) is None
 
-    def test_auto_on_tpu_steps_down_mgf_to_mg(self, monkeypatch):
-        """KITTI f32 (48x156 features) on a (faked) TPU backend: the
-        flow-head depth fits no tile, so auto honestly steps down to
-        'mg' instead of rejecting fusion outright; Sintel admits 'mgf'
-        in either dtype."""
+    def test_auto_on_tpu_fuses_the_depth_that_is_wanted(self, monkeypatch):
+        """KITTI f32 (48x156 features) on a (faked) TPU backend: while
+        every tile recomputed its halo the flow-head depth fitted no
+        tile there and auto stepped down to 'mg'; the streamed body's
+        working rows do not grow with the depth, so a shape that admits
+        a rung admits it at the depth that is wanted."""
         monkeypatch.delenv("RAFT_STEP_PALLAS", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -430,7 +492,7 @@ class TestDispatch:
                          for c in (C, C, 324, 2))
 
         kitti = sds((48, 156), jnp.float32)
-        assert step_pallas.plan_fusion(*kitti, True) == "mg"
+        assert step_pallas.plan_fusion(*kitti, True) == "mgf"
         assert step_pallas.plan_fusion(*kitti, False) == "mg"
         for dtype in (jnp.float32, jnp.bfloat16):
             assert step_pallas.plan_fusion(*sds((55, 128), dtype),
@@ -444,13 +506,13 @@ class TestDispatch:
                                     mode="1")
 
     def test_forced_inadmissible_on_tpu_raises(self, monkeypatch):
-        """'1' on a TPU backend must never silently degrade: when even
-        the 'mg' depth fits no tile (a 1080p feature map), the forced
-        arm dies loudly at trace time."""
+        """'1' on a TPU backend must never silently degrade: when no
+        tile fits (an 8K frame's float32 feature map; 1080p fits since
+        the body streams), the forced arm dies loudly at trace time."""
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
         def sds(c):
-            return jax.ShapeDtypeStruct((1, 135, 240, c), jnp.float32)
+            return jax.ShapeDtypeStruct((1, 540, 960, c), jnp.float32)
 
         with pytest.raises(ValueError, match="admits no row tile"):
             step_pallas.plan_fusion(sds(C), sds(C), sds(324),
@@ -464,8 +526,8 @@ class TestDispatch:
         monkeypatch.delenv("RAFT_STEP_PALLAS", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-        def sds(c):     # a 1080p feature map: too wide for any tile
-            return jax.ShapeDtypeStruct((1, 135, 240, c), jnp.float32)
+        def sds(c):     # an 8K frame's feature map: too wide for any tile
+            return jax.ShapeDtypeStruct((1, 540, 960, c), jnp.float32)
 
         with caplog.at_level(logging.WARNING,
                              logger="raft_tpu.ops.vmem"):
@@ -473,7 +535,7 @@ class TestDispatch:
                                            sds(2), False) is None
         assert "RAFT_STEP_PALLAS=auto" in caplog.text
         assert "falling back to the XLA path" in caplog.text
-        assert "H=135, W=240" in caplog.text
+        assert "H=540, W=960" in caplog.text
         assert "admission budget" in caplog.text
 
     @pytest.mark.multidevice
@@ -534,48 +596,78 @@ class TestDispatch:
 class TestEligibility:
     def test_halos_compose_across_the_chain(self):
         """GRU ±4 (+flow head ±2) of valid x; motion inputs another ±5
-        beyond wherever its output must be valid."""
+        beyond wherever its output must be valid. No tile assembles
+        these rows any more: the outputs run ``hm`` rows behind the
+        input blocks, which costs an image ``ceil(hm / th)`` closing
+        grid steps, and the stages keep in VMEM what their readers
+        need above a step's rows."""
         assert step_pallas.halos(False) == (4, 9)
         assert step_pallas.halos(True) == (6, 11)
+        assert [step_pallas.grid_steps(55, th, True)
+                for th in (16, 8, 4)] == [4 + 1, 7 + 2, 14 + 3]
+        assert [step_pallas.grid_steps(46, th, False)
+                for th in (16, 8, 4)] == [3 + 1, 6 + 2, 12 + 3]
+        rows = step_pallas._carry_rows(16, True)
+        assert (rows["flow"], rows["cor1"], rows["inp"], rows["h1"],
+                rows["h2"], rows["delta"]) == (6, 5, 11, 4, 7, 5)
+        # 63 image rows in all, most of them 128 lanes wide: ~2.4 MB at
+        # W = 128 in bfloat16
+        assert sum(rows.values()) == 63
 
     def test_sintel_admission_table(self):
-        """The pinned envelope at Sintel-eval feature shapes (H=55,
-        W=128, Ccorr=4*81=324) under the explicit 100 MiB scoped limit
-        and the Mosaic-calibrated estimate: bf16 admits TH=16 at both
-        depths (Mosaic takes up to 67.7 / 75.2 MiB there; with the 49
-        two-channel taps it took 95 / 106 and TH=8 was the rung); f32
-        admits TH=8 at both (71.6 / 79.4 MiB; 93.6 / 102.4 at TH=16);
-        KITTI bf16 (48x156) TH=8 at both; at 1080p (W=240) nothing
-        admits — auto steps down / falls back (logged) rather than OOM
-        Mosaic."""
-        assert step_pallas.choose_rows(55, 128, 324, 2) == 16
+        """The pinned envelope under the explicit 100 MiB scoped limit
+        and Mosaic's figures for the streamed body. Every rung fits at
+        Sintel-eval feature shapes (H=55, W=128, Ccorr=4*81=324) in
+        either dtype (bf16 'mgf' TH=16 took 75.2 MiB while every tile
+        recomputed its halo and takes 40.3; f32 TH=16 took 102.4 and
+        takes 81.6), so the rung is the one whose grid streams least:
+        TH=8 for Sintel's 55 rows (72 rows a stage against 80 at
+        TH=16), TH=16 for chairs' 46 and KITTI's 48 (64 rows either
+        way), TH=8 for 1080p's 135, which is admitted now (f32 too, up
+        to TH=8). The ladder has no TH=4: that rung reads wrong on the
+        chip, and nothing that fits a rung needs it."""
+        assert step_pallas._ROW_LADDER == (16, 8)
+        assert step_pallas.choose_rows(55, 128, 324, 2) == 8
         assert step_pallas.choose_rows(55, 128, 324, 2,
-                                       flow_head=True) == 16
+                                       flow_head=True) == 8
         assert step_pallas.choose_rows(55, 128, 324, 4) == 8
         assert step_pallas.choose_rows(55, 128, 324, 4,
                                        flow_head=True) == 8
         assert step_pallas.choose_rows(46, 62, 324, 2) == 16
         assert step_pallas.choose_rows(48, 156, 324, 2,
+                                       flow_head=True) == 16
+        assert step_pallas.choose_rows(48, 156, 324, 4,
                                        flow_head=True) == 8
-        assert step_pallas.choose_rows(135, 240, 324, 2) is None
+        assert step_pallas.choose_rows(135, 240, 324, 2) == 8
+        assert step_pallas.choose_rows(135, 240, 324, 4,
+                                       flow_head=True) == 8
+        assert not vmem.fits(step_pallas.step_vmem_parts(240, 16, 4),
+                             vmem.SCAN_LIMIT_BYTES)
+        assert step_pallas.choose_rows(540, 960, 324, 4) is None
 
-    @pytest.mark.parametrize("th,dtype_bytes,flow_head,mosaic_mib", [
-        (4, 2, False, 40.18), (8, 2, True, 57.50),
-        (16, 2, False, 67.74), (16, 2, True, 75.23),
-        (8, 4, True, 79.39), (16, 4, True, 102.37)])
-    def test_estimate_covers_what_mosaic_reported(self, th, dtype_bytes,
+    @pytest.mark.parametrize("w,th,dtype_bytes,flow_head,mosaic_mib", [
+        (128, 4, 2, True, 16.19), (128, 8, 2, True, 24.34),
+        (128, 16, 2, False, 35.68), (128, 16, 2, True, 40.34),
+        (128, 8, 4, True, 49.09), (128, 16, 4, True, 81.59),
+        (62, 16, 2, True, 22.48), (156, 8, 2, True, 29.05),
+        (240, 16, 2, True, 70.80), (240, 8, 4, True, 82.41),
+        (240, 16, 4, True, None)])
+    def test_estimate_covers_what_mosaic_reported(self, w, th, dtype_bytes,
                                                   flow_head, mosaic_mib):
         """The phase-peak estimate admitted Sintel bf16 'mg' TH=4 at
         12.8 MiB under a 13 MiB budget where Mosaic needed 45.4 MiB.
         The calibrated estimate is at least what the compiler reported
         (for the present body, compiled for a described v5e under a
-        1 GiB limit) at every probed tile; the tiles the cells ride
-        (bf16 TH=8 and TH=16) are admitted, and a tile Mosaic takes more
-        than the limit for (f32 'mgf' TH=16 at 102.4 MiB) is not."""
+        1 GiB limit) at every probed tile and width, and inside the
+        limit there: the tiles the cells ride (Sintel bf16 TH=8, chairs
+        TH=16) are admitted. A tile the compiler could not place at all
+        (f32 'mgf' TH=16 at 1080p: RESOURCE_EXHAUSTED) is refused."""
         est = vmem.total_bytes(step_pallas.step_vmem_parts(
-            128, th, dtype_bytes, flow_head=flow_head))
-        assert est >= mosaic_mib * 2**20
-        assert (est <= vmem.SCAN_LIMIT_BYTES) == (mosaic_mib < 100)
+            w, th, dtype_bytes, flow_head=flow_head))
+        if mosaic_mib is None:
+            assert est > vmem.SCAN_LIMIT_BYTES
+        else:
+            assert mosaic_mib * 2**20 <= est <= vmem.SCAN_LIMIT_BYTES
 
     def test_small_shapes_admit_deeper_fusion(self):
         """Smaller operating points ride the top rung at the 'mgf'
@@ -587,17 +679,17 @@ class TestEligibility:
     def test_fused_step_preflights_real_launches(self, update_setup):
         """fused_step(interpret=False) trips the itemized VMEM
         preflight before any pallas_call for an over-budget shape (a
-        1080p-wide map: no rung fits)."""
+        map as wide as an 8K frame's: no rung fits)."""
         _, vs, *_ = update_setup
         mmats, gmats, fmats = _packers(vs["params"])
         rng = np.random.default_rng(2)
-        net = jnp.asarray(rng.standard_normal((1, 8, 240, C)),
+        net = jnp.asarray(rng.standard_normal((1, 8, 960, C)),
                           jnp.float32)
-        inp = jnp.asarray(rng.standard_normal((1, 8, 240, C)),
+        inp = jnp.asarray(rng.standard_normal((1, 8, 960, C)),
                           jnp.float32)
-        corr = jnp.asarray(rng.standard_normal((1, 8, 240, CC)),
+        corr = jnp.asarray(rng.standard_normal((1, 8, 960, CC)),
                            jnp.float32)
-        flow = jnp.asarray(rng.standard_normal((1, 8, 240, 2)),
+        flow = jnp.asarray(rng.standard_normal((1, 8, 960, 2)),
                            jnp.float32)
         with pytest.raises(ValueError, match="VMEM"):
             step_pallas.fused_step(net, inp, corr, flow, mmats, gmats,
