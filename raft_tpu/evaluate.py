@@ -31,7 +31,8 @@ from raft_tpu.data import datasets, frame_utils
 from raft_tpu.families import FLOW_FAMILIES, family_of
 from raft_tpu.utils.compile_count import xla_compile_count
 from raft_tpu.utils.padder import InputPadder
-from raft_tpu.utils.profiling import host_timer
+from raft_tpu.utils.profiling import (host_timer, slow_unit_lines,
+                                       unit_accounts)
 from raft_tpu.utils.staging import StagingArena
 from raft_tpu.utils.warm_start import forward_interpolate
 
@@ -889,8 +890,13 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
     the generator at that yield; ``arena_fresh``: how many of the
     batch's two buffers had to be allocated; ``ahead``: 1 where the
     batch was dispatched while an earlier one's outputs were pending
-    (0 for a pass's first batch and in the synchronous order); and
-    ``compiles`` where a call into the predictor for it compiled.
+    (0 for a pass's first batch and in the synchronous order);
+    ``compiles`` where a call into the predictor for it compiled;
+    ``consume_us``: how long the pass stood suspended at the batch's
+    yields, the consumer's time; and, at its close, what its thread
+    has used (``HostStageTimer.close_root``). For the length of the
+    pass the cyclic collector's passes are spans too
+    (``HostStageTimer.collector_spans``).
     """
     timer = host_timer()
     bs = getattr(predictor, "batch_size", 1)
@@ -906,7 +912,7 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
     def open_root():
         # detached: the next batch's root opens while this one is open
         roots.append(timer.span("pass.batch", unit=next(units), complete=0,
-                                ahead=0).detach())
+                                ahead=0, consume_us=0).detach())
         return roots[-1]
 
     @contextlib.contextmanager
@@ -994,6 +1000,7 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
         if batch is None:
             return
         root, n = batch.root, len(batch.items)
+        consumed_ns = 0    # the consumer's, between a yield and the resume
         if batched:
             with calling(root):
                 _, up = (predictor.collect_batch(batch.flows) if pipelined
@@ -1019,9 +1026,12 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
             # counted before the yield: a consumer that closes the
             # generator at the batch's last yield never resumes it
             root.args["complete"] = int(j == n - 1)
+            handed = time.perf_counter_ns()
             yield idx, sample, flow
+            consumed_ns += time.perf_counter_ns() - handed
+            root.args["consume_us"] = consumed_ns // 1000
         roots.remove(root)
-        root.close()
+        timer.close_root(root)
 
     def take_pending():
         nonlocal pending
@@ -1029,32 +1039,34 @@ def _predict_dataset(predictor, dataset, mode: Optional[str] = None):
         return batch
 
     stream = launched()
-    try:
-        while True:
-            try:
-                batch = next(stream, None)
-            except Exception:
-                # the synchronous order had yielded the batch before
-                # this one ahead of touching this one's first pair
-                yield from collect(take_pending())
-                raise
-            if batch is None:
-                yield from collect(take_pending())
-                break
-            if pipelined:   # one behind: what was dispatched before it
-                batch, pending = pending, batch
-            yield from collect(batch)
-    finally:
-        # Given up at a yield, or failed. Nothing waits for the device:
-        # a batch dispatched ahead keeps running, its root closes with
-        # complete 0, and its pair, which a transfer may still read, is
-        # dropped with it; so is the pair of a batch that raised.
-        for root in roots:
-            root.close()
-        # buckets still filling: nothing of theirs is in flight, so
-        # their buffers go back
-        for batch in buckets.values():
-            arena.release(*batch.buffers)
+    with timer.collector_spans():
+        try:
+            while True:
+                try:
+                    batch = next(stream, None)
+                except Exception:
+                    # the synchronous order had yielded the batch before
+                    # this one ahead of touching this one's first pair
+                    yield from collect(take_pending())
+                    raise
+                if batch is None:
+                    yield from collect(take_pending())
+                    break
+                if pipelined:   # one behind: what was dispatched before it
+                    batch, pending = pending, batch
+                yield from collect(batch)
+        finally:
+            # Given up at a yield, or failed. Nothing waits for the
+            # device: a batch dispatched ahead keeps running, its root
+            # closes with complete 0, and its pair, which a transfer may
+            # still read, is dropped with it; so is the pair of a batch
+            # that raised.
+            for root in roots:
+                timer.close_root(root)
+            # buckets still filling: nothing of theirs is in flight, so
+            # their buffers go back
+            for batch in buckets.values():
+                arena.release(*batch.buffers)
 
 
 def _reported_pass(predictor, dataset, mode: Optional[str] = None):
@@ -1062,18 +1074,21 @@ def _reported_pass(predictor, dataset, mode: Optional[str] = None):
     prints where the pass's host time went once it is through, how many
     of its batches' staging buffers came from the arena warm, and how
     many batches were dispatched while the one before was on the
-    device."""
+    device, and a line for every batch that took over three times the
+    pass's median batch."""
     timer = host_timer()
     before, began = timer.summary(), time.perf_counter_ns()
     yield from _predict_dataset(predictor, dataset, mode)
-    roots = [s.args for s in timer.spans()
-             if s.name == "pass.batch" and s.start_ns >= began]
+    spans = [s for s in timer.spans() if s.start_ns >= began]
+    roots = [s.args for s in spans if s.name == "pass.batch"]
     fresh = [r["arena_fresh"] for r in roots if "arena_fresh" in r]
     reuse = (f" | arena reuse: {1 - sum(fresh) / (2 * len(fresh)):.0%} of "
              f"{2 * len(fresh)} buffers" if fresh else "")
     ahead = (f" | dispatched ahead: {sum(r['ahead'] for r in roots)} of "
              f"{len(roots)} batches" if roots else "")
     print("host stages:", timer.report(since=before) + reuse + ahead)
+    for line in slow_unit_lines(unit_accounts(spans, "pass.batch"), "batch"):
+        print(line)
 
 
 def _epe_map(flow: np.ndarray, flow_gt: np.ndarray) -> np.ndarray:

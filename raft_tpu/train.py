@@ -280,6 +280,8 @@ def train(tcfg: TrainConfig, mcfg, *,
             # what a compiling step freezes (below) is the cyclic
             # collector's again once the loop is left, however it is
             leaving.callback(gc.unfreeze)
+            # the collector's passes are spans for as long as steps run
+            leaving.enter_context(timer.collector_spans())
             # the while-condition check also escapes a pathological spin
             # over an exhausted one-shot dataloader (local flag only; no
             # collectives run in an empty pass)
@@ -364,7 +366,7 @@ def train(tcfg: TrainConfig, mcfg, *,
                             # 1.2-1.8 s (PERF.md, Findings of PR 35).
                             gc.collect()
                             gc.freeze()
-                        step_span.close()
+                        timer.close_root(step_span)
                     if tcfg.max_consecutive_skips and consecutive_skips \
                             >= tcfg.max_consecutive_skips:
                         # The guard never applied a non-finite update,

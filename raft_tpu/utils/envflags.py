@@ -12,7 +12,7 @@ every flag fails loudly and identically:
 * ``env_int_choice`` — closed integer sets with an optional sentinel for
   "unset/auto" (``RAFT_CORR_TILE`` in {0, 128, 256}).
 * ``forced_flag`` — scoped override/restore for A/B harnesses
-  (``bench.py --gru/--motion ab``, ``scripts/profile_probe.py``) that
+  (``bench.py --gru/--motion ab``, ``scripts/serve_drill.py``) that
   force a trace-time flag for one arm and must put the environment back
   exactly — including deleting a variable that was unset — however the
   arm exits.

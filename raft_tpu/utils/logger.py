@@ -22,9 +22,11 @@ import json
 import os
 import time
 from collections import defaultdict, deque
+from statistics import fmean
 from typing import Dict, Iterable, Optional
 
-from raft_tpu.utils.profiling import host_timer
+from raft_tpu.utils.profiling import (host_timer, slow_unit_lines,
+                                       unit_accounts)
 
 
 class SmoothedValue:
@@ -187,6 +189,8 @@ class TrainLogger:
                 self._tb = EventWriter(log_dir)
         self._t0 = time.time()
         self._host_mark = host_timer().summary()
+        self._spans_mark = host_timer().recorded
+        self._last_step_id = 0
         # The same run totals, live on the process telemetry registry
         # (one labeled gauge family; the JSONL/TensorBoard stream stays
         # the canonical artifact — this is the scrape surface).
@@ -235,6 +239,10 @@ class TrainLogger:
                 scalars["lr"] = lr
             scalars.update(self.counters)
             scalars.update(self._host_stage_means())
+            account, slow = self._host_step_account()
+            scalars.update(account)
+            for line in slow:
+                print(line)
             self.write_dict(scalars)
             self.running = {}
             self._t0 = time.time()
@@ -248,6 +256,37 @@ class TrainLogger:
         self._host_mark = timer.summary()
         return {f"host/{name[len('train.'):]}_ms": row["mean_ms"]
                 for name, row in new.items() if name.startswith("train.")}
+
+    def _host_step_account(self):
+        """The steps that closed since the last flush, from the ring
+        (the step that is flushing is still open: the next flush's):
+        ``host/unattributed_ms`` (step time under no stage span),
+        ``host/collector_ms`` (the cyclic collector's, on any thread),
+        ``host/cpu_ms`` (the loop's thread on a CPU), each a mean, and
+        ``host/worst_step_ms``; and a line for each step that ran over
+        three times their median. Off the hot path: only the spans
+        recorded since the last flush, and the step before them, are
+        read."""
+        timer = host_timer()
+        recorded = timer.recorded
+        # 16 more: the step before, which the first new one's CPU time
+        # is counted from
+        rows = unit_accounts(
+            timer.spans(newest=recorded - self._spans_mark + 16),
+            "train.step")
+        rows = [r for r in rows if r["id"] > self._last_step_id]
+        self._spans_mark = recorded
+        if not rows:
+            return {}, []
+        self._last_step_id = rows[-1]["id"]
+        scalars = {
+            "host/unattributed_ms": fmean(r["unattributed"] for r in rows),
+            "host/collector_ms": fmean(r["collector_ms"] for r in rows),
+            "host/worst_step_ms": max(r["ms"] for r in rows)}
+        cpu = [r["cpu_ms"] for r in rows if r["cpu_ms"] is not None]
+        if cpu:
+            scalars["host/cpu_ms"] = fmean(cpu)
+        return scalars, slow_unit_lines(rows, "step")
 
     def write_images(self, image1, image2, flow_gt, flow_preds,
                      sparse_preds=None, phase: str = "T",

@@ -1,60 +1,42 @@
-"""On-device profiling: trace capture + per-op time breakdown.
+"""Host-side accounting of a run: where the wall time of a batch or a
+step went, on the clock the device trace is anchored on.
 
-The reference's only observability hooks are the dormant
-``MetricLogger.log_every`` timers (reference ``core/utils/misc.py:193-280``);
-on TPU the native tracer is ``jax.profiler``. This module makes its output
-actionable without TensorBoard:
+The device's side is read from ``jax.profiler`` traces by the benchmark
+(``benchmark/trace_reduce.py``; ``benchmark/tools/scope_summary.py``
+prints the by-stage table). This module is the host's side:
 
-* :func:`trace` — context manager around ``jax.profiler.trace`` with a
-  fresh run directory per capture.
-* :func:`op_breakdown` — parse the captured ``*.xplane.pb`` protobuf
-  directly (the tensorboard-plugin converter stack is not required) and
-  aggregate per-HLO-op self times from the device's "XLA Ops" timeline.
-* :func:`print_breakdown` — the top-N table, normalized per step. When
-  the trace carries per-op ``flops`` stats (TPU traces do; CPU traces
-  usually don't) each row also gets an achieved-TFLOP/s and an MFU
-  column, so "which op is the MFU wall" is answerable from the probe
-  artifact alone instead of cross-referencing a roofline by hand.
-* :func:`peak_tflops` — the MFU denominator: ``RAFT_PEAK_TFLOPS`` env
-  override, else the TPU-v5e bf16 figure (197) on TPU backends, else
-  unknown (CPU peak varies too much across hosts to guess).
-* :func:`group_rows` / :func:`op_group_summary` — collapse the per-op
-  rows into named op-pattern groups (e.g. every ``convc*``/``convf*``
-  op of the motion encoder vs its fused Pallas custom-call) with summed
-  time, FLOPs, achieved TFLOP/s and MFU per group — the "per-op MFU
-  columns, but for a subsystem" view the kernel A/B probes print.
-* :class:`HostStageTimer` — accumulated *host-side* wall time per named
-  pipeline stage (pad / stack / dispatch / sync), for code whose cost
-  the device tracer can't see, and — given a ring — the last spans
-  themselves (start, duration, unit, parent). The serving engine
-  threads a totals-only one through its dispatch loop; the dataset
-  pass, the predictor and the train loop record into the process-wide
-  one, :func:`host_timer`.
-
-Typical use::
-
-    with profiling.trace("/tmp/raft-trace") as t:
-        for _ in range(3):
-            state, metrics = step_fn(state, batch, rng)
-        jax.block_until_ready(metrics)
-    profiling.print_breakdown(t.logdir, steps=3)
-
-Parsing needs the ``xplane_pb2`` proto, vendored by tensorflow; on hosts
-without tensorflow :func:`op_breakdown` raises a clear error (the trace
-itself can still be viewed in TensorBoard elsewhere).
+* :class:`HostStageTimer` — accumulated wall time per named stage
+  (pad / stack / dispatch / sync), for code whose cost the device
+  tracer can't see, and — given a ring — the last spans themselves
+  (start, duration, unit, parent). The serving engine threads a
+  totals-only one through its dispatch loop; the dataset pass, the
+  predictor and the train loop record into the process-wide one,
+  :func:`host_timer`.
+* :meth:`HostStageTimer.collector_spans` — for the length of a run the
+  cyclic collector's passes are spans too (``gc.pass``, with the thread
+  and generation; the many short young passes only as the totals
+  ``gc.young``), so a stall inside ``block_until_ready`` can be told
+  from device time.
+* :meth:`HostStageTimer.close_root` — a unit's root span closes with
+  its thread's cumulative CPU time: wall time without CPU time is a
+  thread kept from running.
+* :func:`unit_accounts` / :func:`slow_unit_lines` — the operator's
+  reading of the ring at a flush: per unit what no stage covers, the
+  collector's share, CPU beside wall, and a line for every unit that
+  ran over three times the median.
 """
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
-import glob
-import importlib
-import os
-import os.path as osp
+import gc
+import statistics
 import struct
+import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence
 
 from raft_tpu.observability import tracer as _tracing
 
@@ -62,6 +44,14 @@ from raft_tpu.observability import tracer as _tracing
 #: parent, unit, start_ns, dur_ns, nbytes
 _ROW = struct.Struct("6q")
 
+
+#: A collector pass of a young generation that took less than this
+#: leaves no span, only the ``gc.young`` totals.
+GC_SPAN_NS = 1_000_000
+
+#: A unit that took over this many times the median one gets a line
+#: of its own from :func:`slow_unit_lines`.
+SLOW_FACTOR = 3.0
 
 #: A closed span as :meth:`HostStageTimer.spans` gives it back.
 SpanRecord = collections.namedtuple(
@@ -163,11 +153,20 @@ class HostStageTimer:
     call sites write request-keyed slices, into the tracer the engine
     captured when it was built). ``ring=0`` keeps totals only: the
     serving engine's instance.
+
+    Inside :meth:`collector_spans` a pass of the cyclic collector is a
+    span too: ``gc.pass`` for a full pass (generation 2) or one of
+    ``GC_SPAN_NS`` or more, with integer args ``generation``,
+    ``collected``, ``uncollectable`` and ``main`` (1 on the thread that
+    entered :meth:`collector_spans`, 0 on any other: a loader's thread
+    that holds the interpreter while the loop's thread waits for it);
+    its parent and unit are those of the innermost span open on the
+    thread it ran on. Every other pass adds to the totals ``gc.young``
+    (:meth:`summary`; :attr:`young_us`) and takes no slot of the ring.
     """
 
     def __init__(self, ring: int = 0):
         import itertools
-        import threading
 
         if ring < 0:
             raise ValueError(f"ring must be >= 0, got {ring}")
@@ -187,6 +186,16 @@ class HostStageTimer:
         self._recorded = 0
         self._ids = itertools.count(1)
         self._open = threading.local()
+        # The collector's passes (collector_spans). The callback runs
+        # wherever a pass begins, which can be inside _record on the
+        # thread that holds the lock: it takes no lock and leaves a
+        # finished pass in a queue that the next lock holder folds in.
+        self._gc_users = 0
+        self._gc_main: Optional[int] = None
+        self._gc_began_ns = 0
+        self._gc_done: collections.deque = collections.deque()
+        self._young_ns = 0
+        self._young_count = 0
 
     def span(self, name: str, unit: Optional[int] = None, nbytes: int = 0,
              parent: Optional[Span] = None, **args: int) -> Span:
@@ -222,29 +231,113 @@ class HostStageTimer:
                     break
 
     def _record(self, span: Span) -> None:
+        row = (span.name, span.id, span.parent, span.unit, span.start_ns,
+               span.dur_ns, int(span.nbytes), span.args)
         with self._lock:
-            self._total_ns[span.name] += span.dur_ns
-            self._count[span.name] += 1
-            if span.nbytes:
-                self._bytes[span.name] += int(span.nbytes)
-            if self.ring:
-                slot = self._recorded % self.ring
-                self._names[slot] = span.name
-                self._args[slot] = span.args or None
-                _ROW.pack_into(
-                    self._rows, _ROW.size * slot, span.id, span.parent,
-                    -1 if span.unit is None else span.unit, span.start_ns,
-                    span.dur_ns, int(span.nbytes))
-                self._recorded += 1
-        if self.ring and span.unit is not None:
+            self._fold_passes()
+            self._put(*row)
+        self._forward(*row)
+
+    def _put(self, name, ident, parent, unit, start_ns, dur_ns, nbytes,
+             args) -> None:
+        """Under the lock: one closed span into the totals and the
+        ring."""
+        self._total_ns[name] += dur_ns
+        self._count[name] += 1
+        if nbytes:
+            self._bytes[name] += nbytes
+        if self.ring:
+            slot = self._recorded % self.ring
+            self._names[slot] = name
+            self._args[slot] = args or None
+            _ROW.pack_into(self._rows, _ROW.size * slot, ident, parent,
+                           -1 if unit is None else unit, start_ns, dur_ns,
+                           nbytes)
+            self._recorded += 1
+
+    def _forward(self, name, ident, parent, unit, start_ns, dur_ns, nbytes,
+                 args) -> None:
+        """A span of a unit to the process tracer, where one is on."""
+        if self.ring and unit is not None:
             tracer = _tracing.current()
             if tracer is not None:
                 tracer.complete(
-                    span.name, span.dur_ns / 1e9, cat="host",
-                    end_ts_us=(span.start_ns + span.dur_ns
-                               - tracer.t0_ns) / 1e3,
-                    args={"unit": span.unit, "nbytes": span.nbytes,
-                          **span.args})
+                    name, dur_ns / 1e9, cat="host",
+                    end_ts_us=(start_ns + dur_ns - tracer.t0_ns) / 1e3,
+                    args={"unit": unit, "nbytes": nbytes, **args})
+
+    # ------------------------------------------------- the collector
+
+    @contextlib.contextmanager
+    def collector_spans(self):
+        """For the length of the block the cyclic collector's passes
+        are recorded (class docstring). Blocks nest, also across
+        ``train()`` and the dataset pass it validates with: one
+        callback in ``gc.callbacks`` while any is open, none after the
+        last is left, however it is left."""
+        with self._lock:
+            self._gc_users += 1
+            if self._gc_users == 1:
+                self._gc_main = threading.get_ident()
+                gc.callbacks.append(self._on_gc)
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._gc_users -= 1
+                if not self._gc_users:
+                    gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        now = time.perf_counter_ns()
+        if phase == "start":
+            self._gc_began_ns = now
+            return
+        began, self._gc_began_ns = self._gc_began_ns, 0
+        if not began:
+            # hooked between a pass's two callbacks: its start was not
+            # seen
+            return
+        dur = now - began
+        if info["generation"] < 2 and dur < GC_SPAN_NS:
+            # passes do not nest and each holds the interpreter: no
+            # other thread is in here
+            self._young_ns += dur
+            self._young_count += 1
+            return
+        stack = self._stack()
+        parent, unit = (stack[-1].id, stack[-1].unit) if stack else (0, None)
+        row = ("gc.pass", next(self._ids), parent, unit, began, dur, 0,
+               {"generation": info["generation"],
+                "collected": info["collected"],
+                "uncollectable": info["uncollectable"],
+                "main": int(threading.get_ident() == self._gc_main)})
+        self._gc_done.append(row)
+        self._forward(*row)
+
+    def _fold_passes(self) -> None:
+        """Under the lock: the passes finished since the last call
+        into the ring, before whatever closes next."""
+        done = self._gc_done
+        while done:
+            self._put(*done.popleft())
+
+    @property
+    def young_us(self) -> int:
+        """Microseconds the passes that left no span have taken, in
+        all, since the timer was made."""
+        return self._young_ns // 1000
+
+    def close_root(self, span: Span) -> None:
+        """Close a unit's root span, on the thread that opened it, with
+        what that thread has used so far: ``cpu_us`` (its user and
+        system CPU time, ``time.thread_time_ns``) and ``young_us``
+        (:attr:`young_us`). Both are cumulative: a reader takes the
+        difference of two consecutive roots."""
+        if span.dur_ns is None:
+            span.args.update(cpu_us=time.thread_time_ns() // 1000,
+                             young_us=self.young_us)
+        span.close()
 
     def add_bytes(self, name: str, n: int) -> None:
         """Attribute ``n`` bytes to ``name`` outside a ``span()``
@@ -256,13 +349,24 @@ class HostStageTimer:
     def dropped(self) -> int:
         """Spans the ring has overwritten."""
         with self._lock:
+            self._fold_passes()
             return max(0, self._recorded - self.ring)
 
-    def spans(self) -> List[SpanRecord]:
-        """The ring's closed spans, oldest first (by when they closed:
-        children before their parent)."""
+    @property
+    def recorded(self) -> int:
+        """Spans recorded so far, the overwritten ones among them."""
         with self._lock:
+            return self._recorded + len(self._gc_done)
+
+    def spans(self, newest: Optional[int] = None) -> List[SpanRecord]:
+        """The ring's closed spans, oldest first (by when they closed:
+        children before their parent); with ``newest``, no more than
+        that many of its latest."""
+        with self._lock:
+            self._fold_passes()
             kept = min(self._recorded, self.ring)
+            if newest is not None:
+                kept = min(kept, max(int(newest), 0))
             slots = [(self._recorded - kept + k) % self.ring
                      for k in range(kept)]
             rows = [(self._names[i], self._args[i],
@@ -275,12 +379,17 @@ class HostStageTimer:
 
     def summary(self, since: Optional[Dict[str, Dict[str, float]]] = None
                 ) -> Dict[str, Dict[str, float]]:
-        """Totals by stage; with ``since`` (an earlier summary), what
-        was added after it, stages with nothing new left out."""
+        """Totals by stage, ``gc.young`` (the collector's passes that
+        left no span) among them; with ``since`` (an earlier summary),
+        what was added after it, stages with nothing new left out."""
         with self._lock:
+            self._fold_passes()
             rows = {name: (tot / 1e6, float(self._count[name]),
                            float(self._bytes[name]))
                     for name, tot in self._total_ns.items()}
+            if self._young_count:
+                rows["gc.young"] = (self._young_ns / 1e6,
+                                    float(self._young_count), 0.0)
         out = {}
         for name, (ms, count, nbytes) in rows.items():
             if since is not None and name in since:
@@ -321,247 +430,109 @@ def host_timer() -> HostStageTimer:
     return _HOST_TIMER
 
 
-class _Trace:
-    def __init__(self, logdir: str):
-        self.logdir = logdir
+# ------------------------------------------------- the operator's reading
 
+def unit_accounts(spans: Sequence[SpanRecord], root: str) -> List[dict]:
+    """What the thread of the ``root`` spans (``train.step``,
+    ``pass.batch``) did, unit by unit, from ``spans`` (the ring, oldest
+    first); one row a closed root:
 
-@contextlib.contextmanager
-def trace(logdir: Optional[str] = None):
-    """Capture a ``jax.profiler`` trace; yields an object with ``logdir``."""
-    import jax
-
-    if logdir is None:
-        logdir = osp.join("/tmp", f"raft_tpu_trace_{int(time.time())}")
-    os.makedirs(logdir, exist_ok=True)
-    t = _Trace(logdir)
-    with jax.profiler.trace(logdir):
-        yield t
-
-
-def _load_xspace(logdir: str):
-    # The xplane proto moved across TF releases; try the known homes.
-    XSpace, last_err = None, None
-    for mod in ("tensorflow.core.profiler.protobuf.xplane_pb2",
-                "tensorflow.tsl.profiler.protobuf.xplane_pb2"):
-        try:
-            XSpace = importlib.import_module(mod).XSpace
-            break
-        except ImportError as e:
-            last_err = e
-    if XSpace is None:  # pragma: no cover - depends on image
-        raise ImportError(
-            "parsing traces requires tensorflow's xplane_pb2 proto (tried "
-            "tensorflow.core.profiler and tensorflow.tsl.profiler "
-            f"locations); view the trace in TensorBoard instead "
-            f"(logdir={logdir})") from last_err
-
-    paths = sorted(glob.glob(
-        osp.join(logdir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not paths:
-        raise FileNotFoundError(f"no *.xplane.pb under {logdir}")
-    xs = XSpace()
-    with open(paths[-1], "rb") as f:
-        xs.ParseFromString(f.read())
-    return xs
-
-
-def op_breakdown(logdir: str) -> List[Tuple[str, float, int]]:
-    """Aggregate device-op self times from the latest trace in ``logdir``.
-
-    Returns ``[(op_name, total_ms, count), ...]`` sorted by time. On TPU
-    the ops live in each device plane's "XLA Ops" timeline; CPU traces put
-    them on executor thread lines named ``tf_XLA...``. Exactly those two
-    line kinds are considered and summed across ALL matching lines, so a
-    multi-core/multi-device trace reports whole-trace op totals rather
-    than one core's (the per-line totals are printed by
-    :func:`print_breakdown` when more than one line contributed).
+    * ``unit``, ``id``; ``ms``: the unit's time, from the root's start,
+      or from the close of the root before it where that one was still
+      open then (the dataset pass keeps two open), to its close;
+    * ``stages``: milliseconds by name of the roots' children that ran
+      in that time, whichever unit's they are (and ``consume``, the
+      consumer's time at the batch's yields); ``unattributed``: what is
+      left of ``ms``;
+    * ``passes``: ``(generation, main, ms)`` of every ``gc.pass`` span
+      that overlaps that time, on any thread; ``collector_ms``: their
+      overlap and the short passes (``young_us``) together;
+    * ``cpu_ms``: the thread's CPU time
+      (:meth:`HostStageTimer.close_root`) since the root before,
+      ``None`` for a run's first root in ``spans``.
     """
-    return _collect_ops(logdir)[0]
+    roots = [s for s in spans if s.name == root]
+    ids = {r.id for r in roots}
+    rows, begins, ends = [], [], []
+    before = None
+    for r in roots:
+        end = r.start_ns + r.dur_ns
+        follows = before is not None and r.unit == before.unit + 1
+        begin = max(r.start_ns, ends[-1]) if follows else r.start_ns
+        used = {k: (r.args[k] - before.args[k]) if follows
+                and k in r.args and k in before.args else None
+                for k in ("cpu_us", "young_us")}
+        consumed = r.args.get("consume_us", 0) / 1e3
+        rows.append({
+            "unit": r.unit, "id": r.id, "ms": (end - begin) / 1e6,
+            "stages": {"consume": consumed} if consumed else {},
+            "passes": [], "collector_ms": (used["young_us"] or 0) / 1e3,
+            "cpu_ms": None if used["cpu_us"] is None
+            else used["cpu_us"] / 1e3})
+        begins.append(begin)
+        ends.append(end)
+        before = r
+    for s in spans:
+        if s.name == "gc.pass":
+            at = bisect.bisect_right(ends, s.start_ns)
+            while at < len(rows) and begins[at] < s.start_ns + s.dur_ns:
+                ms = (min(s.start_ns + s.dur_ns, ends[at])
+                      - max(s.start_ns, begins[at])) / 1e6
+                rows[at]["passes"].append(
+                    (s.args.get("generation"), s.args.get("main"), ms))
+                rows[at]["collector_ms"] += ms
+                at += 1
+        elif s.parent in ids:
+            at = bisect.bisect_right(ends, s.start_ns)
+            if at < len(rows) and begins[at] <= s.start_ns:
+                stages = rows[at]["stages"]
+                name = s.name.partition(".")[2] or s.name
+                stages[name] = stages.get(name, 0.0) + s.dur_ns / 1e6
+    for row in rows:
+        row["unattributed"] = row["ms"] - sum(row["stages"].values())
+    return rows
 
 
-#: Published dense bf16 peak per chip, TFLOP/s, keyed by
-#: ``jax.Device.device_kind``. Source: Google Cloud TPU documentation,
-#: "TPU v5e" system architecture page (197 TFLOP/s bf16, 819 GB/s HBM).
-#: A device kind that is not listed is an error, never a default.
-PEAK_BF16_TFLOPS = {
-    "TPU v5 lite": 197.0,
-    "TPU v5e": 197.0,
-}
-
-
-def peak_tflops() -> Optional[float]:
-    """MFU denominator in TFLOP/s: ``RAFT_PEAK_TFLOPS`` env override
-    (accepts any float; ``0``/empty = unknown), else the published bf16
-    peak of the default device's ``device_kind`` from
-    ``PEAK_BF16_TFLOPS``. ``None`` off-TPU (unknown; MFU columns are
-    suppressed rather than guessed); a TPU whose kind is not in the
-    table raises ``KeyError`` instead of borrowing another chip's
-    peak."""
-    raw = os.environ.get("RAFT_PEAK_TFLOPS", "")
-    if raw:
-        v = float(raw)
-        return v if v > 0 else None
-    import jax
-
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        return None
-    if dev.device_kind not in PEAK_BF16_TFLOPS:
-        raise KeyError(
-            f"no published peak for device kind {dev.device_kind!r}; add "
-            f"it to raft_tpu.utils.profiling.PEAK_BF16_TFLOPS with its "
-            f"source, or set RAFT_PEAK_TFLOPS")
-    return PEAK_BF16_TFLOPS[dev.device_kind]
-
-
-def _event_flops(plane, ev, stat_names) -> int:
-    """FLOP count of one xplane event: the ``flops`` stat, read from the
-    event's own stats first, then from its (shared) event metadata —
-    traces have carried it in either place across TF releases."""
-    for stats in (ev.stats, plane.event_metadata[ev.metadata_id].stats):
-        for st in stats:
-            if stat_names.get(st.metadata_id) != "flops":
-                continue
-            return int(st.int64_value or st.uint64_value
-                       or st.double_value)
-    return 0
-
-
-def _collect_ops(logdir: str):
-    """Shared collector:
-    ``(rows, [(plane/line, total_ms), ...], {op: flops})``.
-
-    ``rows`` keeps the historical ``[(name, total_ms, count), ...]``
-    shape (:func:`op_breakdown`'s public contract); flops ride in the
-    separate per-op dict, empty when the trace has no ``flops`` stats.
-    """
-    xs = _load_xspace(logdir)
-    # Candidate op-level timelines: "XLA Ops" (TPU device planes) and CPU
-    # executor threads ("tf_XLA..."). The TPU plane also has an
-    # "XLA Modules" line whose whole-executable spans would double-count
-    # every op — excluded. When BOTH device and host lines exist (a TPU
-    # trace also records host executor activity for the same program),
-    # only the device lines are summed: mixing them would double-count.
-    device_lines, host_lines = [], []
-    for plane in xs.planes:
-        for line in plane.lines:
-            if line.name == "XLA Ops":
-                device_lines.append((plane, line))
-            elif line.name.startswith("tf_XLA"):
-                host_lines.append((plane, line))
-    tot: collections.Counter = collections.Counter()
-    cnt: collections.Counter = collections.Counter()
-    flops: collections.Counter = collections.Counter()
-    lines_used = []
-    for plane, line in device_lines or host_lines:
-        stat_names = {sid: meta.name
-                      for sid, meta in plane.stat_metadata.items()}
-        line_ps = 0
-        for ev in line.events:
-            name = plane.event_metadata[ev.metadata_id].name
-            tot[name] += ev.duration_ps
-            cnt[name] += 1
-            flops[name] += _event_flops(plane, ev, stat_names)
-            line_ps += ev.duration_ps
-        if line_ps:
-            lines_used.append((f"{plane.name}/{line.name}", line_ps / 1e9))
-    rows = sorted(((k, ps / 1e9, cnt[k]) for k, ps in tot.items()),
-                  key=lambda x: -x[1])
-    return rows, lines_used, {k: v for k, v in flops.items() if v}
-
-
-def group_rows(rows, flops, groups, steps: int = 1):
-    """Collapse per-op ``rows`` (``op_breakdown`` shape) into named
-    groups by substring match.
-
-    ``groups`` maps a group name to a tuple of op-name substrings; an op
-    belongs to the FIRST group (in dict order) with a matching pattern,
-    so put the most specific patterns first. Pure function of the row
-    data — unit-testable without a trace. Returns
-    ``{group: {time_ms, ops, count, flops, tflops_per_s, mfu_pct}}``
-    (``tflops_per_s``/``mfu_pct`` are ``None`` without flops stats /
-    a known peak), plus an ``"(other)"`` group for unmatched time so the
-    groups always sum to the whole program.
-    """
-    peak = peak_tflops() if flops else None
-    out = {name: {"time_ms": 0.0, "ops": 0, "count": 0, "flops": 0}
-           for name in groups}
-    out["(other)"] = {"time_ms": 0.0, "ops": 0, "count": 0, "flops": 0}
-
-    def bucket(op_name):
-        for gname, pats in groups.items():
-            if any(p in op_name for p in pats):
-                return gname
-        return "(other)"
-
-    for name, ms, c in rows:
-        g = out[bucket(name)]
-        g["time_ms"] += ms / max(steps, 1)
-        g["ops"] += 1
-        g["count"] += c
-        g["flops"] += flops.get(name, 0) // max(steps, 1)
-    for g in out.values():
-        if g["flops"] and g["time_ms"]:
-            tf = g["flops"] / (g["time_ms"] * 1e-3) / 1e12
-            g["tflops_per_s"] = tf
-            g["mfu_pct"] = 100.0 * tf / peak if peak else None
-        else:
-            g["tflops_per_s"] = None
-            g["mfu_pct"] = None
-    return out
-
-
-def op_group_summary(logdir: str, groups, steps: int = 1) -> dict:
-    """Parse the latest trace in ``logdir`` and print + return the
-    :func:`group_rows` table for ``groups`` — one line per group with
-    summed time/step, op & event counts, and (when the trace has flops
-    stats) achieved TFLOP/s and MFU."""
-    rows, _, flops = _collect_ops(logdir)
-    summary = group_rows(rows, flops, groups, steps=steps)
-    for name, g in sorted(summary.items(),
-                          key=lambda kv: -kv[1]["time_ms"]):
-        if not g["count"]:
+def slow_unit_lines(rows: Sequence[dict], what: str,
+                    factor: float = SLOW_FACTOR) -> List[str]:
+    """One line for each of ``rows`` (:func:`unit_accounts`) that took
+    over ``factor`` times their median (of an even count, the lower of
+    the two in the middle): its number, its time beside the median, the
+    stage that grew most over that stage's median and by how much, the
+    collector's passes that overlap it by thread and generation, and
+    CPU time beside wall."""
+    if not rows:
+        return []
+    median = statistics.median_low(r["ms"] for r in rows)
+    usual: Dict[str, float] = {}
+    for name in {n for r in rows for n in (*r["stages"], "unattributed")}:
+        usual[name] = statistics.median_low(
+            r["stages"].get(name, 0.0) if name != "unattributed"
+            else r["unattributed"] for r in rows)
+    lines = []
+    for row in rows:
+        if row["ms"] <= factor * median:
             continue
-        line = (f"{g['time_ms']:9.3f} ms/step  {g['ops']:4d} ops "
-                f"x{g['count']:6d}")
-        if g["tflops_per_s"] is not None:
-            line += f"  {g['tflops_per_s']:7.2f} TF/s"
-            if g["mfu_pct"] is not None:
-                line += f" {g['mfu_pct']:5.1f}% MFU"
-        print(f"{line}  {name}")
-    return summary
-
-
-def print_breakdown(logdir: str, steps: int = 1, top: int = 20) -> None:
-    """Print the top-``top`` ops, times divided by ``steps``.
-
-    With per-op ``flops`` stats in the trace, each row gains the op's
-    achieved TFLOP/s and — when :func:`peak_tflops` knows the chip — its
-    MFU, plus a weighted whole-program MFU line. Both are *self-time*
-    utilizations (flops / op self time / peak), so memory-bound ops
-    honestly read near 0% rather than inheriting neighbors' compute.
-    """
-    rows, lines_used, flops = _collect_ops(logdir)
-    total = sum(ms for _, ms, _ in rows)
-    peak = peak_tflops() if flops else None
-    print(f"total device op time: {total / max(steps, 1):.2f} ms/step "
-          f"({len(rows)} distinct ops, {len(lines_used)} op timelines)")
-    if flops and total:
-        agg = sum(flops.values()) / (total * 1e-3) / 1e12
-        line = f"achieved: {agg:.2f} TFLOP/s over device op time"
-        if peak:
-            line += f" = {100.0 * agg / peak:.1f}% MFU of {peak:g} peak"
-        print(line)
-    if len(lines_used) > 1:
-        for name, ms in lines_used:
-            print(f"  contributing line: {name} "
-                  f"({ms / max(steps, 1):.2f} ms/step)")
-    for name, ms, c in rows[:top]:
-        cols = f"{ms / max(steps, 1):9.3f} ms/step  x{c:5d}"
-        if name in flops and ms:
-            tf = flops[name] / (ms * 1e-3) / 1e12
-            cols += f"  {tf:7.2f} TF/s"
-            if peak:
-                cols += f" {100.0 * tf / peak:5.1f}% MFU"
-        print(f"{cols}  {name[:90]}")
+        grown = {name: (row["unattributed"] if name == "unattributed"
+                        else row["stages"].get(name, 0.0)) - usual[name]
+                 for name in usual}
+        name = max(grown, key=grown.get)
+        parts = [f"slow {what} {row['unit']}: {row['ms']:.1f} ms for a "
+                 f"median of {median:.1f}",
+                 f"{name} +{grown[name]:.1f} ms"]
+        # one entry a generation and thread: a compiling step holds
+        # dozens of long young passes
+        kinds = sorted({p[:2] for p in row["passes"]})
+        for generation, main in kinds:
+            took = [ms for g, m, ms in row["passes"]
+                    if (g, m) == (generation, main)]
+            parts.append(
+                (f"{len(took)} x " if len(took) > 1 else "")
+                + f"gc.pass generation {generation} on "
+                f"{'this' if main else 'another'} thread {sum(took):.1f} ms")
+        if not kinds:
+            parts.append("no gc.pass")
+        if row["cpu_ms"] is not None:
+            parts.append(f"cpu {row['cpu_ms']:.1f} ms")
+        lines.append(" | ".join(parts))
+    return lines

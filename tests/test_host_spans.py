@@ -69,10 +69,15 @@ def by_name(spans, name):
     return [s for s in spans if s.name == name]
 
 
+def staged(spans):
+    """Without the collector's passes: they come when they come."""
+    return [s for s in spans if s.name != "gc.pass"]
+
+
 def test_pass_spans(predictor, timer, monkeypatch):
     monkeypatch.setattr(predictor, "staging", StagingArena())
     flows = run_pass(predictor)
-    spans = timer.spans()
+    spans = staged(timer.spans())
     roots = by_name(spans, "pass.batch")
     assert [r.unit for r in roots] == [0, 1, 2]
     assert [r.args["pairs"] for r in roots] == [3, 3, 1]
@@ -468,7 +473,7 @@ def test_pipelined_pass_overlaps(predictor, timer):
     a change of shape too (the batches go a a b a b a), and every span
     lies under its own batch's root though two roots are open."""
     list(_predict_dataset(predictor, MixedPairs(np.float32), "sintel"))
-    spans = timer.spans()
+    spans = staged(timer.spans())
     roots = by_name(spans, "pass.batch")
     assert [r.unit for r in roots] == list(range(6))
     assert [r.args["ahead"] for r in roots] == [0, 1, 1, 1, 1, 1]
@@ -573,7 +578,8 @@ def test_closing_the_pass_does_not_wait_for_the_batch_in_flight(
     roots = by_name(timer.spans(), "pass.batch")
     assert [(r.unit, r.args["complete"], r.args["ahead"])
             for r in roots] == [(0, 1, 0), (1, 1, 1), (2, 0, 1)]
-    assert {s.name for s in timer.spans() if s.parent == roots[2].id} == {
+    assert {s.name for s in staged(timer.spans())
+            if s.parent == roots[2].id} == {
         "pass.fetch", "pass.pad", "pass.stack", "predict.h2d",
         "predict.dispatch"}
     with timer.span("probe") as probe:
@@ -695,7 +701,18 @@ def test_reader_reads_the_newest_pass(predictor, timer):
                                      ["pass.stack"], "batches") is None
 
 
-def test_ring_overflow_silences_the_reader(predictor, monkeypatch):
+@pytest.fixture
+def no_collector():
+    """No pass of the cyclic collector, so none takes a slot of a ring
+    whose slots a test counts."""
+    import gc
+    gc.disable()
+    yield
+    gc.enable()
+
+
+def test_ring_overflow_silences_the_reader(predictor, monkeypatch,
+                                           no_collector):
     small = profiling.HostStageTimer(ring=40)    # a pass is 39 spans
     monkeypatch.setattr(profiling, "_HOST_TIMER", small)
     run_pass(predictor)
@@ -786,9 +803,12 @@ def test_spans_reach_the_chrome_trace(predictor, timer):
     finally:
         disable_tracing()
     host = [e for e in doc["traceEvents"] if e.get("cat") == "host"]
-    assert {e["name"] for e in host} == set(BATCH_LEVEL) | {
+    # a collector pass under a span of a unit is forwarded like any
+    # span of that unit
+    assert {e["name"] for e in host} - {"gc.pass"} == set(BATCH_LEVEL) | {
         "pass.fetch", "pass.pad", "pass.unpad"}
-    assert len(host) == len(timer.spans())
+    assert len(host) == len([s for s in timer.spans()
+                             if s.unit is not None])
     batch = next(e for e in host if e["name"] == "pass.batch")
     assert batch["args"]["pairs"] == 3 and batch["args"]["unit"] == 0
     # on the timer's clock through the artifact's t0_ns
@@ -849,8 +869,9 @@ def test_train_spans(tmp_path, timer):
     logger.close()
     assert not (tmp_path / "ckpts" / "t").exists() or not any(
         (tmp_path / "ckpts" / "t").iterdir())
+    assert _hooks() == []      # the collector's hook left with the loop
 
-    spans = timer.spans()
+    spans = staged(timer.spans())
     steps = by_name(spans, "train.step")
     assert [(s.unit, s.args["complete"]) for s in steps] == [
         (1, 1), (2, 1), (3, 1), (4, 1), (5, 0)]
@@ -889,6 +910,448 @@ def test_train_spans(tmp_path, timer):
     for line in lines:
         for name in STEP_CHILDREN[:-1] + ("train.step",):
             assert line["host/" + name[len("train."):] + "_ms"] > 0
+
+
+# ------------------------------------------- the whole of a step, a batch
+
+import gc          # noqa: E402
+import threading   # noqa: E402
+import time        # noqa: E402
+
+from benchmark.readers import program_units   # noqa: E402
+
+
+def test_a_full_pass_inside_a_span_is_a_span(timer):
+    before = time.perf_counter_ns()
+    with timer.collector_spans():
+        with timer.span("train.step", unit=7) as root:
+            with timer.span("train.log") as log:
+                gc.collect()
+    after = time.perf_counter_ns()
+    (found,) = by_name(timer.spans(), "gc.pass")
+    assert found.args["generation"] == 2 and found.args["main"] == 1
+    assert set(found.args) == {"generation", "collected", "uncollectable",
+                               "main"}
+    assert (found.parent, found.unit) == (log.id, 7) and root.id != log.id
+    # on the spans' clock, inside the span it ran in
+    assert before <= log.start_ns <= found.start_ns
+    assert (found.start_ns + found.dur_ns
+            <= log.start_ns + log.dur_ns <= after)
+    # children before their parent: the pass precedes the span it fell in
+    names = [s.name for s in timer.spans()]
+    assert names == ["gc.pass", "train.log", "train.step"]
+
+
+def test_a_pass_on_another_thread_is_counted_by_overlap(timer):
+    """The collector on a loader's thread holds the interpreter while
+    the loop's thread waits in ``block_until_ready``: no span of the
+    loop is its parent, and the step it fell into still pays for it."""
+    with timer.collector_spans():
+        for unit in (1, 2, 3):
+            root = timer.span("train.step", unit=unit, complete=1)
+            with timer.span("train.device_wait"):
+                if unit == 2:
+                    other = threading.Thread(target=gc.collect)
+                    other.start()
+                    other.join(timeout=30)
+                    assert not other.is_alive()
+            timer.close_root(root)
+    spans = timer.spans()
+    (found,) = by_name(spans, "gc.pass")
+    assert found.args["main"] == 0 and found.args["generation"] == 2
+    assert (found.parent, found.unit) == (0, None)
+    steps = by_name(spans, "train.step")
+    assert steps[1].start_ns <= found.start_ns and (
+        found.start_ns + found.dur_ns <= steps[1].start_ns + steps[1].dur_ns)
+    got = program_units.collector_ms_per_unit(spans, 0, "train.step", 3)
+    young = (steps[2].args["young_us"] - steps[0].args["young_us"]) / 2 / 1e3
+    assert got == pytest.approx(found.dur_ns / 3 / 1e6 + young)
+    rows = profiling.unit_accounts(spans, "train.step")
+    assert [len(r["passes"]) for r in rows] == [0, 1, 0]
+    assert rows[1]["passes"][0][:2] == (2, 0)
+
+
+def test_young_passes_reach_the_totals_and_never_the_ring(timer):
+    gc.collect()
+    with timer.collector_spans():
+        with timer.span("pass.batch", unit=0):
+            for _ in range(30):
+                gc.collect(0)
+                gc.collect(1)
+    young = timer.summary()["gc.young"]
+    assert young["count"] == 60 and young["total_ms"] > 0
+    assert timer.young_us == int(young["total_ms"] * 1e3)
+    assert [s.name for s in timer.spans()] == ["pass.batch"]
+    assert timer.recorded == 1 and "gc.pass" not in timer.summary()
+    # what came since a mark, as for any stage
+    mark = timer.summary()
+    with timer.collector_spans():
+        gc.collect(0)
+    assert timer.summary(since=mark)["gc.young"]["count"] == 1
+
+
+def test_a_hook_installed_inside_a_pass_skips_it(timer):
+    """Another ``gc.callbacks`` entry can let go of the interpreter
+    between a pass's two callbacks; a hook appended then gets ``stop``
+    without its ``start``: no span from time zero, no totals."""
+    info = {"generation": 2, "collected": 0, "uncollectable": 0}
+    timer._on_gc("stop", info)
+    assert timer.spans() == [] and "gc.young" not in timer.summary()
+    # and a start is used once
+    timer._on_gc("start", info)
+    timer._on_gc("stop", info)
+    timer._on_gc("stop", info)
+    (found,) = by_name(timer.spans(), "gc.pass")
+    assert found.start_ns > 0 and found.dur_ns < 1e9
+
+
+def _train_tiny(tmp_path, loader, num_steps=10, sum_freq=100):
+    from raft_tpu.train import train
+    from raft_tpu.utils.logger import TrainLogger
+
+    tcfg = TrainConfig(name="t", num_steps=num_steps, batch_size=8,
+                       image_size=(TH, TW), iters=2, val_freq=1000,
+                       sum_freq=sum_freq)
+    logger = TrainLogger(str(tmp_path / "logs"), sum_freq=sum_freq,
+                         tensorboard=False)
+    try:
+        train(tcfg, RAFTConfig(small=True, iters=2),
+              ckpt_dir=str(tmp_path / "ckpts"),
+              log_dir=str(tmp_path / "logs"), dataloader=loader,
+              logger=logger)
+    finally:
+        logger.close()
+
+
+def _hooks():
+    return [cb for cb in gc.callbacks
+            if getattr(cb, "__func__", None)
+            is profiling.HostStageTimer._on_gc]
+
+
+@pytest.mark.parametrize("way_out", ["block_returns", "block_raises",
+                                     "closed_mid_batch", "nested"])
+def test_the_collector_hook_is_gone_on_every_way_out(way_out, timer,
+                                                     predictor):
+    """``train()``'s own ways out, a return and an exception, are held
+    by ``test_train_prints_a_slow_step_and_writes_the_worst`` and
+    ``test_train_spans``."""
+    assert _hooks() == []
+    if way_out == "block_returns":
+        with timer.collector_spans():
+            assert len(_hooks()) == 1
+    elif way_out == "block_raises":
+        with pytest.raises(Closed):
+            with timer.collector_spans():
+                raise Closed()
+    elif way_out == "closed_mid_batch":
+        gen = _predict_dataset(predictor, Pairs(), mode="sintel")
+        next(gen)
+        assert len(_hooks()) == 1
+        gen.close()
+        root = by_name(timer.spans(), "pass.batch")[0]
+        assert root.args["complete"] == 0 and "cpu_us" in root.args
+    else:
+        # a pass inside a run (train()'s validation): one hook, the
+        # outer run's, until the outer run is left
+        with timer.collector_spans():
+            run_pass(predictor)
+            assert len(_hooks()) == 1
+    assert _hooks() == []
+
+
+@pytest.mark.parametrize("plant", ["sleep", "busy"])
+def test_time_between_two_children_with_and_without_cpu(timer, plant):
+    """Between two children of one step in four the thread sleeps for
+    50 ms, or works until it has had 50 ms of a CPU: unattributed
+    either way; CPU time only where it worked."""
+    planted_ms = 0.0
+    for unit in range(1, 5):
+        root = timer.span("train.step", unit=unit, complete=1)
+        with timer.span("train.dispatch"):
+            pass
+        if unit == 3:
+            began, worked = time.perf_counter(), time.thread_time()
+            if plant == "sleep":
+                time.sleep(0.05)
+            while plant == "busy" and time.thread_time() - worked < 0.05:
+                pass
+            planted_ms = (time.perf_counter() - began) * 1e3
+        with timer.span("train.log"):
+            pass
+        timer.close_root(root)
+    spans = timer.spans()
+    assert planted_ms >= 50
+    assert program_units.unattributed_ms_per_unit(
+        spans, 0, "train.step", 4) == pytest.approx(planted_ms / 4, rel=0.1)
+    # no root before the four: the growth from the first's close on
+    cpu_us = program_units.arg_per_unit(spans, 0, "train.step", 4, "cpu_us",
+                                        True)
+    row = profiling.unit_accounts(spans, "train.step")[2]
+    assert row["unattributed"] == pytest.approx(planted_ms, rel=0.1)
+    assert row["stages"].keys() == {"dispatch", "log"}
+    if plant == "sleep":
+        assert cpu_us < 10_000 / 3 and row["cpu_ms"] < 10
+    else:
+        assert cpu_us == pytest.approx(50_000 / 3, rel=0.2)
+        assert row["cpu_ms"] == pytest.approx(50, rel=0.2)
+    assert program_units.worst_over_median(
+        spans, 0, "train.step", 4, False) > 20
+    # (the other three are microseconds long: one may be thrice another)
+    (line,) = [line for line in profiling.slow_unit_lines(
+        profiling.unit_accounts(spans, "train.step"), "step")
+        if line.startswith("slow step 3: ")]
+    assert "unattributed +" in line
+    assert "no gc.pass" in line and "cpu " in line
+
+
+def test_consume_us_is_the_consumers_time(predictor, timer, capsys):
+    """The consumer dawdles 30 ms over each flow of the second batch:
+    that batch's root says so, the stage spans do not grow, and the
+    pass's account closes."""
+    from raft_tpu.evaluate import _reported_pass
+
+    run_pass(predictor)      # compiled: the timed pass is steady
+    began = time.perf_counter_ns()
+    for n, _ in enumerate(_reported_pass(predictor, Cycled(Pairs(), 15),
+                                         "sintel")):
+        if n // BS == 1:
+            time.sleep(0.03)
+    spans = [s for s in timer.spans() if s.start_ns >= began]
+    roots = by_name(spans, "pass.batch")
+    assert [r.args["complete"] for r in roots] == [1] * 5
+    consumed = [r.args["consume_us"] for r in roots]
+    # a sleep may overrun on a busy machine, never fall short
+    assert 3 * 30_000 <= consumed[1] < 10 * 30_000
+    assert all(c < consumed[1] / 4 for c in consumed[:1] + consumed[2:])
+    assert program_units.arg_per_unit(
+        spans, 0, "pass.batch", 5, "consume_us", False) == sum(consumed) / 5
+    # every millisecond between the first root's start and the last
+    # one's end: in a stage span, the consumer's, or uncovered
+    ids = {r.id for r in roots}
+    staged_ns = sum(s.dur_ns for s in staged(spans) if s.parent in ids)
+    uncovered = program_units.uncovered_ms_per_unit(spans, 0, "pass.batch",
+                                                    5)
+    whole = roots[-1].start_ns + roots[-1].dur_ns - roots[0].start_ns
+    assert staged_ns / 1e6 + sum(consumed) / 1e3 + 5 * uncovered == (
+        pytest.approx(whole / 1e6, abs=0.01))
+    assert 0 <= uncovered < 0.2 * whole / 5 / 1e6
+    # the batch periods by the roots' closes: the dawdled one is longest
+    rows = profiling.unit_accounts(spans, "pass.batch")
+    assert max(rows, key=lambda r: r["ms"])["unit"] == 1
+    assert rows[1]["stages"]["consume"] == consumed[1] / 1e3
+    printed = capsys.readouterr().out
+    assert "host stages:" in printed
+
+
+def _record(name, ident, parent, unit, start_ms, dur_ms, **args):
+    return profiling.SpanRecord(name, ident, parent, unit,
+                                int(start_ms * 1e6), int(dur_ms * 1e6), 0,
+                                args)
+
+
+def _hand_made(root="train.step", overlap_ms=0.0, usage=True):
+    """Five roots of 10 ms (units 0-4; unit 3 takes 40), each with two
+    children of 2 and 3 ms; ``overlap_ms``: how far a root opens before
+    the one before closes (a pass's roots). A generation-2 pass of 6 ms
+    on another thread inside unit 3, a young pass's span of 1 ms on
+    this thread inside unit 1."""
+    spans, at = [], 0.0
+    for unit in range(5):
+        ident, dur = 10 * (unit + 1), (40.0 if unit == 3 else 10.0)
+        start = at - (overlap_ms if unit else 0.0)
+        spans += [_record("x.load", ident + 1, ident, unit, start + 1, 2),
+                  _record("x.wait", ident + 2, ident, unit, at + 4, 3)]
+        if unit == 1:
+            spans.append(_record("gc.pass", ident + 3, ident + 2, unit,
+                                 at + 5, 1, generation=1, main=1))
+        if unit == 3:
+            spans.append(_record("gc.pass", ident + 3, 0, None, at + 20, 6,
+                                 generation=2, main=0))
+        used = dict(cpu_us=1000 * (unit + 1) + (500 if unit >= 3 else 0),
+                    young_us=200 * (unit + 1),
+                    consume_us=1500) if usage else {}
+        spans.append(_record(root, ident, 0, unit, start,
+                             at + dur - start, complete=1, **used))
+        at += dur
+    return spans
+
+
+# reader, arguments after n, what it reads of the last four units with
+# the root before them (unit 0) in the ring, and of all five without
+READERS = {
+    "unattributed": (program_units.unattributed_ms_per_unit, (),
+                     (70 - 4 * 5) / 4, (80 - 5 * 5) / 5),
+    "collector": (program_units.collector_ms_per_unit, (),
+                  (1 + 6) / 4 + 0.2, (1 + 6) / 5 + 0.2),
+    "cpu": (program_units.arg_per_unit, ("cpu_us", True),
+            (5500 - 1000) / 4, (5500 - 1000) / 4),
+    "consume": (program_units.arg_per_unit, ("consume_us", False),
+                1500, 1500),
+    "worst_by_duration": (program_units.worst_over_median, (False,),
+                          40 / 10, 40 / 10),
+    "worst_by_closes": (program_units.worst_over_median, (True,),
+                        40 / 10, 40 / 10),
+    "second_worst": (program_units.worst_over_median, (False, 1),
+                     10 / 10, 10 / 10),
+}
+
+
+@pytest.mark.parametrize("name", sorted(READERS))
+def test_unit_readers_on_hand_made_spans(name):
+    reader, args, with_before, without = READERS[name]
+    spans = _hand_made()
+    assert reader(spans, 0, "train.step", 4, *args) == pytest.approx(
+        with_before)
+    assert reader(spans, 0, "train.step", 5, *args) == pytest.approx(
+        without)
+    # as the stage reader: too few units, another root, two runs' units
+    assert reader(spans, 0, "train.step", 6, *args) is None
+    assert reader(spans, 0, "pass.batch", 2, *args) is None
+    assert reader(spans, 0, "train.step", 0, *args) is None
+    # the ring dropped spans: fine while the oldest kept is not a
+    # chosen root's; silent once it is
+    assert reader(spans, 9, "train.step", 4, *args) == pytest.approx(
+        with_before)
+    assert reader(spans[3:], 9, "train.step", 4, *args) is None
+    assert reader(spans, 9, "train.step", 5, *args) is None
+    # a program without the roots' integers: the readers that need them
+    bare = _hand_made(usage=False)
+    got = reader(bare, 0, "train.step", 4, *args)
+    if name == "unattributed" or "worst" in name:
+        assert got == pytest.approx(with_before)
+    else:
+        assert got is None
+
+
+def test_uncovered_reader_on_hand_made_overlapping_roots():
+    """A pass's roots: each opens 6 ms before the one before closes."""
+    spans = _hand_made("pass.batch", overlap_ms=6.0)
+    read = program_units.uncovered_ms_per_unit
+    # units 1-4, from unit 1's start (4 ms) to unit 4's end (80): in
+    # there unit 0's wait (4-7), every later child (4 x 5) and the
+    # consumers of units 0-4 (5 x 1.5)
+    assert read(spans, 0, "pass.batch", 4) == pytest.approx(
+        (76 - 3 - 20 - 7.5) / 4)
+    assert read(spans, 0, "pass.batch", 5) == pytest.approx(
+        (80 - 25 - 7.5) / 5)
+    assert read(spans[3:], 9, "pass.batch", 5) is None
+    assert read(_hand_made("pass.batch", 6.0, usage=False), 0, "pass.batch",
+                4) is None
+    # batch periods by the closes: 10, 10, 40, 10 after unit 0's close
+    assert program_units.worst_over_median(
+        spans, 0, "pass.batch", 4, True) == pytest.approx(4.0)
+    assert program_units.worst_over_median(
+        spans, 0, "pass.batch", 4, False) == pytest.approx(46 / 16)
+    # the second longest: of 40, 10 on a median of 25; of one, none
+    assert program_units.worst_over_median(
+        spans, 0, "pass.batch", 2, True) == pytest.approx(40 / 25)
+    assert program_units.worst_over_median(
+        spans, 0, "pass.batch", 2, True, 1) == pytest.approx(10 / 25)
+    assert program_units.worst_over_median(
+        spans, 0, "pass.batch", 1, True, 1) is None
+    rows = profiling.unit_accounts(spans, "pass.batch")
+    assert [round(r["ms"]) for r in rows] == [10, 10, 10, 40, 10]
+    assert rows[3]["passes"] == [(2, 0, 6.0)]
+    assert rows[3]["cpu_ms"] == 1.5 and rows[0]["cpu_ms"] is None
+    (line,) = profiling.slow_unit_lines(rows, "batch")
+    assert line == ("slow batch 3: 40.0 ms for a median of 10.0 | "
+                    "unattributed +30.0 ms | gc.pass generation 2 on "
+                    "another thread 6.0 ms | cpu 1.5 ms")
+    # at a lower factor none more; passes of one kind on one thread
+    # share an entry
+    assert profiling.slow_unit_lines(rows, "batch", 1.1) == [line]
+    rows[3]["passes"] += [(1, 1, 1.5), (1, 1, 2.0)]
+    (line,) = profiling.slow_unit_lines(rows, "batch")
+    assert line.endswith("| 2 x gc.pass generation 1 on this thread 3.5 ms "
+                         "| gc.pass generation 2 on another thread 6.0 ms "
+                         "| cpu 1.5 ms")
+
+
+class SleepyLoader(Loader):
+    """The loader of the train tests; its ``slow``-th batch comes a
+    second late."""
+
+    def __init__(self, n, slow):
+        super().__init__(n)
+        self.slow = slow
+
+    def __iter__(self):
+        for k, batch in enumerate(super().__iter__(), 1):
+            if k == self.slow:
+                time.sleep(1.0)
+            yield batch
+
+
+def test_train_prints_a_slow_step_and_writes_the_worst(tmp_path, timer,
+                                                       capsys):
+    """Nine steps flushed every four; the seventh waits a second for
+    its batch: the second flush (steps 4-7) prints it."""
+    _train_tiny(tmp_path, SleepyLoader(9, slow=7), num_steps=9, sum_freq=4)
+    assert _hooks() == []      # train() returned: its hook went with it
+    lines = [json.loads(line)
+             for line in open(tmp_path / "logs" / "scalars.jsonl")]
+    assert [line["step"] for line in lines] == [4, 8]
+    for line in lines:
+        for key in ("host/unattributed_ms", "host/collector_ms",
+                    "host/cpu_ms", "host/worst_step_ms"):
+            assert line[key] >= 0, key
+    assert lines[1]["host/worst_step_ms"] > 1000
+    assert lines[1]["host/cpu_ms"] < 500
+    slow = [line for line in capsys.readouterr().out.splitlines()
+            if line.startswith("slow step ")]
+    assert any(line.startswith("slow step 7: ") and "loader_wait +" in line
+               for line in slow), slow
+    # the step that compiled collected and froze inside its root: a full
+    # pass under it, on this thread
+    first = by_name(timer.spans(), "train.step")[0]
+    assert any(s.parent == first.id and s.args["generation"] == 2
+               and s.args["main"] == 1
+               for s in by_name(timer.spans(), "gc.pass"))
+
+
+NEW_METRICS = (
+    "unattributed_ms_per_step", "collector_ms_per_step",
+    "host_cpu_ms_per_step", "worst_step_over_median",
+    "uncovered_ms_per_batch.pass", "consume_ms_per_batch.pass",
+    "collector_ms_per_batch.pass", "host_cpu_ms_per_batch.pass",
+    "worst_batch_over_median.pass", "second_worst_step_over_median",
+    "second_worst_batch_over_median.pass")
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_unit_metric_files(name, timer):
+    from benchmark import harness
+
+    manifest = harness.read_json(harness.ROOT, "BENCHMARK.json")
+    spec = harness.read_json(harness.BENCH_DIR, "metrics", name + ".json")
+    (entry,) = [m for m in manifest["per_layer"] if m["name"] == name]
+    assert {k: spec[k] for k in entry} == entry
+    assert entry["source"] == "program_span"
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    assert set(spec["workloads"]) <= set(cells)
+    train = name.endswith(("_step", "step_over_median"))
+    assert all(cells[w]["traffic"].endswith("_train") == train
+               for w in spec["workloads"])
+    assert len(spec["workloads"]) == (4 if train else 2)
+    assert (entry["layer"], entry["moves"]) == (
+        ("train loop", "samples_per_s") if train
+        else ("dataset pass and predictor", "pairs_per_s"))
+    module, _, func = spec["reader"].partition(":")
+    assert module == "program_units"
+    reader = getattr(program_units, func)
+    # it reads the process timer: nothing there, nothing read
+    units = spec["args"]["units"]
+    assert reader({"run": {units: 3}}, **spec["args"]) is None
+    assert reader({"run": {}}, **spec["args"]) is None
+    # and a window of three made by hand
+    for unit in range(4):
+        root = timer.span(spec["args"]["root"], unit=unit, complete=1,
+                          consume_us=0)
+        with timer.span("x.stage"):
+            pass
+        timer.close_root(root)
+    assert reader({"run": {units: 3}}, **spec["args"]) >= 0
 
 
 # ----------------------------------------------------------- named scopes

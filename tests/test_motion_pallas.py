@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from raft_tpu.ops import gru_pallas, motion_pallas, vmem
-from raft_tpu.utils import profiling
 
 # Interpret-mode kernel parity suite — one selectable group across the
 # corr/gru/msda/motion kernels (registered in conftest.py).
@@ -377,27 +376,3 @@ class TestPackWeights:
             motion_pallas.pack_weights(
                 pair("convc1"), pair("convc2"), pair("convf1"),
                 pair("convf2"), pair("convf2"))
-
-
-class TestGroupRows:
-    def test_groups_and_other_sum_to_whole(self):
-        """profiling.group_rows (backs the new per-op motion/GRU MFU
-        columns in profile_probe): first-match-wins bucketing, per-step
-        normalization, and an '(other)' catch-all."""
-        rows = [("fusion.7/_motion_kernel", 4.0, 8),
-                ("jit/convz1_conv", 2.0, 4),
-                ("copy.3", 1.0, 2)]
-        flops = {"fusion.7/_motion_kernel": 8e9}
-        groups = {"motion_pallas": ("_motion_kernel",),
-                  "gru_convs": ("convz", "convr", "convq")}
-        out = profiling.group_rows(rows, flops, groups, steps=2)
-        assert set(out) == {"motion_pallas", "gru_convs", "(other)"}
-        assert out["motion_pallas"]["time_ms"] == pytest.approx(2.0)
-        assert out["motion_pallas"]["count"] == 8
-        assert out["motion_pallas"]["flops"] == 4e9
-        # 4e9 flops over 2.0 ms → 2 TFLOP/s
-        assert out["motion_pallas"]["tflops_per_s"] == pytest.approx(2.0)
-        assert out["gru_convs"]["time_ms"] == pytest.approx(1.0)
-        assert out["gru_convs"]["tflops_per_s"] is None
-        assert out["(other)"]["time_ms"] == pytest.approx(0.5)
-        assert out["(other)"]["count"] == 2
