@@ -164,6 +164,20 @@ def train(tcfg: TrainConfig, mcfg, *,
     sequences (``data_root`` names an optional token file), and the
     counters its step reports (the row's ``step_counters``) ride each
     ``train.step`` span.
+
+    **Order.** The loop is two steps deep: step n+1 is fetched, sharded
+    and dispatched (queued behind step n on the device, the state
+    donated) before step n's metrics are waited for, read and logged,
+    so the host's work runs while the device does. The logged rate is
+    the schedule evaluated eagerly on the host's CPU backend: on the
+    accelerator it would queue behind the step in flight. Nothing is
+    dispatched past a point that needs the state after a step: a
+    ``val_freq`` boundary, ``num_steps``, the preemption vote, an abort
+    after ``max_consecutive_skips`` (one step later than the step that
+    reached it), the loader running out or raising; there every
+    dispatched step is finished and logged first, and only then is the
+    state saved, validated or returned, or the loader's exception let
+    through.
     """
     family = family_of(tcfg.model_family)
     tokens, step_counters = family.tokens, family.step_counters
@@ -213,6 +227,7 @@ def train(tcfg: TrainConfig, mcfg, *,
         freeze_bn = tcfg.stage != "chairs" and not tokens
         step_fn = make_train_step(tcfg, freeze_bn=freeze_bn, mesh=mesh)
         schedule = make_schedule(tcfg)
+        host_cpu = jax.local_devices(backend="cpu")[0]
 
         if dataloader is None:
             from raft_tpu.data.datasets import fetch_dataloader
@@ -247,7 +262,8 @@ def train(tcfg: TrainConfig, mcfg, *,
                                                   iters=tcfg.iters))
 
         step_rng = jax.random.fold_in(rng, 1)
-        total_steps = int(state.step)
+        total_steps = int(state.step)     # steps whose metrics were read
+        dispatched = total_steps          # and the step in flight, if any
         keep_training = total_steps < tcfg.num_steps
         guard = _PreemptionGuard()
         # Multi-host runs vote on the flag only at deterministic step
@@ -255,6 +271,7 @@ def train(tcfg: TrainConfig, mcfg, *,
         # process checks every step with no collective.
         check_every = 1 if jax.process_count() == 1 else 10
         consecutive_skips = 0
+        diverged = None         # why the run stops once nothing is in flight
         loader_stats = getattr(dataloader, "stats", None)
         if loader_stats is not None and \
                 hasattr(loader_stats, "attach_registry"):
@@ -268,14 +285,72 @@ def train(tcfg: TrainConfig, mcfg, *,
         # one spurious spike.
         last_substituted = (loader_stats.substituted_samples
                             if loader_stats is not None else 0)
-        # Loader snapshot taken at each *stepped* boundary. The for-loop
-        # below pulls batch N+1 before the preemption check, so the
-        # loader's live cursor at save time is one batch ahead of the
-        # trained step — saves always use this snapshot, and the
-        # pulled-but-unstepped batch is re-produced on resume.
+        # Loader snapshot taken as each batch is dispatched. A save
+        # comes only once every dispatched step is done, and a batch
+        # pulled but not dispatched (the preemption vote's) is not in
+        # it: resume re-produces that batch.
         loader_snap = (dataloader.state().to_dict()
                        if hasattr(dataloader, "state") else None)
         timer = host_timer()
+
+        def launch(batch):
+            """Shard ``batch`` and queue its step behind the one in
+            flight (the state donated), its metrics' copies to the host
+            behind it; returns the step's metrics and sharded batch."""
+            nonlocal state, dispatched, loader_snap
+            with timer.span("train.shard_batch") as span:
+                batch = shard_batch(batch, mesh)
+                span.nbytes = sum(a.nbytes for a in jax.tree.leaves(batch))
+            with timer.span("train.dispatch"):
+                state, metrics = step_fn(state, batch, step_rng)
+            dispatched += 1
+            for leaf in jax.tree.leaves(metrics):
+                leaf.copy_to_host_async()
+            if loader_snap is not None:
+                with timer.span("train.loader_snapshot"):
+                    loader_snap = dataloader.state().to_dict()
+            return metrics, batch
+
+        def finish(root, metrics):
+            """Wait for the oldest step in flight, read its metrics and
+            log them under ``root``, which then reads ``complete`` 1."""
+            nonlocal total_steps, consecutive_skips, last_substituted
+            # device_get would block as long; waiting first tells the
+            # device's time from the fetch's
+            with timer.span("train.device_wait"):
+                jax.block_until_ready(metrics)
+            with timer.span("train.metrics_fetch", leaves=len(metrics)):
+                host_metrics = jax.device_get(metrics)
+            total_steps += 1
+            for key in step_counters:
+                if key in host_metrics:
+                    root.args[key] = int(host_metrics[key])
+            with timer.span("train.log"):
+                # Degradation counters into the scalar stream (logger
+                # accumulates them as run totals): per-step skip flag
+                # from the jitted guard, substitution delta from the
+                # loader.
+                if loader_stats is not None:
+                    subs = loader_stats.substituted_samples
+                    host_metrics["substituted_samples"] = \
+                        float(subs - last_substituted)
+                    last_substituted = subs
+                if host_metrics.get("skipped_steps", 0.0) > 0:
+                    consecutive_skips += 1
+                else:
+                    consecutive_skips = 0
+                with jax.default_device(host_cpu):
+                    lr = float(schedule(total_steps - 1))
+                logger.push(host_metrics, lr=lr)
+            root.args["complete"] = 1
+
+        def stop_voted():
+            # before a batch is dispatched, at the same step counts on
+            # every host
+            return (dispatched % check_every == 0
+                    and _preemption_agreed(guard.requested))
+
+        pending = None          # the step in flight: (metrics, batch)
         with guard, contextlib.ExitStack() as leaving:
             # what a compiling step freezes (below) is the cyclic
             # collector's again once the loop is left, however it is
@@ -287,75 +362,66 @@ def train(tcfg: TrainConfig, mcfg, *,
             # collectives run in an empty pass)
             while keep_training and not guard.requested:
                 batches = iter(dataloader)
-                while True:
-                    # One root span a step in the process host timer,
-                    # from asking the loader to the end of logger.push
+                exhausted = preempted = False
+                while not exhausted:
+                    # Two deep: step n+1 is dispatched before step n's
+                    # metrics are read, so the host's work lies behind
+                    # the device's. One root span a step in the process
+                    # host timer, from asking the loader for the next
+                    # batch to the end of this step's logger.push
                     # (`complete` 1 there); `unit` is the step's number.
+                    # A root with nothing in flight before it also
+                    # fetches and dispatches its own step; `ahead` 1
+                    # where its step was already in flight when it began
+                    # and it dispatched the next one: its host work lay
+                    # wholly behind the device's.
                     compiles0 = xla_compile_count()
-                    step_span = timer.span("train.step",
-                                           unit=total_steps + 1,
-                                           complete=0)
+                    root = timer.span("train.step", unit=total_steps + 1,
+                                      complete=0, ahead=0)
+                    following, own = None, False
                     try:
-                        with timer.span("train.loader_wait"):
-                            batch = next(batches, None)
-                        if batch is None:
-                            break
-                        if total_steps % check_every == 0 and \
-                                _preemption_agreed(guard.requested):
-                            ckptr.save(state, loader_state=loader_snap)
-                            ckptr.wait_for_pending()  # commit before exit
-                            print(f"preemption checkpoint at step "
-                                  f"{total_steps}; resume with --resume")
-                            return state
-                        with timer.span("train.shard_batch") as span:
-                            batch = shard_batch(batch, mesh)
-                            span.nbytes = sum(
-                                a.nbytes for a in jax.tree.leaves(batch))
-                        with timer.span("train.dispatch"):
-                            state, metrics = step_fn(state, batch, step_rng)
-                        total_steps += 1
-                        if loader_snap is not None:
-                            # The batch is now *trained on*: snapshot
-                            # the cursor at this quiescent point for
-                            # every save until the next step.
-                            with timer.span("train.loader_snapshot"):
-                                loader_snap = dataloader.state().to_dict()
-                        # device_get would block as long; waiting first
-                        # tells the device's time from the fetch's. The
-                        # copies are queued behind the step before the
-                        # wait, as device_get alone would queue them.
-                        for leaf in jax.tree.leaves(metrics):
-                            leaf.copy_to_host_async()
-                        with timer.span("train.device_wait"):
-                            jax.block_until_ready(metrics)
-                        with timer.span("train.metrics_fetch",
-                                        leaves=len(metrics)):
-                            host_metrics = jax.device_get(metrics)
-                        for key in step_counters:
-                            if key in host_metrics:
-                                step_span.args[key] = int(host_metrics[key])
-                        with timer.span("train.log"):
-                            # Degradation counters into the scalar
-                            # stream (logger accumulates them as run
-                            # totals): per-step skip flag from the
-                            # jitted guard, substitution delta from the
-                            # loader.
-                            if loader_stats is not None:
-                                subs = loader_stats.substituted_samples
-                                host_metrics["substituted_samples"] = \
-                                    float(subs - last_substituted)
-                                last_substituted = subs
-                            if host_metrics.get("skipped_steps", 0.0) > 0:
-                                consecutive_skips += 1
-                            else:
-                                consecutive_skips = 0
-                            logger.push(host_metrics,
-                                        lr=float(schedule(total_steps - 1)))
-                        step_span.args["complete"] = 1
+                        if pending is None:
+                            with timer.span("train.loader_wait"):
+                                batch = next(batches, None)
+                            if batch is None:
+                                break
+                            preempted = stop_voted()
+                            if not preempted:
+                                pending, own = launch(batch), True
+                        # No step is dispatched past a point that needs
+                        # a finished state: a val_freq boundary, the
+                        # last step, an abort.
+                        if pending is not None and not preempted \
+                                and diverged is None \
+                                and dispatched % tcfg.val_freq \
+                                and dispatched < tcfg.num_steps:
+                            try:
+                                with timer.span("train.loader_wait"):
+                                    batch = next(batches, None)
+                                exhausted = batch is None
+                                preempted = not exhausted and stop_voted()
+                                if not (exhausted or preempted):
+                                    following = launch(batch)
+                                    root.args["ahead"] = int(not own)
+                            except BaseException:
+                                # What raised began no step: its root
+                                # closes incomplete, and the step in
+                                # flight ends complete and logged under
+                                # a root of its own before the exception
+                                # goes on.
+                                timer.close_root(root)
+                                root = timer.span(
+                                    "train.step", unit=total_steps + 1,
+                                    complete=0, ahead=0)
+                                finish(root, pending[0])
+                                raise
+                        if pending is not None:
+                            finish(root, pending[0])
+                            batch, pending = pending[1], following
                     finally:
                         compiles = xla_compile_count() - compiles0
                         if compiles:
-                            step_span.args["compiles"] = compiles
+                            root.args["compiles"] = compiles
                             # What tracing and compiling left (jaxprs,
                             # the executables' Python side: ~1 M
                             # container objects for a token model's
@@ -366,9 +432,19 @@ def train(tcfg: TrainConfig, mcfg, *,
                             # 1.2-1.8 s (PERF.md, Findings of PR 35).
                             gc.collect()
                             gc.freeze()
-                        timer.close_root(step_span)
-                    if tcfg.max_consecutive_skips and consecutive_skips \
+                        timer.close_root(root)
+                    if preempted:
+                        ckptr.save(state, loader_state=loader_snap)
+                        ckptr.wait_for_pending()  # commit before exit
+                        print(f"preemption checkpoint at step "
+                              f"{total_steps}; resume with --resume")
+                        return state
+                    if tcfg.max_consecutive_skips and diverged is None \
+                            and consecutive_skips \
                             >= tcfg.max_consecutive_skips:
+                        diverged = (f"{consecutive_skips} consecutive "
+                                    f"non-finite steps at step {total_steps}")
+                    if diverged is not None and pending is None:
                         # The guard never applied a non-finite update,
                         # so the state being saved is the last finite
                         # one; persistent divergence needs an operator,
@@ -376,9 +452,8 @@ def train(tcfg: TrainConfig, mcfg, *,
                         ckptr.save(state, loader_state=loader_snap)
                         ckptr.wait_for_pending()   # commit before abort
                         raise TrainingDiverged(
-                            f"{consecutive_skips} consecutive non-finite "
-                            f"steps at step {total_steps}; checkpointed "
-                            f"last finite state to {run_ckpt_dir}")
+                            f"{diverged}; checkpointed the last finite "
+                            f"state (step {total_steps}) to {run_ckpt_dir}")
 
                     if total_steps % tcfg.val_freq == 0:
                         with timer.span("train.checkpoint",

@@ -233,13 +233,16 @@ class TrainLogger:
             else:
                 self.running[k] = self.running.get(k, 0.0) + float(v)
         if self.total_steps % self.sum_freq == 0:
-            print(self._status(lr))
+            account, slow = self._host_step_account()
+            ahead = (f" | dispatched ahead: {account['host/ahead_steps']:g} "
+                     f"of {account['host/steps']:g} steps" if account
+                     else "")
+            print(self._status(lr) + ahead)
             scalars = {k: v / self.sum_freq for k, v in self.running.items()}
             if lr is not None:
                 scalars["lr"] = lr
             scalars.update(self.counters)
             scalars.update(self._host_stage_means())
-            account, slow = self._host_step_account()
             scalars.update(account)
             for line in slow:
                 print(line)
@@ -262,8 +265,11 @@ class TrainLogger:
         (the step that is flushing is still open: the next flush's):
         ``host/unattributed_ms`` (step time under no stage span),
         ``host/collector_ms`` (the cyclic collector's, on any thread),
-        ``host/cpu_ms`` (the loop's thread on a CPU), each a mean, and
-        ``host/worst_step_ms``; and a line for each step that ran over
+        ``host/cpu_ms`` (the loop's thread on a CPU), each a mean,
+        ``host/worst_step_ms``, and how many steps completed and of them
+        how many were already in flight when their step began and had the
+        next one dispatched ahead (``host/steps``,
+        ``host/ahead_steps``); and a line for each step that ran over
         three times their median. Off the hot path: only the spans
         recorded since the last flush, and the step before them, are
         read."""
@@ -282,7 +288,9 @@ class TrainLogger:
         scalars = {
             "host/unattributed_ms": fmean(r["unattributed"] for r in rows),
             "host/collector_ms": fmean(r["collector_ms"] for r in rows),
-            "host/worst_step_ms": max(r["ms"] for r in rows)}
+            "host/worst_step_ms": max(r["ms"] for r in rows),
+            "host/steps": float(sum(r["complete"] for r in rows)),
+            "host/ahead_steps": float(sum(r["ahead"] for r in rows))}
         cpu = [r["cpu_ms"] for r in rows if r["cpu_ms"] is not None]
         if cpu:
             scalars["host/cpu_ms"] = fmean(cpu)

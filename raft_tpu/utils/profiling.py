@@ -449,7 +449,9 @@ def unit_accounts(spans: Sequence[SpanRecord], root: str) -> List[dict]:
       overlap and the short passes (``young_us``) together;
     * ``cpu_ms``: the thread's CPU time
       (:meth:`HostStageTimer.close_root`) since the root before,
-      ``None`` for a run's first root in ``spans``.
+      ``None`` for a run's first root in ``spans``;
+    * ``complete``, ``ahead``: the root's (1 and 0 where it has
+      none).
     """
     roots = [s for s in spans if s.name == root]
     ids = {r.id for r in roots}
@@ -468,7 +470,9 @@ def unit_accounts(spans: Sequence[SpanRecord], root: str) -> List[dict]:
             "stages": {"consume": consumed} if consumed else {},
             "passes": [], "collector_ms": (used["young_us"] or 0) / 1e3,
             "cpu_ms": None if used["cpu_us"] is None
-            else used["cpu_us"] / 1e3})
+            else used["cpu_us"] / 1e3,
+            "complete": r.args.get("complete", 1),
+            "ahead": r.args.get("ahead", 0)})
         begins.append(begin)
         ends.append(end)
         before = r

@@ -849,8 +849,13 @@ class Closed(Exception):
 
 
 def test_train_spans(tmp_path, timer):
-    """Four steps, then a loader that raises: ``train()`` lets the
-    exception through (no exit checkpoint) and the open spans end."""
+    """Four steps, then a loader that raises: ``train()`` finishes and
+    logs step 4, in flight when the fifth fetch raised, under a root of
+    its own (the root whose fetch raised began no step: `complete` 0),
+    then lets the exception through (no exit checkpoint) and the open
+    spans end. Two deep: root n asks for batch n+1, dispatches step n+1
+    and then finishes step n; the first also fetches and dispatches
+    step 1."""
     import json
 
     from raft_tpu.train import train
@@ -873,24 +878,34 @@ def test_train_spans(tmp_path, timer):
 
     spans = staged(timer.spans())
     steps = by_name(spans, "train.step")
-    assert [(s.unit, s.args["complete"]) for s in steps] == [
-        (1, 1), (2, 1), (3, 1), (4, 1), (5, 0)]
+    assert [(s.unit, s.args["complete"], s.args["ahead"]) for s in steps] == [
+        (1, 1, 0), (2, 1, 1), (3, 1, 1), (4, 0, 0), (4, 1, 0)]
     assert all(s.parent == 0 for s in steps)
     assert steps[0].args["compiles"] >= 1
-    assert "compiles" not in steps[3].args
-    for step in steps[:4]:
-        kids = [s for s in spans if s.parent == step.id]
-        assert sorted(k.name for k in kids) == sorted(STEP_CHILDREN)
-        assert all(k.unit == step.unit for k in kids)
+    assert not any("compiles" in s.args for s in steps[3:])
+    # the roots do not overlap, and every other span lies in one of them
+    for a, b in zip(steps, steps[1:]):
+        assert a.start_ns + a.dur_ns <= b.start_ns
+    ids = {s.id for s in steps}
+    assert all(s.parent in ids for s in spans if s.id not in ids)
+    kids = [[s for s in spans if s.parent == step.id] for step in steps]
+    for step, under in zip(steps, kids):
+        assert all(k.unit == step.unit for k in under)
         assert all(k.start_ns >= step.start_ns
                    and k.start_ns + k.dur_ns <= step.start_ns + step.dur_ns
-                   for k in kids)
-        assert by_name(kids, "train.shard_batch")[0].nbytes == (
+                   for k in under)
+    # step 1's fetch, shard and dispatch and step 2's under root 1
+    assert sorted(k.name for k in kids[0]) == sorted(
+        STEP_CHILDREN + STEP_CHILDREN[:3])
+    for under in kids[1:3]:
+        assert sorted(k.name for k in under) == sorted(STEP_CHILDREN)
+        assert by_name(under, "train.shard_batch")[0].nbytes == (
             8 * TH * TW * (3 + 3 + 2 + 1) * 4)
-        assert by_name(kids, "train.metrics_fetch")[0].args["leaves"] >= 5
-    # the step whose next() raised: its wait, closed, and nothing else
-    assert [s.name for s in spans if s.parent == steps[4].id] == [
-        "train.loader_wait"]
+        assert by_name(under, "train.metrics_fetch")[0].args["leaves"] >= 5
+    # the fetch that raised, then step 4 finished under a root of its own
+    assert [[k.name for k in under] for under in kids[3:]] == [
+        ["train.loader_wait"],
+        ["train.device_wait", "train.metrics_fetch", "train.log"]]
     with timer.span("probe") as probe:
         pass
     assert probe.parent == 0
@@ -903,13 +918,17 @@ def test_train_spans(tmp_path, timer):
         {"run": {"steps": 5}}, "train.step", ["train.dispatch"],
         "steps") is None
 
-    # the operator's line: means of the spans closed since the last flush
+    # the operator's line: means of the spans closed since the last
+    # flush, and of the steps closed since, how many had the next step
+    # dispatched ahead
     lines = [json.loads(line)
              for line in open(tmp_path / "logs" / "scalars.jsonl")]
     assert len(lines) == 2 and np.isfinite(lines[0]["loss"])
     for line in lines:
         for name in STEP_CHILDREN[:-1] + ("train.step",):
             assert line["host/" + name[len("train."):] + "_ms"] > 0
+    assert [(line["host/ahead_steps"], line["host/steps"])
+            for line in lines] == [(0, 1), (2, 2)]
 
 
 # ------------------------------------------- the whole of a step, a batch
@@ -1269,8 +1288,8 @@ def test_uncovered_reader_on_hand_made_overlapping_roots():
 
 
 class SleepyLoader(Loader):
-    """The loader of the train tests; its ``slow``-th batch comes a
-    second late."""
+    """The loader of the train tests; its ``slow``-th batch comes three
+    seconds late."""
 
     def __init__(self, n, slow):
         super().__init__(n)
@@ -1279,14 +1298,18 @@ class SleepyLoader(Loader):
     def __iter__(self):
         for k, batch in enumerate(super().__iter__(), 1):
             if k == self.slow:
-                time.sleep(1.0)
+                time.sleep(3.0)
             yield batch
 
 
 def test_train_prints_a_slow_step_and_writes_the_worst(tmp_path, timer,
                                                        capsys):
-    """Nine steps flushed every four; the seventh waits a second for
-    its batch: the second flush (steps 4-7) prints it."""
+    """Nine steps flushed every four; the seventh batch comes three
+    seconds late, and step 6's root is the one that asks for it (two
+    deep: root n fetches batch n+1): the second flush (steps 4-7) prints
+    it. Step 6 runs on the device while the loader sleeps, so the root
+    is the sleep long, not the sleep and a step: three seconds keep it
+    over three times the median step on a loaded machine."""
     _train_tiny(tmp_path, SleepyLoader(9, slow=7), num_steps=9, sum_freq=4)
     assert _hooks() == []      # train() returned: its hook went with it
     lines = [json.loads(line)
@@ -1296,11 +1319,11 @@ def test_train_prints_a_slow_step_and_writes_the_worst(tmp_path, timer,
         for key in ("host/unattributed_ms", "host/collector_ms",
                     "host/cpu_ms", "host/worst_step_ms"):
             assert line[key] >= 0, key
-    assert lines[1]["host/worst_step_ms"] > 1000
+    assert lines[1]["host/worst_step_ms"] > 3000
     assert lines[1]["host/cpu_ms"] < 500
     slow = [line for line in capsys.readouterr().out.splitlines()
             if line.startswith("slow step ")]
-    assert any(line.startswith("slow step 7: ") and "loader_wait +" in line
+    assert any(line.startswith("slow step 6: ") and "loader_wait +" in line
                for line in slow), slow
     # the step that compiled collected and froze inside its root: a full
     # pass under it, on this thread

@@ -727,10 +727,12 @@ def test_train_aborts_after_consecutive_nan_steps(tmp_path):
               dataloader=SyntheticLoader(n=50),
               logger=TrainLogger(str(tmp_path / "logs" / "t"),
                                  sum_freq=2, tensorboard=False))
-    # the checkpointed state is the last finite one
+    # the checkpointed state is the last finite one: the third skip is
+    # read with step 4 already in flight, which is finished (skipped
+    # too) before the save
     d = str(tmp_path / "ckpts" / "t")
     step = ckpt_lib.latest_step(d)
-    assert step == 3                   # step counter advanced 3 skips
+    assert step == 4                   # step counter advanced 4 skips
     _, _, fresh = _tiny_state(seed=1)
     restored = ckpt_lib.restore_checkpoint(d, fresh)
     assert all(np.all(np.isfinite(np.asarray(l)))
