@@ -23,7 +23,8 @@ def _loss_rows(log_dir):
     return [r for r in rows if "loss" in r]
 
 
-def test_train_loop_follows_the_reference_counts_and_resumes(tmp_path):
+def test_train_loop_follows_the_reference_counts_and_resumes(
+        tmp_path, compile_cache):
     """``train()`` with ``model_family="afmoe"`` through the real loop
     (8 sequences over the tests' 8-device data mesh), its state begun
     from the benchmark's seeded weights: the first three losses are the

@@ -19,6 +19,10 @@ from raft_tpu.models.raft import RAFT
 from raft_tpu.parallel import create_train_state, make_mesh
 from raft_tpu.utils.logger import MetricLogger, SmoothedValue, TrainLogger
 
+# ``train()`` runs five times in this file: with the compile cache on,
+# the three runs of four steps compile their step program once
+pytestmark = pytest.mark.usefixtures("compile_cache")
+
 H, W = 64, 96
 
 
@@ -200,7 +204,9 @@ def test_preemption_checkpoints_and_resumes(tmp_path):
     with nothing saved, SURVEY.md §5)."""
     from raft_tpu.train import _PreemptionGuard, train
 
-    tcfg, mcfg = _tiny_setup(tmp_path, num_steps=50)
+    # num_steps 4, as the resume's and test_train_loop_end_to_end's
+    # first run: one step program for the three, compiled once
+    tcfg, mcfg = _tiny_setup(tmp_path, num_steps=4)
 
     class PreemptingLoader(SyntheticLoader):
         """Requests preemption after the second batch, the way a SIGTERM
@@ -221,8 +227,6 @@ def test_preemption_checkpoints_and_resumes(tmp_path):
                 yield batch
 
     # intercept the guard the loop creates
-    import dataclasses
-
     import raft_tpu.train as train_mod
     box = [None]
 
@@ -243,8 +247,7 @@ def test_preemption_checkpoints_and_resumes(tmp_path):
     assert ckpt_lib.latest_step(str(tmp_path / "ckpts" / "t")) == 2
 
     # resume completes to num_steps without re-running saved steps
-    tcfg2 = dataclasses.replace(tcfg, num_steps=4)
-    state2 = train(tcfg2, mcfg, ckpt_dir=str(tmp_path / "ckpts"),
+    state2 = train(tcfg, mcfg, ckpt_dir=str(tmp_path / "ckpts"),
                    log_dir=str(tmp_path / "logs"),
                    dataloader=SyntheticLoader(), resume=True,
                    logger=TrainLogger(str(tmp_path / "logs" / "t"),

@@ -19,6 +19,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
+from conftest import CHILD_TIME_LIMIT  # noqa: E402
 
 SMALL = os.path.join(REPO, "assets", "golden", "weights_small.npz")
 
@@ -32,6 +33,36 @@ def _leave_the_compile_cache_as_found():
     was = jax.config.jax_compilation_cache_dir
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def rehearsal(tmp_path_factory):
+    """``--multichip``'s two comparisons on four virtual CPU devices, in
+    a fresh interpreter that owns its device count, started with the
+    module: it runs beside the phases below, and its test collects it.
+    The persistent compile cache is on, in a directory of its own, as
+    ``main()`` turns it on before the phases: the one-device side's eager
+    init reads the data-parallel side's small executables back."""
+    out = tmp_path_factory.mktemp("rehearsal")
+    code = (
+        "import chip_smoke as cs\n"
+        "from raft_tpu.utils.compile_cache import enable_compile_cache\n"
+        "enable_compile_cache()\n"
+        "cs.multichip_train(hw=(64, 64), batch=4, iters=2, small=True,"
+        " steps=2)\n"
+        f"cs.multichip_predict(weights={SMALL!r}, hw=(64, 96),"
+        " fallback_hw=(64, 96), iters=2, small=True)\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "JAX_COMPILATION_CACHE_DIR": str(out / "compile_cache")}
+    with open(out / "said.txt", "w") as said:
+        proc = subprocess.Popen([sys.executable, "-c", code], env=env,
+                                cwd=REPO, stdout=said,
+                                stderr=subprocess.STDOUT)
+    yield proc, out / "said.txt"
+    if proc.poll() is None:
+        proc.kill()
+        proc.wait()
 
 
 @pytest.fixture(scope="module")
@@ -56,13 +87,13 @@ def test_serve_phase(predictor, capsys):
 
 
 def test_train_phase(tmp_path, capsys):
-    chip_smoke.phase_train(str(tmp_path), hw=(64, 96), batch=8, iters=2,
-                           small=True, steps=3, expect=())
+    chip_smoke.phase_train(str(tmp_path), hw=(64, 64), batch=8, iters=2,
+                           small=True, steps=2, expect=())
     line = [ln for ln in capsys.readouterr().out.splitlines()
             if ln.startswith("[train]")][-1]
     fields = json.loads(line[len("[train] "):])
-    assert fields["steps"] == 3 and fields["skipped_steps"] == 0
-    assert fields["checkpoint_restored_equal"] and fields["saved_step"] == 3
+    assert fields["steps"] == 2 and fields["skipped_steps"] == 0
+    assert fields["checkpoint_restored_equal"] and fields["saved_step"] == 2
     assert fields["augment_backend"] in ("native", "numpy")
 
 
@@ -81,29 +112,26 @@ def test_no_chip_means_failure_and_no_result_line():
     and no result line."""
     proc = subprocess.run([sys.executable, os.path.join(REPO,
                                                         "chip_smoke.py")],
-                          capture_output=True, text=True, timeout=300,
+                          capture_output=True, text=True,
+                          timeout=CHILD_TIME_LIMIT,
                           env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode != 0
     assert '"ok"' not in proc.stdout
 
 
 @pytest.mark.multidevice
-def test_multichip_rehearsal_on_four_virtual_devices():
-    """``--multichip``'s two comparisons on four virtual CPU devices, in a
-    fresh interpreter that owns its device count."""
-    code = (
-        "import chip_smoke as cs\n"
-        "cs.multichip_train(hw=(64, 96), batch=4, iters=2, small=True,"
-        " steps=3)\n"
-        f"cs.multichip_predict(weights={SMALL!r}, hw=(128, 160),"
-        " fallback_hw=(64, 96), iters=2, small=True)\n")
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO,
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
-    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
-                          capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, (proc.stdout + proc.stderr)[-3000:]
+def test_multichip_rehearsal_on_four_virtual_devices(rehearsal):
+    """Both comparisons of the rehearsal the module started hold."""
+    proc, said = rehearsal
+    try:
+        proc.wait(timeout=CHILD_TIME_LIMIT)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        proc.wait()
+    out = said.read_text()
+    assert proc.returncode == 0, out[-3000:]
     lines = {ln.split("]")[0][1:]: json.loads(ln.split("] ", 1)[1])
-             for ln in proc.stdout.splitlines() if ln.startswith("[multi")}
+             for ln in out.splitlines() if ln.startswith("[multi")}
     assert lines["multichip-train"]["devices_holding"]["data_parallel"] == {
         "batch": 4, "params": 4}
     assert lines["multichip-predict"]["devices_holding_output"] == 4

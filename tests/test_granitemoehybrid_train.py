@@ -27,7 +27,8 @@ def _document_starts(batch):
     return int((np.diff(batch["segment_ids"], axis=1) != 0).sum())
 
 
-def test_train_loop_follows_the_reference_counts_and_resumes(tmp_path):
+def test_train_loop_follows_the_reference_counts_and_resumes(
+        tmp_path, compile_cache):
     """``train()`` with ``model_family="granitemoehybrid"`` through the
     real loop (8 sequences over the tests' 8-device data mesh), its
     state begun from the benchmark's seeded weights: the first three

@@ -28,7 +28,7 @@ def _loss_rows(log_dir):
     return [r for r in rows if "loss" in r]
 
 
-def test_train_loop_counters_and_resume(tmp_path):
+def test_train_loop_counters_and_resume(tmp_path, compile_cache):
     """``train()`` with ``model_family="lfm2_moe"`` through the real
     loop (8 sequences over the tests' 8-device data mesh): the routing
     counters ride every ``train.step`` span and the scalar stream; a

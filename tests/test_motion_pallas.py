@@ -157,8 +157,8 @@ class TestGradParity:
             return jnp.sum(motion_pallas.motion_encoder(
                 fl, co, mats, interpret=True))
 
-        g_flax = jax.grad(loss_flax, argnums=(0, 1))(flow, corr)
-        g_kern = jax.grad(loss_kern, argnums=(0, 1))(flow, corr)
+        g_flax = jax.jit(jax.grad(loss_flax, argnums=(0, 1)))(flow, corr)
+        g_kern = jax.jit(jax.grad(loss_kern, argnums=(0, 1)))(flow, corr)
         for a, b in zip(g_flax, g_kern):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-4, rtol=0)
@@ -175,8 +175,8 @@ class TestGradParity:
             return jnp.sum(motion_pallas.motion_encoder(
                 flow, corr, _pack_from_params(params), interpret=True))
 
-        g_flax = jax.grad(loss_flax)(vs["params"])
-        g_kern = jax.grad(loss_kern)(vs["params"])
+        g_flax = jax.jit(jax.grad(loss_flax))(vs["params"])
+        g_kern = jax.jit(jax.grad(loss_kern))(vs["params"])
         for a, b in zip(jax.tree_util.tree_leaves(g_flax),
                         jax.tree_util.tree_leaves(g_kern)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),

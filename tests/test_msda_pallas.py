@@ -208,12 +208,14 @@ def test_channel_sweep_gradient_parity(m, d):
             return jnp.sum(fn(args[0], shapes, args[1], args[2]) * cot)
         return f
 
-    for argnum, name in ((0, "value"), (1, "locations"), (2, "weights")):
-        g_ref = jax.grad(loss(ms_deform_attn), argnums=argnum)(
-            value, loc, w)
-        g_ker = jax.grad(loss(ms_deform_attn_pallas), argnums=argnum)(
-            value, loc, w)
-        np.testing.assert_allclose(np.asarray(g_ker), np.asarray(g_ref),
+    # one backward pass a path, for all three inputs
+    g_ref = jax.jit(jax.grad(loss(ms_deform_attn), argnums=(0, 1, 2)))(
+        value, loc, w)
+    g_ker = jax.jit(jax.grad(loss(ms_deform_attn_pallas),
+                             argnums=(0, 1, 2)))(value, loc, w)
+    for ker, ref, name in zip(g_ker, g_ref,
+                              ("value", "locations", "weights")):
+        np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    atol=2e-3, rtol=1e-3,
                                    err_msg=f"d={d} {name}")
 

@@ -22,6 +22,7 @@ import socket
 import subprocess
 import sys
 import textwrap
+import time
 
 import pytest
 
@@ -142,22 +143,16 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _scaled(timeout: int) -> int:
-    """Scale a child timeout with host load: the budgets below carry a
-    ~1.7x margin on an idle 1-core host, which a concurrent on-chip
-    capture eats (round-4 flake: 420 s hit under load, 243 s in
-    isolation — VERDICT r4 weak #6). 1-minute loadavg ≈ number of
-    runnable processes competing for this host's core."""
-    try:
-        load = os.getloadavg()[0] / max(os.cpu_count() or 1, 1)
-    except OSError:
-        load = 0.0
-    return int(timeout * (1.0 + min(3.0, max(0.0, load))))
+def _run_children(template: str, while_they_run=lambda: None):
+    """Spawn two coordinated children from ``template``, call
+    ``while_they_run()`` in this process meanwhile, and return the
+    children's RESULT dicts keyed by pid with what it returned. Both
+    children share one deadline, under the test's own limit; on the way
+    out, by any path, neither is left running. (The children import this
+    module: conftest, which sets the parent's topology flags, is imported
+    here and not at the top.)"""
+    from conftest import CHILD_TIME_LIMIT as timeout
 
-
-def _run_children(template: str, timeout: int):
-    """Spawn two coordinated children from ``template``, return their
-    RESULT dicts keyed by pid."""
     child_env = {**os.environ,
                  "PYTHONPATH": os.pathsep.join([REPO_ROOT, TESTS_DIR])}
     code = template % {"port": _free_port()}
@@ -166,26 +161,37 @@ def _run_children(template: str, timeout: int):
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         env=child_env)
         for i in range(2)]
+    deadline = time.monotonic() + timeout
     results = {}
-    for p in procs:
-        try:
-            out, _ = p.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            for q in procs:
+    try:
+        here = while_they_run()
+        for p in procs:
+            try:
+                out, _ = p.communicate(
+                    timeout=max(deadline - time.monotonic(), 1.0))
+            except subprocess.TimeoutExpired:
+                for q in procs:
+                    q.kill()
+                out, _ = p.communicate()
+                pytest.fail(f"distributed child over its {timeout} s "
+                            f"(coordinator hang?):\n{out[-2000:]}")
+            assert p.returncode == 0, out[-2000:]
+            lines = [ln for ln in out.splitlines()
+                     if ln.startswith("RESULT ")]
+            assert lines, f"no RESULT line in child output:\n{out[-2000:]}"
+            r = json.loads(lines[-1][len("RESULT "):])
+            results[r["pid"]] = r
+    finally:
+        for q in procs:
+            if q.poll() is None:
                 q.kill()
-            pytest.fail("distributed child timed out (coordinator hang?)")
-        assert p.returncode == 0, out[-2000:]
-        lines = [ln for ln in out.splitlines()
-                 if ln.startswith("RESULT ")]
-        assert lines, f"no RESULT line in child output:\n{out[-2000:]}"
-        r = json.loads(lines[-1][len("RESULT "):])
-        results[r["pid"]] = r
+                q.wait()
     assert set(results) == {0, 1}
-    return results
+    return results, here
 
 
 def test_two_process_distributed_helpers():
-    results = _run_children(CHILD_HELPERS, timeout=_scaled(300))
+    results, _ = _run_children(CHILD_HELPERS)
     for pid, r in results.items():
         assert r["process_count"] == 2
         assert r["local_devices"] == 1
@@ -204,7 +210,9 @@ def test_two_process_sharded_train_step():
     run of the same step to float tolerance."""
     import numpy as np
 
-    results = _run_children(CHILD_TRAIN, timeout=_scaled(420))
+    # the single-process ground truth is computed while the children run
+    results, (_, m_single) = _run_children(
+        CHILD_TRAIN, lambda: run_one_step(mesh=None))
     if any(r.get("unsupported") for r in results.values()):
         pytest.skip("jaxlib backend lacks cross-process XLA computations "
                     "(CPU multiprocess); host-side distributed machinery "
@@ -213,6 +221,5 @@ def test_two_process_sharded_train_step():
     # replicated metrics: both hosts computed the same global loss
     assert abs(results[0]["loss"] - results[1]["loss"]) < 1e-6
 
-    _, m_single = run_one_step(mesh=None)
     np.testing.assert_allclose(results[0]["loss"],
                                float(m_single["loss"]), rtol=2e-4)

@@ -14,7 +14,8 @@ def small_model():
     cfg = RAFTConfig(small=True)
     m = RAFT(cfg)
     img = jnp.zeros((1, 64, 96, 3), jnp.float32)
-    variables = m.init(jax.random.PRNGKey(0), img, img, iters=1)
+    variables = jax.jit(lambda a, b: m.init(jax.random.PRNGKey(0), a, b,
+                                            iters=1))(img, img)
     return m, variables
 
 
@@ -26,7 +27,8 @@ def test_param_counts_match_reference(small_model):
 
     m = RAFT(RAFTConfig())
     img = jnp.zeros((1, 64, 96, 3), jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), img, img, iters=1)
+    v = jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0), img, img,
+                                      iters=1))      # shapes are enough
     n_large = sum(x.size for x in jax.tree.leaves(v["params"]))
     assert n_large == 5_257_536
 
@@ -85,7 +87,7 @@ def test_gradients_flow(small_model):
         out = m.apply({"params": params}, img1, img2, iters=2)
         return jnp.abs(out).mean()
 
-    g = jax.grad(loss)(v["params"])
+    g = jax.jit(jax.grad(loss))(v["params"])
     norms = [float(jnp.abs(x).max()) for x in jax.tree.leaves(g)]
     assert max(norms) > 0
 

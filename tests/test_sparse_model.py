@@ -110,35 +110,74 @@ def test_cnn_encoders(rng):
     B, H, W = 1, 64, 96
     x = jnp.asarray(rng.uniform(-1, 1, (2 * B, H, W, 3)), jnp.float32)
     enc = CNNEncoder(base_channel=32)
-    p = enc.init(jax.random.PRNGKey(0), x)
-    x1, x2 = enc.apply(p, x)
+    p = jax.jit(enc.init)(jax.random.PRNGKey(0), x)
+    x1, x2 = jax.jit(enc.apply)(p, x)
     assert [f.shape for f in x1] == [
         (B, 16, 24, 48), (B, 8, 12, 64), (B, 4, 6, 96), (B, 2, 3, 128)]
     # the reference's X2[0]-quirk: level-0 of X2 is image1's features
     np.testing.assert_array_equal(np.asarray(x2[0]), np.asarray(x1[0]))
 
     dec = CNNDecoder(base_channel=32)
-    variables = dec.init(jax.random.PRNGKey(0), x)
-    (y1, y2, u1), _ = dec.apply(variables, x, train=True,
-                                mutable=["batch_stats"])
+    variables = jax.jit(dec.init)(jax.random.PRNGKey(0), x)
+    (y1, y2, u1), _ = jax.jit(lambda v, x: dec.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables, x)
     assert u1.shape == (B, 16, 24, 48)   # stride 4, up_dim = 1.5c
 
 
-def test_sparse_raft_forward(rng):
-    from raft_tpu.models.ours import SparseRAFT
+# One sparse model, initialised once (under ``jit``: eagerly the family's
+# init alone takes half a minute of this CPU), and its forward compiled
+# once: the forward, gradient, test-mode and alternate-corr tests below
+# all read them.
 
-    cfg = OursConfig(base_channel=16, d_model=32, outer_iterations=2,
-                     num_keypoints=16, n_heads=4, n_points=2)
-    model = SparseRAFT(cfg)
-    B, H, W = 1, 64, 96
-    img = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
+B, H, W = 1, 64, 96
+CFG = OursConfig(base_channel=16, d_model=32, outer_iterations=2,
+                 num_keypoints=16, n_heads=4, n_points=2, dropout=0.0)
+
+
+@pytest.fixture(scope="module")
+def images():
+    rng = jax.random.PRNGKey(0)
+    img1 = jax.random.uniform(rng, (B, H, W, 3)) * 255.0
+    img2 = jnp.roll(img1, 2, axis=2)
+    return img1, img2
+
+
+@pytest.fixture(scope="module")
+def sparse(images):
+    """The model (``alternate_corr`` on, the default), its variables,
+    and the outputs of its compiled forward on ``images``."""
+    model = SparseRAFT(CFG)
     k = jax.random.PRNGKey(0)
-    variables = model.init({"params": k, "dropout": k}, img, img)
-    (flows, sparse), _ = model.apply(variables, img, img,
-                                     mutable=["batch_stats"])
-    assert len(flows) == 2 and len(sparse) == 2
+    variables = jax.jit(lambda a, b: model.init(
+        {"params": k, "dropout": k}, a, b))(*images)
+    forward = jax.jit(lambda v, a, b: model.apply(v, a, b,
+                                                  mutable=["batch_stats"]))
+    (flows, sparse_out), _ = forward(variables, *images)
+    return model, variables, flows, sparse_out
+
+
+@pytest.fixture(scope="module")
+def grads(sparse, images):
+    """Gradients of the summed flow magnitudes in training mode, through
+    the shared model's on-demand correlation."""
+    model, variables, _, _ = sparse
+    k = jax.random.PRNGKey(0)
+
+    def loss(params):
+        (flows, _), _ = model.apply(
+            {"params": params, "batch_stats": variables["batch_stats"]},
+            *images, train=True, rngs={"dropout": k},
+            mutable=["batch_stats"])
+        return sum(jnp.abs(f).mean() for f in flows)
+
+    return jax.tree.leaves(jax.jit(jax.grad(loss))(variables["params"]))
+
+
+def test_sparse_raft_forward(sparse, images):
+    model, variables, flows, sparse_out = sparse
+    assert len(flows) == 2 and len(sparse_out) == 2
     assert flows[0].shape == (B, H, W, 2)
-    src_points, key_flow, masks, scores = sparse[-1]
+    src_points, key_flow, masks, scores = sparse_out[-1]
     assert src_points.shape == (B, 16, 2)
     assert key_flow.shape == (B, 16, 2)
     assert masks.shape == (B, 16, H // 4, W // 4)
@@ -146,63 +185,34 @@ def test_sparse_raft_forward(rng):
     for f in flows:
         assert np.isfinite(np.asarray(f)).all()
 
-    # jits cleanly (static shapes; unrolled outer iterations)
-    fn = jax.jit(lambda v, a, b: model.apply(v, a, b,
-                                             mutable=["batch_stats"]))
-    (flows2, _), _ = fn(variables, img, img)
-    np.testing.assert_allclose(np.asarray(flows2[0]), np.asarray(flows[0]),
+    # jits cleanly (static shapes; unrolled outer iterations): the
+    # compiled forward gives the eager one's flow
+    (eager, _), _ = model.apply(variables, *images, mutable=["batch_stats"])
+    np.testing.assert_allclose(np.asarray(flows[0]), np.asarray(eager[0]),
                                rtol=2e-4, atol=2e-4)
 
 
-def test_sparse_raft_gradients_flow(rng):
-    from raft_tpu.models.ours import SparseRAFT
-
-    cfg = OursConfig(base_channel=16, d_model=32, outer_iterations=1,
-                     num_keypoints=9, n_heads=4, n_points=2, dropout=0.0)
-    model = SparseRAFT(cfg)
-    B, H, W = 1, 64, 64
-    img = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
-    k = jax.random.PRNGKey(0)
-    variables = model.init({"params": k, "dropout": k}, img, img)
-
-    def loss(params):
-        (flows, _), _ = model.apply(
-            {"params": params, "batch_stats": variables["batch_stats"]},
-            img, img, train=True, rngs={"dropout": k},
-            mutable=["batch_stats"])
-        return sum(jnp.abs(f).mean() for f in flows)
-
-    grads = jax.grad(loss)(variables["params"])
-    gnorm = sum(float(jnp.sum(jnp.abs(g)))
-                for g in jax.tree.leaves(grads))
+def test_sparse_raft_gradients_flow(grads):
+    gnorm = sum(float(np.abs(np.asarray(g)).sum()) for g in grads)
     assert np.isfinite(gnorm) and gnorm > 0
 
 
-def test_sparse_test_mode_drives_shared_eval_harness(rng):
+def test_sparse_test_mode_drives_shared_eval_harness(sparse, images):
     """SparseRAFT must satisfy the (flow_low, flow_up) test_mode contract
     so FlowPredictor/validators drive both families."""
-    import jax
-    import jax.numpy as jnp
-
-    from raft_tpu.config import OursConfig
     from raft_tpu.evaluate import FlowPredictor
-    from raft_tpu.models import SparseRAFT
 
-    cfg = OursConfig(base_channel=16, d_model=32, num_feature_levels=2,
-                     outer_iterations=2, num_keypoints=4, n_heads=4,
-                     n_points=2, dropout=0.0)
-    model = SparseRAFT(cfg)
-    img = jnp.asarray(rng.uniform(0, 255, (1, 32, 48, 3)), jnp.float32)
-    vs = model.init({"params": jax.random.PRNGKey(0),
-                     "dropout": jax.random.PRNGKey(0)}, img, img, iters=1)
-    pred = FlowPredictor(model, vs, iters=2, batch_size=1)
-    low, up = pred(np.asarray(img[0]), np.asarray(img[0]))
-    assert up.shape == (32, 48, 2) and low.shape == (4, 6, 2)
+    model, variables, _, _ = sparse
+    img = np.asarray(images[0][0])
+    pred = FlowPredictor(model, variables, iters=2, batch_size=1)
+    low, up = pred(img, img)
+    assert up.shape == (H, W, 2) and low.shape == (H // 8, W // 8, 2)
     assert np.isfinite(up).all()
 
     # warm start is a canonical-RAFT capability; the sparse family refuses
     with pytest.raises(ValueError):
-        model.apply(vs, img, img, flow_init=jnp.zeros((1, 4, 6, 2)))
+        model.apply(variables, *images,
+                    flow_init=jnp.zeros((B, H // 8, W // 8, 2)))
 
 
 @pytest.mark.parametrize("channels", [5, 16])
@@ -228,59 +238,28 @@ def test_msda_gradcheck_channels(rng, channels):
                 atol=1e-2, rtol=1e-2)
 
 
-def test_sparse_alternate_corr_matches_materialized(rng):
+def test_sparse_alternate_corr_matches_materialized(sparse, images,
+                                                    grads):
     """cfg.alternate_corr recomputes the one-shot center-grid correlation
     windows on demand (deleting the all-pairs volume + avg-pool chain the
     round-4 profile measured at ~17% of the train step) — outputs must
-    match the materialized default to float accumulation order, and
-    gradients must flow."""
+    match the materialized path to float accumulation order, and
+    gradients must flow (``grads``: through the on-demand model)."""
     import dataclasses
 
-    from raft_tpu.models.ours import SparseRAFT
-
-    cfg = OursConfig(base_channel=16, d_model=32, outer_iterations=1,
-                     num_keypoints=16, n_heads=4, n_points=2)
-    B, H, W = 1, 64, 96
-    img1 = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
-    img2 = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
-    k = jax.random.PRNGKey(0)
-    dense = SparseRAFT(cfg)
-    variables = dense.init({"params": k, "dropout": k}, img1, img2)
-    ondemand = SparseRAFT(dataclasses.replace(cfg, alternate_corr=True))
-
-    (flows_d, _), _ = dense.apply(variables, img1, img2,
-                                  mutable=["batch_stats"])
-    (flows_o, _), _ = ondemand.apply(variables, img1, img2,
-                                     mutable=["batch_stats"])
+    _, variables, flows_o, _ = sparse
+    materialized = SparseRAFT(dataclasses.replace(CFG, alternate_corr=False))
+    (flows_d, _), _ = jax.jit(lambda v, a, b: materialized.apply(
+        v, a, b, mutable=["batch_stats"]))(variables, *images)
     np.testing.assert_allclose(np.asarray(flows_o[-1]),
                                np.asarray(flows_d[-1]),
                                rtol=2e-4, atol=2e-4)
 
-    def loss(params):
-        (flows, _), _ = ondemand.apply(
-            {"params": params, **{k_: v for k_, v in variables.items()
-                                  if k_ != "params"}},
-            img1, img2, mutable=["batch_stats"])
-        return jnp.mean(jnp.abs(flows[-1]))
-
-    g = jax.grad(loss)(variables["params"])
-    leaves = jax.tree_util.tree_leaves(g)
-    assert any(float(jnp.max(jnp.abs(l))) > 0 for l in leaves)
-    assert all(np.isfinite(np.asarray(l)).all() for l in leaves)
+    assert any(float(np.abs(np.asarray(g)).max()) > 0 for g in grads)
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads)
 
 
 # ``OursConfig.encoder_iterations``: the ours_07 encoder stacks
-
-B, H, W = 1, 64, 96
-
-
-@pytest.fixture(scope="module")
-def images():
-    rng = jax.random.PRNGKey(0)
-    img1 = jax.random.uniform(rng, (B, H, W, 3)) * 255.0
-    img2 = jnp.roll(img1, 2, axis=2)
-    return img1, img2
-
 
 class TestOurs07EncoderMode:
     def test_encoder_stacks_active(self, images):
@@ -290,20 +269,16 @@ class TestOurs07EncoderMode:
                          encoder_iterations=2)
         m = SparseRAFT(cfg)
         rng = jax.random.PRNGKey(3)
-        v = m.init({"params": rng, "dropout": rng}, img1, img2)
+        v = jax.jit(lambda a, b: m.init({"params": rng, "dropout": rng},
+                                        a, b))(img1, img2)
         names = set(v["params"].keys())
         assert {"encoder_0", "encoder_1", "context_encoder_0",
                 "context_encoder_1", "encoder_pos_proj"} <= names
-        fp, sp = m.apply(v, img1, img2)
+        fp, sp = jax.jit(m.apply)(v, img1, img2)
         assert len(fp) == 2 and fp[-1].shape == (B, H, W, 2)
         assert bool(jnp.isfinite(fp[-1]).all())
 
-    def test_default_has_no_encoder_params(self, images):
-        img1, img2 = images
-        cfg = OursConfig(base_channel=16, d_model=32, outer_iterations=1,
-                         num_keypoints=9, n_heads=4, dropout=0.0)
-        m = SparseRAFT(cfg)
-        rng = jax.random.PRNGKey(3)
-        v = m.init({"params": rng, "dropout": rng}, img1, img2)
+    def test_default_has_no_encoder_params(self, sparse):
+        _, variables, _, _ = sparse          # encoder_iterations 0
         assert not any(n.startswith("encoder_")
-                       for n in v["params"].keys())
+                       for n in variables["params"].keys())

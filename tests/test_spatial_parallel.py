@@ -11,16 +11,22 @@ from raft_tpu.parallel.mesh import make_mesh
 from raft_tpu.parallel.spatial import image_spec, spatial_jit
 
 
+def _init(model, img1, img2):
+    """The model's variables, initialised as one compiled program."""
+    key = jax.random.PRNGKey(0)
+    return jax.jit(lambda a, b: model.init(
+        {"params": key, "dropout": key}, a, b, iters=1))(img1, img2)
+
 def test_spatial_forward_matches_replicated(rng):
     cfg = RAFTConfig(small=True, iters=3)
     model = RAFT(cfg)
     B, H, W = 2, 32, 48
     img1 = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
     img2 = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
-    key = jax.random.PRNGKey(0)
-    vs = model.init({"params": key, "dropout": key}, img1, img2, iters=1)
+    vs = _init(model, img1, img2)
 
-    ref = model.apply(vs, img1, img2, test_mode=True)[1]
+    ref = jax.jit(lambda v, a, b: model.apply(v, a, b, test_mode=True)[1])(
+        vs, img1, img2)
 
     mesh = make_mesh(n_data=2, n_spatial=4)
     fwd = spatial_jit(
@@ -86,10 +92,10 @@ def test_spatial_banded_engine_matches_replicated(rng, monkeypatch):
     B, H, W = 2, 64, 96           # h8=8: no degenerate pooled level
     img1 = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
     img2 = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
-    key = jax.random.PRNGKey(0)
-    vs = model.init({"params": key, "dropout": key}, img1, img2, iters=1)
+    vs = _init(model, img1, img2)
 
-    ref = model.apply(vs, img1, img2, test_mode=True)[1]
+    ref = jax.jit(lambda v, a, b: model.apply(v, a, b, test_mode=True)[1])(
+        vs, img1, img2)
 
     mesh = make_mesh(n_data=2, n_spatial=2)      # h8 = 4 rows, 2 shards
     fwd = spatial_jit(
@@ -104,8 +110,7 @@ def test_spatial_sharding_actually_partitions(rng):
     model = RAFT(cfg)
     B, H, W = 1, 16, 32
     img = jnp.asarray(rng.uniform(0, 255, (B, H, W, 3)), jnp.float32)
-    key = jax.random.PRNGKey(0)
-    vs = model.init({"params": key, "dropout": key}, img, img, iters=1)
+    vs = _init(model, img, img)
 
     mesh = make_mesh(n_data=1, n_spatial=8)
     fwd = spatial_jit(

@@ -348,12 +348,13 @@ class TestGradParity:
 
         for f in ("RAFT_MOTION_PALLAS", "RAFT_GRU_PALLAS"):
             monkeypatch.delenv(f, raising=False)
+        # each compiled as one program (the path is read as it traces)
         monkeypatch.setenv("RAFT_STEP_PALLAS", "0")
-        g_conv = jax.grad(loss, argnums=(0, 1, 2, 3))(net, inp, corr,
-                                                      flow)
+        g_conv = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+            net, inp, corr, flow)
         monkeypatch.setenv("RAFT_STEP_PALLAS", "1")
-        g_fused = jax.grad(loss, argnums=(0, 1, 2, 3))(net, inp, corr,
-                                                       flow)
+        g_fused = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+            net, inp, corr, flow)
         for a, b in zip(g_conv, g_fused):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-4, rtol=0)
@@ -373,9 +374,9 @@ class TestGradParity:
         for f in ("RAFT_MOTION_PALLAS", "RAFT_GRU_PALLAS"):
             monkeypatch.delenv(f, raising=False)
         monkeypatch.setenv("RAFT_STEP_PALLAS", "0")
-        g_conv = jax.grad(loss)(vs["params"])
+        g_conv = jax.jit(jax.grad(loss))(vs["params"])
         monkeypatch.setenv("RAFT_STEP_PALLAS", "1")
-        g_fused = jax.grad(loss)(vs["params"])
+        g_fused = jax.jit(jax.grad(loss))(vs["params"])
         for a, b in zip(jax.tree_util.tree_leaves(g_conv),
                         jax.tree_util.tree_leaves(g_fused)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -76,9 +76,11 @@ def timer(monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def one_at_a_time():
+def one_at_a_time(compile_cache):
     """Steps 1-6 on batches 0-5, each waited for before the next is
-    dispatched: the loss, and the state after it on the host."""
+    dispatched: the loss, and the state after it on the host. (With the
+    module's compile cache on, the ``train()`` calls of six steps below
+    read back the step compiled here.)"""
     tcfg = tcfg_of()
     mesh = make_mesh()
     rng = jax.random.PRNGKey(tcfg.seed)

@@ -10,8 +10,10 @@ from raft_tpu.losses import epe_metrics, sequence_loss
 from raft_tpu.models.raft import RAFT
 from raft_tpu.optim import (cosine_warmup_restarts_schedule, make_schedule,
                             onecycle_schedule, step_schedule)
-from raft_tpu.parallel import (create_train_state, make_mesh, make_eval_step,
-                               make_train_step, shard_batch)
+from raft_tpu.parallel import (make_mesh, make_eval_step, make_train_step,
+                               shard_batch)
+
+from conftest import jit_train_state
 
 
 class TestSequenceLoss:
@@ -164,15 +166,13 @@ class TestTrainStep:
         tcfg = TrainConfig(batch_size=2, image_size=(64, 64), num_steps=50,
                            iters=2, lr=1e-4)
         model = RAFT(RAFTConfig(small=True, iters=2))
-        state = create_train_state(jax.random.PRNGKey(0), model, tcfg,
-                                   (64, 64))
-        return tcfg, model, state
+        state = jit_train_state(jax.random.PRNGKey(0), model, tcfg, (64, 64))
+        # donate=False: the class-scoped fixture state is reused by later
+        # tests, so its buffers must survive their steps.
+        return tcfg, model, state, make_train_step(tcfg, donate=False)
 
     def test_loss_decreases_on_overfit(self, setup, rng):
-        tcfg, model, state = setup
-        # donate=False: the class-scoped fixture state is reused by later
-        # tests, so its buffers must survive this loop.
-        step_fn = make_train_step(tcfg, donate=False)
+        tcfg, model, state, step_fn = setup
         batch = _tiny_batch(rng)
         key = jax.random.PRNGKey(0)
         first = None
@@ -183,8 +183,7 @@ class TestTrainStep:
         assert float(metrics["loss"]) < first
 
     def test_metrics_finite_and_step_advances(self, setup, rng):
-        tcfg, model, state = setup
-        step_fn = make_train_step(tcfg, donate=False)
+        tcfg, model, state, step_fn = setup
         state2, metrics = step_fn(state, _tiny_batch(rng),
                                   jax.random.PRNGKey(1))
         assert int(state2.step) == int(state.step) + 1
@@ -192,7 +191,7 @@ class TestTrainStep:
             assert np.isfinite(float(v)), k
 
     def test_eval_step(self, setup):
-        tcfg, model, state = setup
+        tcfg, model, state, _ = setup
         eval_fn = make_eval_step(iters=2)
         i1 = jnp.zeros((1, 64, 64, 3))
         flow_low, flow_up = eval_fn(state, i1, i1)
@@ -210,8 +209,7 @@ class TestBatchNormFreeze:
         tcfg = TrainConfig(batch_size=1, image_size=(64, 64), num_steps=50,
                            iters=1, lr=1e-4)
         model = RAFT(RAFTConfig(iters=1))
-        state = create_train_state(jax.random.PRNGKey(0), model, tcfg,
-                                   (64, 64))
+        state = jit_train_state(jax.random.PRNGKey(0), model, tcfg, (64, 64))
         assert jax.tree_util.tree_leaves(state.batch_stats)
         return tcfg, state
 
@@ -236,38 +234,38 @@ class TestBatchNormFreeze:
 
 
 class TestShardedTrainStep:
-    def test_eight_device_mesh(self, rng):
-        assert len(jax.devices()) == 8
-        mesh = make_mesh()
+    @pytest.fixture(scope="class")
+    def steps(self):
+        """One step of the same state on the same batch, on one device and
+        data-parallel over the 8-device mesh (the default, donating
+        step): the metrics of each, and the mesh's state after."""
         tcfg = TrainConfig(batch_size=8, image_size=(64, 64), num_steps=50,
                            iters=2)
         model = RAFT(RAFTConfig(small=True, iters=2))
-        with mesh:
-            state = create_train_state(jax.random.PRNGKey(0), model, tcfg,
-                                       (64, 64), mesh=mesh)
-            step_fn = make_train_step(tcfg, mesh=mesh)
-            batch = shard_batch(_tiny_batch(rng, B=8), mesh)
-            state, metrics = step_fn(state, batch, jax.random.PRNGKey(1))
-        assert np.isfinite(float(metrics["loss"]))
-
-    def test_sharded_matches_single_device(self, rng):
-        """Data-parallel must be a layout choice, not a semantics choice."""
-        tcfg = TrainConfig(batch_size=8, image_size=(64, 64), num_steps=50,
-                           iters=2)
-        model = RAFT(RAFTConfig(small=True, iters=2))
-        batch = _tiny_batch(rng, B=8)
+        batch = _tiny_batch(np.random.default_rng(0), B=8)
         key = jax.random.PRNGKey(1)
 
-        state1 = create_train_state(jax.random.PRNGKey(0), model, tcfg,
-                                    (64, 64))
+        state1 = jit_train_state(jax.random.PRNGKey(0), model, tcfg,
+                                 (64, 64))
         _, m_single = make_train_step(tcfg, donate=False)(state1, batch, key)
 
         mesh = make_mesh()
         with mesh:
-            state2 = create_train_state(jax.random.PRNGKey(0), model, tcfg,
-                                        (64, 64), mesh=mesh)
-            _, m_shard = make_train_step(tcfg, mesh=mesh, donate=False)(
+            state2 = jit_train_state(jax.random.PRNGKey(0), model, tcfg,
+                                     (64, 64), mesh=mesh)
+            state2, m_shard = make_train_step(tcfg, mesh=mesh)(
                 state2, shard_batch(batch, mesh), key)
+        return m_single, m_shard, state2
+
+    def test_eight_device_mesh(self, steps):
+        _, metrics, state = steps
+        assert len(jax.devices()) == 8
+        assert len(jax.tree.leaves(state.params)[0].sharding.device_set) == 8
+        assert np.isfinite(float(metrics["loss"]))
+
+    def test_sharded_matches_single_device(self, steps):
+        """Data-parallel must be a layout choice, not a semantics choice."""
+        m_single, m_shard, _ = steps
         np.testing.assert_allclose(float(m_single["loss"]),
                                    float(m_shard["loss"]), rtol=2e-4)
 
