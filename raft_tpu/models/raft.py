@@ -164,7 +164,9 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2, inference: bool):
     """Precompute the scan-invariant correlation state.
 
     All-pairs mode: the pooled 4D-volume pyramid (tuple of arrays).
-    Alternate mode: fmap1 + the pooled fmap2 pyramid (tuple of arrays).
+    Alternate mode: fmap1 + the pooled fmap2 pyramid (tuple of arrays),
+    or on the unsharded kernel route both in the kernel's own layout
+    (``corr.alternate_operands``).
     Returned as plain pytrees so they can cross ``nn.scan`` as broadcast
     arguments. ``inference`` resolves both "auto" dtype levers (bf16
     volume storage / bf16 MXU operands are inference-only; training keeps
@@ -182,9 +184,11 @@ def _build_corr_state(cfg: RAFTConfig, fmap1, fmap2, inference: bool):
     kind, meta = corr_state_meta(cfg, inference)
     with jax.named_scope("corr_build"):
         if kind == "alt":
-            return (kind, meta,
-                    (fmap1,
-                     corr.build_feature_pyramid(fmap2, cfg.corr_levels)))
+            # the lookup's operands in the layout it reads them, once a
+            # pair rather than once an iteration
+            return (kind, meta, corr.alternate_operands(
+                fmap1, corr.build_feature_pyramid(fmap2, cfg.corr_levels),
+                cfg.radius, differentiable=meta[1]))
         return (kind, meta,
                 corr.build_corr_pyramid(
                     fmap1, fmap2, cfg.corr_levels, cfg.corr_scale,

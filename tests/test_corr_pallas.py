@@ -447,3 +447,95 @@ def test_out_dtype_bitexact_vs_external_cast(rng):
     for a, b in zip(g_bf, g_f32):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+def _tiled_case(rng, name):
+    """(f1, pyramid, coords, radius, rescale, tiling) of one named case
+    of the 2-D query tiles: a 16 x 64 grid of 8 x 16 tiles, each reading
+    a window of 32 (radius 4: 40) of level 0's 64 columns and 24 of
+    level 1's 32, or a width of 62 (tiles padded to 64)."""
+    from raft_tpu.ops import corr_pallas as cp
+    H, W, C, L = 16, 62 if name == "width62" else 64, 8, 2
+    radius = 4 if name == "f32" else 2
+    dtype = jnp.bfloat16 if name == "bf16" else jnp.float32
+    # Integer features keep every product's sum exact: XLA:CPU's order of
+    # summation in a bfloat16 dot follows the operands' shapes, which the
+    # two tilings differ in (the chip check holds real data).
+    draw = ((lambda *s: rng.integers(-4, 5, s)) if name == "bf16"
+            else lambda *s: rng.standard_normal(s))
+    f1 = jnp.asarray(draw(1, H, W, C), dtype)
+    pyr = build_feature_pyramid(jnp.asarray(draw(1, H, W, C), dtype), L)
+    ys, xs = np.meshgrid(np.arange(H, dtype=np.float32),
+                         np.arange(W, dtype=np.float32), indexing="ij")
+    flow = np.stack([2.0 * np.sin(xs / 9.0) - 1.0,
+                     1.3 * np.cos(xs / 7.0 + ys / 5.0)], -1)
+    if name == "wild":          # the right half spreads past any window
+        flow = np.where((xs >= 32)[..., None],
+                        rng.uniform(-20, 20, (H, W, 2)), flow)
+    coords = jnp.asarray((np.stack([xs, ys], -1) + flow)[None], jnp.float32)
+    levels = cp._level_geometry([f2.shape[1:3] for f2 in pyr])
+    rescale = name != "no_rescale"
+    return (f1, pyr, coords, radius, rescale,
+            cp._tiling(8, 16, levels, radius, rescale))
+
+
+@pytest.mark.parametrize("name", ["f32", "bf16", "no_rescale", "width62",
+                                  "wild", "one_row"])
+def test_query_tiles_bitexact_vs_raster(rng, name):
+    # A 2-D query tile reads a window of each level's columns; the
+    # columns it leaves out carry weight exactly 0 and each output sums
+    # at most two nonzero terms a side, so the result is the raster
+    # launch's bit for bit, windowed tiles, whole-width tiles and all.
+    from raft_tpu.ops import corr_pallas as cp
+    if name == "one_row":
+        # a one- or two-row grid keeps raster tiles, however wide
+        for h in (1, 2):
+            levels = cp._level_geometry([(h, 4096), (h // 2, 2048)])
+            assert cp.choose_query_tile(h, 4096, levels, 4, 256) is None
+        return
+    f1, pyr, coords, radius, rescale, tiling = _tiled_case(rng, name)
+    assert tiling.windows[0] == (8, 40 if radius == 4 else 32)
+    mxu = "bfloat16" if name == "bf16" else "float32"
+    run = functools.partial(cp._fused, radius=radius, scale=True,
+                            mxu_dtype=mxu, interpret=True,
+                            rescale=rescale, out_dtype=jnp.float32)
+    want = np.asarray(run(f1, pyr, coords, band=None, tiling=None))
+    # the wild case in all three band modes: "off" folds every diagonal
+    for band in (("dynamic", "static", "off") if name == "wild"
+                 else (None,)):
+        got = np.asarray(run(f1, pyr, coords, band=band, tiling=tiling))
+        np.testing.assert_array_equal(got, want, err_msg=str(band))
+    if rescale:
+        stats = cp.sweep_stats(np.asarray(coords),
+                               [f2.shape[1:3] for f2 in pyr], radius, tiling)
+        windowed = stats["levels"][0]["tiles_windowed"]
+        if name == "wild":      # some tiles read windows, some whole rows
+            assert 0 < windowed < stats["tiles"]
+        else:
+            assert windowed == stats["tiles"]
+    if name == "f32":
+        ref = _jnp_multilevel(f1, pyr, coords, radius)
+        np.testing.assert_allclose(got, np.asarray(ref), rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_query_tiles_gradients_match_raster(rng):
+    # The backward runs raster tiles whatever tile the forward took: the
+    # gradients are the raster launch's bit for bit, and coordinates get
+    # zero, as ever.
+    from raft_tpu.ops import corr_pallas as cp
+    f1, pyr, coords, radius, _, tiling = _tiled_case(rng, "wild")
+    f2 = _rand(rng, *f1.shape)
+    cot = _rand(rng, *f1.shape[:3], 2 * (2 * radius + 1) ** 2)
+
+    def grads(tiling):
+        def loss(a, b, c):
+            out = cp._fused(a, build_feature_pyramid(b, 2), c, radius, True,
+                            "float32", True, None, True, jnp.float32, tiling)
+            return jnp.sum(out * cot)
+        return jax.grad(loss, argnums=(0, 1, 2))(f1, f2, coords)
+
+    tiled = grads(tiling)
+    for got, want in zip(tiled, grads(None)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(tiled[2]).any()

@@ -155,16 +155,33 @@ def test_diagonal_sweep_bitexact_vs_dense_oracle(rng, name, monkeypatch):
         assert np.abs(want).max() > 0.1
 
 
-def _brute_force_pairs(cy_tile, h2l, radius, held):
+def _brute_force_pairs(cx_tile, cy_tile, h2l, w2pl, radius, window):
     """What the forward kernel folds for one tile at one level, by
     enumeration: ``dense`` is every offset of every row of the chunks
     ``_band_chunks`` gives; ``live`` the pairs among them that can carry
     a nonzero weight (a row of the image with ``floor(min cy) <= y - off
     <= ceil(max cy)``); ``diagonal`` every offset of every diagonal of
     the blocks the diagonal sweep steps through, or the dense count
-    where the kernel keeps the dense sweep (band taller than the
-    ``held`` chunks of scratch, or no fewer pairs by diagonals)."""
+    where the kernel keeps the dense sweep (band taller than the chunks
+    of scratch holds, a level too short to hold one block of diagonals,
+    or no fewer pairs by diagonals; a window always takes the diagonal
+    sweep). The columns: those where
+    some query's x-side weight, evaluated in float32 as the kernel does,
+    is nonzero; the window of ``window = (xb, xw)`` from the block of the
+    first of them holds them all, or the tile reads the level's width a
+    window at a time; ``products``, ``xside`` and the folds follow."""
     from raft_tpu.ops import corr_pallas as cp
+    xb, xw = window
+    xs = np.arange(w2pl, dtype=np.float32)[:, None, None]
+    offs = np.arange(-radius, radius + 1, dtype=np.float32)[None, :, None]
+    reach = np.abs(xs - (cx_tile[None, None, :] + offs)) < 1
+    cols = np.flatnonzero(reach.any(axis=(1, 2)))
+    fits, windows = False, 1
+    held = min(32, -(-h2l // 8) * 8) // 8
+    if xw < w2pl:
+        jb0 = min(cols[0] // xb, (w2pl - xw) // xb) if cols.size else 0
+        fits = not cols.size or cols[-1] < jb0 * xb + xw
+        windows = 1 if fits else -(-w2pl // xw)
     c_lo, c_hi = (int(v) for v in cp._band_chunks(
         jnp.asarray(cy_tile), radius, h2l, -(-h2l // 8)))
     rows = [y for c in range(c_lo, c_hi) for y in range(c * 8, c * 8 + 8)]
@@ -177,37 +194,90 @@ def _brute_force_pairs(cy_tile, h2l, radius, held):
     stepped = [d for d0 in range(d_lo, d_hi + 1, cp._DIAG_BLOCK)
                for d in range(d0, d0 + cp._DIAG_BLOCK)]
     by_diagonals = len(stepped) * len(offs)
-    diagonal = (by_diagonals if c_hi - c_lo <= held and by_diagonals < dense
-                else dense)
-    return {"diagonal": diagonal, "dense": dense, "live": live,
-            "tiles_diagonal": int(diagonal != dense)}
+    takes = (c_hi - c_lo <= held and by_diagonals < dense
+             and held * 8 >= len(offs) + cp._DIAG_BLOCK)
+    if xw < w2pl:               # a window folds by diagonals, always
+        takes = bool(rows)
+    diagonal = by_diagonals if takes else dense
+    return {"diagonal": diagonal * windows, "dense": dense * windows,
+            "live": live, "tiles_diagonal": int(takes),
+            "products": len(rows) * xw * windows, "xside": xw * windows,
+            "tiles_windowed": int(fits)}
 
 
-@pytest.mark.parametrize("case", ["two_rows", "spread", "outside", "r3"])
+def _tiles(coords, tile):
+    """The query tiles' coordinates (tiles, queries, 2), edge-padded:
+    raster runs of ``tile`` queries, or ``(th, tw)`` rectangles."""
+    b, h, w, _ = coords.shape
+    if isinstance(tile, int):
+        flat = coords.reshape(b, h * w, 2)
+        flat = np.pad(flat, ((0, 0), (0, -(h * w) % tile), (0, 0)),
+                      mode="edge")
+        return flat.reshape(-1, tile, 2)
+    th, tw = tile
+    grid = np.pad(coords, ((0, 0), (0, -h % th), (0, -w % tw), (0, 0)),
+                  mode="edge")
+    return np.stack([grid[0, i:i + th, j:j + tw].reshape(-1, 2)
+                     for i in range(0, grid.shape[1], th)
+                     for j in range(0, grid.shape[2], tw)])
+
+
+@pytest.mark.parametrize("case", ["two_rows", "spread", "outside", "r3",
+                                  "tiled", "tiled_wild", "tiled_r3",
+                                  "sintel"])
 def test_sweep_stats_matches_enumeration(rng, case):
+    from raft_tpu.ops import corr_pallas as cp
     from raft_tpu.ops.corr_pallas import sweep_stats
-    radius = 3 if case == "r3" else 4
-    H, W, tq = 16, 64, 128                  # a tile is two raster rows
+    radius = 3 if case.endswith("r3") else 4
+    H, W, tile = 16, 64, 128                # a raster tile is two rows
     shapes = [(40, 64), (20, 32), (10, 16)]  # 40 > the 32 rows of scratch
+    if case.startswith("tiled"):            # windows at two of 3 levels
+        tile = cp._Tiling(8, 16, ((8, 40), (8, 24), (16, 16)))
+    if case == "sintel":                    # the pass cells' grid
+        H, W, tile = 55, 128, None
+        shapes = [(55 >> l, 128 >> l) for l in range(4)]
     ys, xs = np.meshgrid(np.arange(H, dtype=np.float32),
                          np.arange(W, dtype=np.float32), indexing="ij")
     dy = {"two_rows": 0.25 + 0 * xs, "r3": 0.5 * np.sin(xs / 7.0),
           "spread": np.where(ys < 8, rng.uniform(-2, 2, (H, W)),
                              rng.uniform(-6, 26, (H, W))),
-          "outside": 90.0 + np.cos(xs)}[case]
-    coords = np.stack([xs, ys + dy], -1)[None].astype(np.float32)
-    got = sweep_stats(coords, shapes, radius, tq)
-    assert got["tiles"] == H * W // tq and got["tq"] == tq
-    cy = coords[0, ..., 1].reshape(-1, tq)
-    for l, (h2l, _) in enumerate(shapes):
-        held = min(32, -(-h2l // 8) * 8) // 8
-        want = [_brute_force_pairs(t * np.float32(1 / 2 ** l), h2l, radius,
-                                   held) for t in cy]
+          "outside": 90.0 + np.cos(xs), "tiled": 1.5 * np.sin(xs / 9.0),
+          "tiled_wild": np.where(xs < 32, np.cos(ys), rng.uniform(
+              -20, 40, (H, W))), "tiled_r3": 0.7 * np.cos(ys / 4.0),
+          "sintel": 2.0 * np.sin(xs / 11.0) + np.cos(ys / 7.0)}[case]
+    dx = {"tiled": 1.3 * np.cos(ys / 5.0), "tiled_r3": -2.5 + 0 * xs,
+          "sintel": 3.0 * np.cos(xs / 13.0)}.get(case, 0 * xs)
+    if case == "tiled_wild":
+        dx = np.where(xs < 32, 0.5, rng.uniform(-30, 30, (H, W)))
+    coords = np.stack([xs + dx, ys + dy], -1)[None].astype(np.float32)
+    got = sweep_stats(coords, shapes, radius, tile, channels=256)
+    levels = cp._level_geometry(shapes)
+    if tile is None:
+        # the pass cells' 55 x 128 grid takes a tile of 8 x 32 queries,
+        # whose level-0 products are under half a raster tile's
+        tile = cp.choose_query_tile(H, W, levels, radius, 256)
+        assert got["tile"] == [tile.th, tile.tw] == [8, 32]
+        raster = sweep_stats(coords, shapes, radius, 256)
+        assert (2 * got["levels"][0]["products"] / got["tiles"]
+                < raster["levels"][0]["products"] / raster["tiles"])
+        assert (got["levels"][0]["xside"] / got["tiles"]
+                < raster["levels"][0]["xside"] / raster["tiles"] / 2)
+    shape = tile if isinstance(tile, int) else (tile.th, tile.tw)
+    tiles = _tiles(coords, shape)
+    assert got["tiles"] == len(tiles) and got["tq"] == tiles.shape[1]
+    windows = (tile.windows if not isinstance(tile, int)
+               else [(w2pl, w2pl) for (_, _, w2pl) in levels])
+    for l, ((h2l, _, w2pl), window) in enumerate(zip(levels, windows)):
+        s = np.float32(1 / 2 ** l)
+        want = [_brute_force_pairs(t[:, 0] * s, t[:, 1] * s, h2l, w2pl,
+                                   radius, window)
+                for t in tiles]
         assert got["levels"][l] == {
             key: sum(w[key] for w in want) for key in want[0]}
-        assert (got["levels"][l]["live"] <= got["levels"][l]["diagonal"]
-                <= got["levels"][l]["dense"])
-    for key in ("diagonal", "dense", "live"):
+        assert got["levels"][l]["live"] <= got["levels"][l]["diagonal"]
+        if window[1] == w2pl:   # a whole width's diagonals never cost more
+            assert got["levels"][l]["diagonal"] <= got["levels"][l]["dense"]
+    for key in ("diagonal", "dense", "live", "products", "xside"):
         assert got[key] == sum(v[key] for v in got["levels"])
     if case == "two_rows":
         # by hand, level 0: cy spans [y + .25, y + 1.25], so diagonals
@@ -222,3 +292,7 @@ def test_sweep_stats_matches_enumeration(rng, case):
         assert got["levels"][0]["tiles_diagonal"] == 4
     if case == "outside":       # below every level: nothing to fold
         assert got["diagonal"] == 0 and got["live"] == 0
+    if case == "tiled_wild":    # the wild half reads whole widths
+        assert 0 < got["levels"][0]["tiles_windowed"] < got["tiles"]
+    elif case.startswith("tiled"):
+        assert got["levels"][0]["tiles_windowed"] == got["tiles"]
